@@ -188,7 +188,7 @@ def run_tuned(quick: bool = False):
 
     from repro.analysis.vmem import launch_estimate
     from repro.configs import get_config
-    from repro.kernels.ops import _BLOCK_DEFAULTS, _pick_block
+    from repro.kernels.ops import _BLOCK_DEFAULTS, legal_blocks
     from repro.roofline.analysis import fno_model_bytes
     from repro.tuning import resolve_block_plan
 
@@ -221,8 +221,7 @@ def run_tuned(quick: bool = False):
             times[name] = time_fn(fn, x, wr, wi, wb, bias, iters=5)
             outs[name] = fn(x, wr, wi, wb, bias)
             triple = tuned if plan is None else plan
-            eff = (_pick_block(b, triple[0]), _pick_block(h, triple[1]),
-                   _pick_block(h, triple[2]))
+            eff = legal_blocks(r, b, h, h, *triple)
             est = launch_estimate(shapes, "block_fwd", triple, batch=b)
             row(f"tuned_r{r}_{name}", times[name],
                 f"plan={eff} vmem_est={est.total_bytes / 2**20:.2f}MiB "
@@ -252,7 +251,7 @@ def run_serve(quick: bool = False):
     from repro.configs import get_config
     from repro.core import fno as fno_mod
     from repro.distributed import sharding as shd
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.launch.serve_fno import _pick_tp
     from repro.roofline.analysis import fno_collective_bytes
     from repro.train import serve_fno_step as sfs
@@ -262,7 +261,7 @@ def run_serve(quick: bool = False):
     cfg0 = get_config("fno2d", reduced=True)
     tp = _pick_tp(n_dev, cfg0.hidden)  # the serving driver's own auto-pick
     dp = n_dev // tp
-    mesh = make_compat_mesh((dp, tp), ("data", "model"))
+    mesh = make_mesh((dp, tp), ("data", "model"))
     b = 4 if quick else 8
     x = jnp.asarray(np.random.default_rng(2).normal(
         size=(b, cfg0.in_channels) + tuple(cfg0.spatial)), jnp.float32)

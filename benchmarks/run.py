@@ -27,6 +27,8 @@ def main() -> None:
                     help="spatial ranks for the train rank sweep "
                          "(e.g. --ranks 3 tracks only the 3D path)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         ranks = tuple(int(r) for r in args.ranks.split(","))
     except ValueError:

@@ -5,11 +5,15 @@ Reduced demo (runs in ~a minute on this CPU container):
 
     PYTHONPATH=src python examples/train_fno.py --steps 60
 
-Full-scale target (the ~100M-parameter configuration; run on a real
-accelerator — one step is ~0.9 TFLOP at batch 8):
+--full trains the chosen --arch at its full width (default --arch fno2d);
+without it the arch runs at its reduced CPU-smoke size. Full-width fno2d
+on the fused kernels, fwd and bwd, on a TPU:
 
-    PYTHONPATH=src python examples/train_fno.py --full --steps 300 \
-        --batch 8 --lr 3e-4
+    PYTHONPATH=src python examples/train_fno.py --arch fno2d --full \
+        --path pallas --fuse-block --steps 300 --batch 8 --lr 3e-4
+
+The ~100M-parameter per-mode configuration is its own arch:
+--arch fno2d-large --full.
 
 Rank sweep: --arch fno1d / fno2d / fno3d trains the matching PDE task
 (Burgers / Darcy / 3D diffusion-reaction) through the same rank-generic
@@ -29,6 +33,7 @@ from repro.configs import get_config
 from repro.configs.fno import with_fuse_block, with_precision
 from repro.core import fno
 from repro.data import pde
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import AdamW
 from repro.optim.schedule import cosine_warmup
 from repro.train.train_step import make_train_step
@@ -40,13 +45,13 @@ def main():
     ap.add_argument("--steps", type=int, default=60)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--lr", type=float, default=1e-2)
-    ap.add_argument("--arch", default=None,
+    ap.add_argument("--arch", default="fno2d",
                     choices=["fno1d", "fno2d", "fno2d-large", "fno3d"],
                     help="architecture/rank; picks the matching PDE task "
                          "(Burgers 1D / Darcy 2D / diffusion-reaction 3D)")
     ap.add_argument("--full", action="store_true",
-                    help="fno2d-large (~134M params, per-mode weights); "
-                         "shorthand for --arch fno2d-large at full size")
+                    help="train --arch at its full width (default: the "
+                         "reduced smoke size)")
     ap.add_argument("--path", default="xla", choices=["ref", "xla", "pallas"],
                     help="pallas = fused kernels fwd AND bwd (custom_vjp); "
                          "no staged-XLA fallback")
@@ -63,14 +68,12 @@ def main():
                          "pallas_call per layer, fwd and bwd")
     args = ap.parse_args()
 
-    if args.full and args.arch not in (None, "fno2d-large"):
-        ap.error("--full selects fno2d-large; it conflicts with "
-                 f"--arch {args.arch}")
     if args.fuse_block and args.path != "pallas":
         ap.error("--fuse-block requires --path pallas (the staged paths "
                  "stay the parity oracle)")
-    arch = args.arch or ("fno2d-large" if args.full else "fno2d")
-    cfg = with_precision(get_config(arch, reduced=not args.full), args.dtype)
+    enable_compile_cache()
+    cfg = with_precision(get_config(args.arch, reduced=not args.full),
+                         args.dtype)
     if args.fuse_block:
         cfg = with_fuse_block(cfg)
     key = jax.random.PRNGKey(0)

@@ -41,6 +41,9 @@ python scripts/chaos_smoke.py
 # budget) over the whole config matrix. Pure tracing + AST — no kernels
 # execute.
 python scripts/lint.py --all
+# The pytest gate below includes tests/test_tpu_compile.py: the full-width
+# fused block and its gradient compiled for a described TPU v5e (needs
+# libtpu, not a chip).
 # Collection gate: when pytest selection args (-k/-m/paths) could deselect
 # a broken module, a full collect-only pass must still fail the script on
 # any collection error. A bare run needs no gate — pytest itself exits

@@ -38,7 +38,7 @@ def main() -> None:
     from repro.configs import get_config
     from repro.core import fno as fno_mod
     from repro.distributed import sharding as shd
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
 
     dp, tp = 2, 4
     assert jax.device_count() >= dp * tp, (
@@ -51,7 +51,7 @@ def main() -> None:
     params = fno_mod.init_fno(key, cfg0)
     x = jax.random.normal(key, (8, cfg0.in_channels) + tuple(cfg0.spatial))
     y_ref = fno_mod.apply_fno(params, cfg0, x, path="xla")
-    mesh = make_compat_mesh((dp, tp), ("data", "model"))
+    mesh = make_mesh((dp, tp), ("data", "model"))
 
     outs, grads, colls = {}, {}, {}
     for overlap in (False, True):

@@ -1,15 +1,15 @@
 """Source-level contract lints (AST rules) + the config-registry audit.
 
-These encode the repo's compat and precision policies as mechanical rules
-over ``src/repro`` (ROADMAP.md §Durable design contracts, DESIGN.md §7):
+These encode the repo's kernel, sharding and precision policies as
+mechanical rules over ``src/repro`` (ROADMAP.md §Durable design
+contracts, DESIGN.md §7):
 
   * **pallas-compiler-params** — every ``pl.pallas_call`` must pass
-    ``compiler_params=_compiler_params(...)``: the one shim that resolves
-    the TPUCompilerParams/CompilerParams rename across JAX versions. A raw
-    pallas_call breaks on one side of the support matrix.
-  * **compat-shard-map** — ``jax.experimental.shard_map`` may only be
-    imported inside ``distributed/sharding.py`` (home of
-    ``compat_shard_map``, which resolves the check_rep→check_vma rename).
+    ``compiler_params=_compiler_params(...)``: the one place the VMEM
+    limit the kernels compile under is set.
+  * **compat-shard-map** — ``jax.shard_map`` may only be used (or any
+    shard_map imported) inside ``distributed/sharding.py``, whose
+    ``compat_shard_map`` is the one call site (replication checking off).
   * **no-raw-fft** — ``jnp.fft`` is the oracle's tool (``kernels/ref.py``)
     and the data generator's (``data/pde.py``); production paths must use
     the truncated-DFT formulation (``core/spectral.py`` operands through
@@ -43,7 +43,7 @@ FFT_ALLOWED = ("kernels/ref.py", "data/pde.py")
 # allowed to hold float-dtype literals ("<module>" = module level). These
 # are exactly the cast-ownership boundaries of DESIGN.md §4.
 DTYPE_SCOPE: Dict[str, Tuple[str, ...]] = {
-    "kernels/engine.py": ("<module>",),        # _F32 accumulator default
+    "kernels/engine.py": (),                   # policy dtypes only
     "kernels/cgemm.py": ("<module>",),         # _F32 accumulator default
     "kernels/dft.py": ("<module>",),           # _F32 accumulator default
     "kernels/ops.py": ("_spectral_layer_nd",   # f32 oracle boundary
@@ -106,10 +106,8 @@ class _Visitor(ast.NodeVisitor):
                 self.findings.append(Finding(
                     "pallas-compiler-params", self._where(node),
                     "pl.pallas_call without compiler_params="
-                    "_compiler_params(...) — pass dimension semantics "
-                    "through the kernels/__init__ shim so the call "
-                    "survives the TPUCompilerParams/CompilerParams rename "
-                    "(ROADMAP §JAX version compat)"))
+                    "_compiler_params(...) — every kernel compiles under "
+                    "the one VMEM limit kernels/__init__ sets"))
         self.generic_visit(node)
 
     def visit_ImportFrom(self, node: ast.ImportFrom):
@@ -120,8 +118,7 @@ class _Visitor(ast.NodeVisitor):
             self.findings.append(Finding(
                 "compat-shard-map", self._where(node),
                 "raw shard_map import — use distributed.sharding."
-                "compat_shard_map, the one shim that spans the "
-                "check_rep→check_vma rename across JAX versions"))
+                "compat_shard_map, the repo's one shard_map call site"))
         self.generic_visit(node)
 
     def visit_Import(self, node: ast.Import):
@@ -134,6 +131,11 @@ class _Visitor(ast.NodeVisitor):
         self.generic_visit(node)
 
     def visit_Attribute(self, node: ast.Attribute):
+        if node.attr == "shard_map" and self.rel != SHARD_MAP_HOME:
+            self.findings.append(Finding(
+                "compat-shard-map", self._where(node),
+                "raw jax.shard_map — use distributed.sharding."
+                "compat_shard_map, the repo's one shard_map call site"))
         if node.attr == "fft" and self.rel not in FFT_ALLOWED:
             self.findings.append(Finding(
                 "no-raw-fft", self._where(node),

@@ -45,7 +45,7 @@ from repro.roofline.hlo_counter import iter_jaxpr_eqns
 # collectives (post-trace) are invisible here by design: the contract
 # governs the collectives the code *asks for*, i.e. the shard_map psum.
 COLLECTIVE_PRIMS = ("psum", "all_gather", "all_to_all", "ppermute",
-                    "psum_scatter", "reduce_scatter")
+                    "reduce_scatter")
 
 DTYPES = ("f32", "bf16")
 LAYOUTS = ("shared", "per_mode")
@@ -147,8 +147,8 @@ def check_collective_budget(fn, args: Sequence, *, psums: int, target: str,
     one — the final layer's — on the scattered layout). psum_scatters:
     reduce-scatters emitting the next layer's hidden shard (one per
     INTERIOR TP layer on the scattered layout, zero otherwise).
-    ``lax.psum_scatter`` traces as the ``reduce_scatter`` primitive on
-    JAX 0.4.x — both spellings count toward the same budget. Anything
+    ``lax.psum_scatter`` traces as the ``reduce_scatter`` primitive,
+    which is what the budget counts. Anything
     else (all_gather, all_to_all, ppermute) is unexpected on the FNO
     forward/serve path.
     """
@@ -161,7 +161,7 @@ def check_collective_budget(fn, args: Sequence, *, psums: int, target: str,
             f"traced {got} psum(s), want exactly {psums} (scattered "
             f"layout: only the final TP layer psums; psum layout: one per "
             f"TP layer; zero under pure DP — DESIGN.md §6)"))
-    got_rs = counts.pop("psum_scatter", 0) + counts.pop("reduce_scatter", 0)
+    got_rs = counts.pop("reduce_scatter", 0)
     if got_rs != psum_scatters:
         findings.append(Finding(
             "collective-budget", target,
@@ -276,7 +276,7 @@ def lint_model(archs: Sequence[str] = ("fno1d", "fno2d", "fno3d"),
 
 
 def _mesh_or_finding(dp: int, tp: int, target: str):
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
     need = dp * tp
     if jax.device_count() < need:
         return None, [Finding(
@@ -284,7 +284,7 @@ def _mesh_or_finding(dp: int, tp: int, target: str):
             f"skipped: needs {need} devices, have {jax.device_count()} "
             f"(run under XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{need}, as scripts/lint.py does)", severity="warn")]
-    return make_compat_mesh((dp, tp), ("data", "model")), []
+    return make_mesh((dp, tp), ("data", "model")), []
 
 
 def lint_sharded_blocks(mesh_grids: Sequence[Tuple[int, int]] = ((8, 1),

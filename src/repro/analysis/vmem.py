@@ -15,12 +15,15 @@ Shape model (mirrors ``kernels/engine.py`` exactly):
   * the DFT operand mats use constant index maps (same block every
     program) and the VMEM accumulators are scratch → ×1;
   * accumulators live at ``accum_dtype`` with the shapes the kernels
-    declare (``rev_modes+(bb,bo)`` per-mode, ``(bb,)+rev_modes+(bo)``
-    shared, plus the bypass scratch ``(bo,bb)+spatial`` for the block
-    epilogue).
+    declare (``(∏K,bb,bo)`` per-mode, ``(bb,)+rev_modes+(bo)`` shared,
+    plus the bypass scratch ``(bo,bb)+spatial`` for the block epilogue);
+  * every buffer is padded to Mosaic's tiles: the minor dim to 128
+    lanes, the second-minor to 8 (32-bit) or 16 (16-bit) sublanes.
 
-The estimate is deliberately a floor (it ignores Mosaic's own padding of
-sub-(8,128) tiles), so "over budget" findings are real. Severity policy
+The estimate still misses Mosaic's internal scratch (relayouts, matmul
+temporaries); where it and a compile for the described chip disagree,
+the compile decides (the tuner compiles its full-size winners,
+tests/test_tpu_compile.py the main path's). Severity policy
 (since the autotuner landed): EVERY config — reduced and full-size — must
 resolve plans that fit, at error severity. A full-size config erroring
 here means the committed tuned cache (``tuning/cache/blocks.json``) lost
@@ -36,10 +39,13 @@ import jax.numpy as jnp
 
 from repro.analysis import Finding
 from repro.configs.base import FNOConfig, PrecisionPolicy
+from repro.kernels import VMEM_LIMIT_BYTES
 
-# Per-core VMEM on current TPU generations (v4/v5e/v5p are all 16 MiB;
-# interpret-mode CI has no such limit — the budget is about real TPUs).
-VMEM_BUDGET_BYTES = 16 * 1024 * 1024
+# The budget is the scoped-VMEM limit every engine kernel compiles under
+# (``kernels._compiler_params``) — one number. The compile for a described
+# chip (tests/test_tpu_compile.py) is the authority; this estimate only
+# prunes candidates before anything is lowered.
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,8 +69,20 @@ def _prod(xs: Sequence[int]) -> int:
     return int(math.prod(xs))
 
 
+def _tiled(shape: Sequence[int], dtype: str) -> int:
+    """Bytes of one VMEM buffer as Mosaic lays it out: the minor dim
+    padded to 128 lanes, the second-minor to the dtype's sublane tile
+    (8 rows of 32-bit, 16 of 16-bit)."""
+    isz = _isz(dtype)
+    shape = (1,) * max(0, 2 - len(shape)) + tuple(int(d) for d in shape)
+    sub = 8 * max(1, 4 // isz)
+    lead = _prod(shape[:-2])
+    return (lead * (-(-shape[-2] // sub) * sub)
+            * (-(-shape[-1] // 128) * 128) * isz)
+
+
 def _mats_bytes(mats) -> int:
-    return sum(int(m.size) * m.dtype.itemsize for m in mats)
+    return sum(_tiled(m.shape, m.dtype.name) for m in mats)
 
 
 def resolve_blocks(rank: int, b: int, h: int, o: int,
@@ -72,9 +90,9 @@ def resolve_blocks(rank: int, b: int, h: int, o: int,
                    ) -> Tuple[int, int, int]:
     """The (bb, bo, bh) the ops layer would pick: per-rank defaults from
     the block-size table, shrunk to the (8-aligned) actual dims."""
-    from repro.kernels.ops import _pick_block, _resolve_blocks
-    bb, bo, bh = _resolve_blocks(rank, bb, bo, bh)
-    return _pick_block(b, bb), _pick_block(o, bo), _pick_block(h, bh)
+    from repro.kernels.ops import _resolve_blocks, legal_blocks
+    return legal_blocks(rank, b, o, h,
+                        *_resolve_blocks(rank, bb, bo, bh))
 
 
 def _rev_modes(modes: Sequence[int]) -> Tuple[int, ...]:
@@ -85,6 +103,12 @@ def _rev_modes(modes: Sequence[int]) -> Tuple[int, ...]:
     return (kp,) if len(modes) == 1 else tuple(reversed(modes))
 
 
+def _weight_window(rev, bo, bh, per_mode) -> Tuple[int, ...]:
+    """The kernel's hidden-blocked weight window: [bo, bh] shared,
+    [∏K, bo, bh] per-mode."""
+    return ((_prod(rev),) if per_mode else ()) + (bo, bh)
+
+
 def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
                          pol: PrecisionPolicy, *, with_epilogue: bool,
                          with_gy: bool, out_dtype: Optional[str] = None,
@@ -93,32 +117,29 @@ def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
     from repro.core import spectral
     from repro.kernels.ops import _mode_pad
 
-    r = len(modes)
-    cb = _isz(pol.compute_dtype)
-    ab = _isz(pol.accum_dtype)
-    ob = _isz(out_dtype or pol.compute_dtype)
-    sp = _prod(spatial)
+    cd, ad = pol.compute_dtype, pol.accum_dtype
+    sp = tuple(spatial)
     kp = _mode_pad(modes)
     rev = _rev_modes(modes)
-    mats = spectral.fused_operand_mats(tuple(spatial), tuple(modes),
+    mats = spectral.fused_operand_mats(sp, tuple(modes),
                                        pol.spectral_dtype, adjoint, kp)
-    wmodes = _prod((kp,) if r == 1 else tuple(modes)) if per_mode else 1
+    w = _weight_window(rev, bo, bh, per_mode)
 
-    operands = 2 * (bb * bh * sp * cb)                 # x window
-    operands += 2 * (2 * bo * bh * wmodes * cb)        # wr + wi windows
-    operands += _mats_bytes(mats)                      # constant-index mats
-    operands += 2 * (bb * bo * sp * ob)                # y window
+    operands = 2 * _tiled((bb, bh) + sp, cd)               # x window
+    operands += 2 * 2 * _tiled(w, cd)                      # wr + wi
+    operands += _mats_bytes(mats)                          # constant mats
+    operands += 2 * _tiled((bb, bo) + sp, out_dtype or cd)  # y window
     if with_epilogue:
-        operands += 2 * (bo * bh * cb)                 # wb window
+        operands += 2 * _tiled((bo, bh), cd)               # wb window
         if not adjoint:
-            operands += 2 * (bo * 1 * cb)              # bias window
+            operands += 2 * _tiled((bo, 1), cd)            # bias window
     if with_gy:
-        operands += 2 * (bb * bo * sp * cb)            # gy window
+        operands += 2 * _tiled((bb, bo) + sp, cd)          # gy window
 
-    acc = _prod(rev) * bb * bo * ab
-    scratch = 2 * acc                                  # accr + acci
+    acc = ((_prod(rev), bb, bo) if per_mode else (bb,) + rev + (bo,))
+    scratch = 2 * _tiled(acc, ad)                          # accr + acci
     if with_epilogue:
-        scratch += bo * bb * sp * ab                   # bypass accumulator
+        scratch += _tiled((bo, bb) + sp, ad)               # bypass acc
     return LaunchEstimate(launch, operands, scratch)
 
 
@@ -128,24 +149,23 @@ def _core_call_estimate(spatial, modes, bb, bo, bh, per_mode,
     from repro.core import spectral
 
     r = len(modes)
-    cb = _isz(pol.spectral_dtype)
-    ab = _isz(pol.accum_dtype)
+    sd, ad = pol.spectral_dtype, pol.accum_dtype
     nx = spatial[0]
     spec = tuple(reversed(modes[1:]))  # K_R .. K_2
     mats = spectral.fused_operand_mats(tuple(spatial), tuple(modes),
                                        pol.spectral_dtype)
-    fr = mats[2 * r - 2]
-    kx = int(fr.shape[1])
+    kx = int(mats[2 * r - 2].shape[1])
     core_mats = mats[2 * r - 2:2 * r + 2]
-    wmodes = _prod(modes) if per_mode else 1
+    kf = _prod(modes)
+    w = (kf, bo, bh) if per_mode else (bo, bh)
+    y = spec + (bb, bo, nx) if per_mode else (bb,) + spec + (bo, nx)
+    acc = (kf, bb, bo) if per_mode else (bb,) + spec + (kx, bo)
 
-    z_elems = bb * bh * nx * _prod(spec)
-    y_elems = bb * bo * nx * _prod(spec)
-    operands = 2 * (2 * z_elems * cb)                  # zr + zi windows
-    operands += 2 * (2 * bo * bh * wmodes * cb)        # wr + wi windows
-    operands += _mats_bytes(core_mats)                 # f/g operand pairs
-    operands += 2 * (2 * y_elems * cb)                 # yr + yi windows
-    scratch = 2 * (_prod(spec) * kx * bb * bo * ab)
+    operands = 2 * 2 * _tiled((bb, bh, nx) + spec, sd)     # zr + zi
+    operands += 2 * 2 * _tiled(w, sd)                      # wr + wi
+    operands += _mats_bytes(core_mats)                     # f/g pairs
+    operands += 2 * 2 * _tiled(y, sd)                      # yr + yi
+    scratch = 2 * _tiled(acc, ad)
     return LaunchEstimate("core", operands, scratch)
 
 
@@ -156,25 +176,23 @@ def _wgrad_estimate(spatial, modes, bb, bo, bh, per_mode,
     from repro.core import spectral
     from repro.kernels.ops import _mode_pad
 
-    cb = _isz(pol.compute_dtype)
-    ab = _isz(pol.accum_dtype)
-    pb = _isz(pol.param_dtype)
-    sp = _prod(spatial)
+    cd, ad, pd = pol.compute_dtype, pol.accum_dtype, pol.param_dtype
+    sp = tuple(spatial)
     kp = _mode_pad(modes)
     rev = _rev_modes(modes)
-    mats = spectral.wgrad_operand_mats(tuple(spatial), tuple(modes),
+    mats = spectral.wgrad_operand_mats(sp, tuple(modes),
                                        pol.spectral_dtype, kp)
-    dw_elems = (_prod(rev) if per_mode else 1) * bo * bh
+    dw = _weight_window(rev, bo, bh, per_mode)
 
-    operands = 2 * (bb * bh * sp * cb)                 # x window
-    operands += 2 * (bb * bo * sp * cb)                # gz window
+    operands = 2 * _tiled((bb, bh) + sp, cd)               # x window
+    operands += 2 * _tiled((bb, bo) + sp, cd)              # gz window
     operands += _mats_bytes(mats)
-    operands += 2 * (2 * dw_elems * pb)                # dwr + dwi windows
+    operands += 2 * 2 * _tiled(dw, pd)                     # dwr + dwi
+    scratch = 2 * _tiled(dw, ad)
     if with_bypass:
-        operands += 2 * ((bo * bh + bo) * pb)          # dwb + dbias windows
-    scratch = 2 * (dw_elems * ab)
-    if with_bypass:
-        scratch += (bo * bh + bo) * ab
+        operands += 2 * (_tiled((bo, bh), pd)              # dwb + dbias
+                         + _tiled((bo, 1), pd))
+        scratch += _tiled((bo, bh), ad) + _tiled((bo, 1), ad)
     return LaunchEstimate("wgrad", operands, scratch)
 
 
@@ -213,7 +231,7 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
     when the config opts into fuse_ends."""
     from repro import tuning
     from repro.core import spectral
-    from repro.kernels.ops import _mode_pad, _pick_block
+    from repro.kernels.ops import _mode_pad, legal_blocks
 
     h, spatial, modes, per_mode, pol = _norm_shapes(cfg, policy)
     r = len(modes)
@@ -221,35 +239,37 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
         plans = tuning.resolve_launch_plans(
             r, hidden=h, spatial=spatial, modes=modes, per_mode=per_mode,
             policy=pol, override=cfg.block_plan)
-    pbb, _, pbh = plans.for_launch("block_fwd")
-    bb = _pick_block(batch, pbb)
-    bh = _pick_block(h, pbh)
+    bb, _, bh = legal_blocks(r, batch, h, h,
+                            *plans.for_launch("block_fwd"))
     op_ = _rup8(h)                        # bo pinned: single o-grid step
     cinp = _rup8(cfg.in_channels)
     lp = _rup8(cfg.lifting_dim or 2 * h)
     coutp = _rup8(cfg.out_channels)
 
-    cb = _isz(pol.compute_dtype)
-    ab = _isz(pol.accum_dtype)
-    sp = _prod(spatial)
+    cd, ad = pol.compute_dtype, pol.accum_dtype
+    sp = tuple(spatial)
     kp = _mode_pad(modes)
     rev = _rev_modes(modes)
-    mats = spectral.fused_operand_mats(tuple(spatial), tuple(modes),
+    mats = spectral.fused_operand_mats(sp, tuple(modes),
                                        pol.spectral_dtype, False, kp)
-    wmodes = _prod((kp,) if r == 1 else tuple(modes)) if per_mode else 1
+    w = _weight_window(rev, op_, bh, per_mode)
 
-    operands = 2 * (bb * cinp * sp * cb)               # raw-x window
-    operands += 2 * (2 * op_ * bh * wmodes * cb)       # wr + wi windows
-    operands += _mats_bytes(mats)                      # constant-index mats
-    operands += 2 * (bb * coutp * sp * cb)             # y window
-    operands += 2 * (op_ * bh * cb) + 2 * (op_ * cb)   # wb + bias windows
-    operands += (lp * cinp + lp) * cb                  # l1w/l1b (constant)
-    operands += 2 * (bh * lp * cb) + 2 * (bh * cb)     # l2w/l2b (k-grid)
-    operands += (lp * op_ + lp + coutp * lp + coutp) * cb  # proj (constant)
+    operands = 2 * _tiled((bb, cinp) + sp, cd)             # raw-x window
+    operands += 2 * 2 * _tiled(w, cd)                      # wr + wi
+    operands += _mats_bytes(mats)                          # constant mats
+    operands += 2 * _tiled((bb, coutp) + sp, cd)           # y window
+    operands += 2 * (_tiled((op_, bh), cd)                 # wb + bias
+                     + _tiled((op_, 1), cd))
+    operands += _tiled((lp, cinp), cd) + _tiled((lp, 1), cd)  # l1 (const)
+    operands += 2 * (_tiled((bh, lp), cd)                  # l2 (k-grid)
+                     + _tiled((bh, 1), cd))
+    operands += (_tiled((lp, op_), cd) + _tiled((lp, 1), cd)  # proj
+                 + _tiled((coutp, lp), cd) + _tiled((coutp, 1), cd))
 
-    scratch = 2 * (_prod(rev) * bb * op_ * ab)         # accr + acci
-    scratch += op_ * bb * sp * ab                      # bypass accumulator
-    scratch += lp * bb * sp * ab                       # acca (lift prologue)
+    acc = ((_prod(rev), bb, op_) if per_mode else (bb,) + rev + (op_,))
+    scratch = 2 * _tiled(acc, ad)                          # accr + acci
+    scratch += _tiled((op_, bb) + sp, ad)                  # bypass acc
+    scratch += _tiled((lp, bb) + sp, ad)                   # acca (lift)
     return LaunchEstimate("block_fwd_ends", operands, scratch)
 
 
@@ -272,16 +292,13 @@ def launch_estimate(cfg_or_shapes, launch: str,
     """Estimate ONE launch kind under an explicit (bb, bo, bh) block
     preference — the autotuner's pruning oracle and the cache staleness
     re-check. The preference is clamped to the actual dims exactly like
-    the ops layer does at call time (``ops._pick_block``). dx_adjoint
+    the ops layer does at call time (``ops.legal_blocks``). dx_adjoint
     runs with hidden/out swapped in the real kernel; o == h throughout
     this repo's FNO stacks, so the unswapped estimate is exact."""
-    from repro.kernels.ops import _pick_block
+    from repro.kernels.ops import legal_blocks
 
     h, spatial, modes, per_mode, pol = _norm_shapes(cfg_or_shapes, policy)
-    o = h
-    bb = _pick_block(batch, triple[0])
-    bo = _pick_block(o, triple[1])
-    bh = _pick_block(h, triple[2])
+    bb, bo, bh = legal_blocks(len(modes), batch, h, h, *triple)
     if launch == "core":
         return _core_call_estimate(spatial, modes, bb, bo, bh, per_mode,
                                    pol)

@@ -55,9 +55,8 @@ def make_gpipe_fn(stage_fn, *, mesh: Mesh, axis: str, num_stages: int,
                   stage_param_spec, x_spec):
     """shard_map wrapper: returns f(stacked_stage_params, x_mb) -> out.
 
-    Goes through compat_shard_map (the check_rep→check_vma shim, which
-    also disables the replication check this schedule needs off — only
-    the last stage's outputs are real)."""
+    Goes through compat_shard_map, which turns off the replication check
+    this schedule needs off — only the last stage's outputs are real."""
     from repro.distributed.sharding import compat_shard_map
 
     def inner(params, x_mb):

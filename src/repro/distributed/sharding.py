@@ -52,21 +52,11 @@ _TLS = threading.local()
 
 
 def compat_shard_map(f, mesh, in_specs, out_specs):
-    """Version-safe shard_map (ROADMAP.md §JAX version compat): the entry
-    point moved from ``jax.experimental.shard_map`` to ``jax.shard_map``
-    and ``check_rep`` was renamed ``check_vma``. Replication checking is
-    disabled either way — the FNO dispatch closes over custom_vjp pallas
-    wrappers that carry no replication rules."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-        except TypeError:
-            continue
-    raise RuntimeError("no compatible shard_map signature found")
+    """The repo's one ``jax.shard_map`` call site (the AST lint keeps it
+    so). Varying-manual-axes checking is off: the FNO dispatch closes
+    over custom_vjp pallas wrappers that carry no replication rules."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------

@@ -28,8 +28,9 @@ s_{R-1}, …, then s_1, leaving the spectrum as [bb,bh,K_R,…,K_1]:
     A ·(bh) W[bo,bh]                 → acc[bb,K_R..K_1,bo]   (shared W)
     acc ─(R iDFT stages)→ y[bb,bo,s_1..s_R]
 
-For per-mode weights W[bo,bh,K_1..K_R] the CGEMM batches over every
-spectral axis and the accumulator is [K_R..K_1,bb,bo]. Rank 1 reproduces
+For per-mode weights W[bo,bh,K_1..K_R] the CGEMM batches over the
+flattened spectral axes (W laid out [∏K,bo,bh] outside the kernel) and
+the accumulator is [∏K,bb,bo]. Rank 1 reproduces
 the original 1D kernel exactly; rank 2 the full-fusion 2D kernel; rank 3 is
 the new 3D FNO layer.
 
@@ -60,6 +61,7 @@ Three kernel families:
 from __future__ import annotations
 
 import functools
+import math
 from typing import Tuple
 
 import jax
@@ -69,7 +71,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import _compiler_params
 
-_F32 = jnp.float32
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 # Version tag of the engine's launch geometry — the facts a persisted
@@ -84,34 +85,50 @@ _SEMANTICS = ("parallel", "parallel", "arbitrary")
 # OPTIONAL operands absent from every launch kind the cache tunes — an
 # ends-fused launch reuses the block_fwd plan with bo pinned to the padded
 # O, so tuned winners for the default launches stay exactly valid.
-BLOCK_SIGNATURE = ("fnond-v1:grid=(b/bb,o/bo,h/bh);wgrad-grid=(o/bo,h/bh,"
-                   "b/bb);acc=rev_modes@accum+bypass;launches=block_fwd,"
-                   "gz_recompute,dx_adjoint,wgrad,core")
+BLOCK_SIGNATURE = ("fnond-v2:grid=(b/bb,o/bo,h/bh);wgrad-grid=(o/bo,h/bh,"
+                   "b/bb);acc=rev_modes@accum+bypass;"
+                   "per_mode-acc=flat_modes;weights=[H/bh,O,bh];"
+                   "vmem=96MiB;launches=block_fwd,gz_recompute,"
+                   "dx_adjoint,wgrad,core")
 
 
-def _dot(a, b, axis, acc=_F32):
-    """Contract `axis` of a with dim 0 of b; the new dim is appended last.
+def _matmul(acc_dtype: str, interpret: bool):
+    """The kernels' one ``dot_general``: accumulates at the accumulator
+    dtype (PrecisionPolicy.accum_dtype — f32 under the bf16 policy, so
+    only the ref-write boundaries cast down).
 
-    `acc` is the MXU accumulation dtype (PrecisionPolicy.accum_dtype —
-    stays f32 under the bf16 policy so only the ref-write boundaries cast
-    down)."""
-    return jax.lax.dot_general(a, b, (((axis,), (0,)), ((), ())),
-                               preferred_element_type=acc)
+    An interpreted kernel (off-TPU) widens its operands to that dtype
+    first. XLA:CPU cannot execute a bf16×bf16→f32 dot once it sits in a
+    jitted loop ("Unsupported element type for DotThunk::Execute"), and
+    since a bf16 product is exact in f32 the result is the same. A kernel
+    compiled for the TPU hands its operands to the MXU as they are."""
+    acc = jnp.dtype(acc_dtype)
+
+    def mm(a, b, dims):
+        if interpret:
+            a, b = a.astype(acc), b.astype(acc)
+        return jax.lax.dot_general(a, b, dims, preferred_element_type=acc)
+    return mm
 
 
-def _cstage(zr, zi, mr, mi, axis, acc=_F32):
+def _dot(mm, a, b, axis):
+    """Contract `axis` of a with dim 0 of b; the new dim is appended last."""
+    return mm(a, b, (((axis,), (0,)), ((), ())))
+
+
+def _cstage(mm, zr, zi, mr, mi, axis):
     """One complex DFT stage: (zr + i·zi) · (mr + i·mi) along `axis`.
 
     zi=None marks a real input (the first rDFT stage) — the imaginary
     products vanish.
     """
     if zi is None:
-        return _dot(zr, mr, axis, acc), _dot(zr, mi, axis, acc)
-    return (_dot(zr, mr, axis, acc) - _dot(zi, mi, axis, acc),
-            _dot(zr, mi, axis, acc) + _dot(zi, mr, axis, acc))
+        return _dot(mm, zr, mr, axis), _dot(mm, zr, mi, axis)
+    return (_dot(mm, zr, mr, axis) - _dot(mm, zi, mi, axis),
+            _dot(mm, zr, mi, axis) + _dot(mm, zi, mr, axis))
 
 
-def _dft_chain(z, mats, rank, acc=_F32):
+def _dft_chain(mm, z, mats, rank):
     """Run the forward DFT chain over the trailing `rank` spatial axes.
 
     z: [bb,bc,s_1..s_R] real; mats: flat (mr, mi) pairs in stage order
@@ -119,9 +136,53 @@ def _dft_chain(z, mats, rank, acc=_F32):
     """
     zr, zi = z, None
     for i in range(rank):
-        zr, zi = _cstage(zr, zi, mats[2 * i][...], mats[2 * i + 1][...],
-                         1 + rank - i, acc)
+        zr, zi = _cstage(mm, zr, zi, mats[2 * i][...],
+                         mats[2 * i + 1][...], 1 + rank - i)
     return zr, zi
+
+
+# ---------------------------------------------------------------------------
+# Hidden-blocked operand layout. Mosaic tiles the last two dims of every
+# block by (8, 128) unless the block spans the whole array dim, so a
+# [bo, bh] window of an [O, H] weight would force bh to all of H or a
+# multiple of 128. The engine therefore hands Mosaic [O, H] operands as
+# [H/bh, O, bh] — each hidden block its own leading index, its minor dim
+# the whole (bh-wide) array dim — so bh stays a free k-loop tile. The
+# re-layout is an XLA transpose of a weight-sized array, outside the
+# kernel.
+# ---------------------------------------------------------------------------
+def _hidden_blocked(w: jax.Array, bh: int) -> jax.Array:
+    """[…, R, H] -> [H/bh, …, R, bh]."""
+    h = w.shape[-1]
+    return jnp.moveaxis(w.reshape(w.shape[:-1] + (h // bh, bh)), -2, 0)
+
+
+def _hidden_unblocked(w: jax.Array) -> jax.Array:
+    """[H/bh, …, R, bh] -> […, R, H] (inverse of ``_hidden_blocked``)."""
+    t = jnp.moveaxis(w, 0, -2)
+    return t.reshape(t.shape[:-2] + (t.shape[-2] * t.shape[-1],))
+
+
+def _modes_leading(w: jax.Array, rank: int) -> jax.Array:
+    """Per-mode weight [O, H, K_1..K_R] -> [∏K, O, H], modes flattened in
+    the spectrum's K_R..K_1 order: one leading batch dim for the CGEMM
+    (Mosaic's matmul batches over at most one dim)."""
+    t = jnp.transpose(w, tuple(range(1 + rank, 1, -1)) + (0, 1))
+    return t.reshape((-1,) + w.shape[:2])
+
+
+def _modes_major(t: jax.Array) -> jax.Array:
+    """Spectrum [bb, c, K_R..K_1] -> [∏K, bb, c]: the flattened modes as
+    the leading (batch) dim of a per-mode CGEMM or weight reduction."""
+    t = t.reshape(t.shape[:2] + (-1,))
+    return jnp.swapaxes(jnp.swapaxes(t, 1, 2), 0, 1)
+
+
+def _hidden_spec(bo: int, bh: int, index_map) -> pl.BlockSpec:
+    """Window of a hidden-blocked [H/bh, O, bh] operand; index_map gives
+    (hidden block, out block)."""
+    return pl.BlockSpec((None, bo, bh),
+                        lambda *g: tuple(index_map(*g)) + (0,))
 
 
 _GELU_C = 0.7978845608028654  # sqrt(2/pi)
@@ -162,9 +223,11 @@ def _dgelu(z):
 def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
                      has_wb: bool = False, has_bias: bool = False,
                      act: str = "linear", has_lift: bool = False,
-                     has_proj: bool = False):
+                     has_proj: bool = False, interpret: bool = False,
+                     rev_modes: Tuple[int, ...] = ()):
     r = rank
     acc = jnp.dtype(acc_dtype)
+    mm = _matmul(acc_dtype, interpret)
     has_gy = act == "gelu_vjp"
 
     def kernel(*refs):
@@ -201,7 +264,9 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
 
         def _colvec(ref, nd):
             # [D,1] operand broadcast over the trailing batch/spatial dims.
-            return ref[...].reshape((-1,) + (1,) * nd)
+            # Widened to the accumulator dtype first: Mosaic has no
+            # rank-changing shape cast for a packed (bf16) column.
+            return ref[...].astype(acc).reshape((-1,) + (1,) * nd)
 
         @pl.when(pl.program_id(2) == 0)
         def _init():
@@ -212,18 +277,15 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
             if has_lift:
                 # Lift prologue, once per (i,j): a = gelu(W_l1ᵀ·x + b_l1)
                 # → [L, bb, s…], persisted across the hidden k-loop.
-                a = jax.lax.dot_general(
-                    lift_refs[0][...], x_ref[...],
-                    (((1,), (1,)), ((), ())), preferred_element_type=acc)
+                a = mm(lift_refs[0][...], x_ref[...],
+                       (((1,), (1,)), ((), ())))
                 a = a + _colvec(lift_refs[1], 1 + r)
                 acca[...] = jax.nn.gelu(a, approximate=True)
 
         if has_lift:
             # This k step's hidden block: h_k = W_l2ᵀ[k]·a + b_l2[k],
             # realigned [L,bb,…]→[bb,bh,…] by a major-axes swap.
-            hk = jax.lax.dot_general(
-                lift_refs[2][...], acca[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=acc)
+            hk = mm(lift_refs[2][...], acca[...], (((1,), (0,)), ((), ())))
             hk = hk + _colvec(lift_refs[3], 1 + r)
             xblk = jnp.swapaxes(hk, 0, 1).astype(x_ref.dtype)
         else:
@@ -231,21 +293,20 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
 
         # Truncated forward DFT chain — the FFT writing its A-tile to
         # "shared memory" (VMEM registers).
-        ar, ai = _dft_chain(xblk, fwd, r, acc)
+        ar, ai = _dft_chain(mm, xblk, fwd, r)
 
         # CGEMM over hidden (the k-loop MAC).
         wr, wi = wr_ref[...], wi_ref[...]
         if per_mode:
-            # Batch every spectral axis: A's are reversed (K_R..K_1)
-            # relative to W[bo,bh,K_1..K_R].
-            dims = (((1,), (1,)),
-                    (tuple(range(2, 2 + r)), tuple(range(1 + r, 1, -1))))
+            # The modes, flattened, are the one batch dim: A [∏K,bb,bh]
+            # against W [∏K,bo,bh] -> [∏K,bb,bo].
+            ar, ai = _modes_major(ar), _modes_major(ai)
+            dims = (((2,), (2,)), ((0,), (0,)))
         else:
             dims = (((1,), (1,)), ((), ()))
 
         def dg(a, w):
-            return jax.lax.dot_general(a, w, dims,
-                                       preferred_element_type=acc)
+            return mm(a, w, dims)
 
         accr[...] += dg(ar, wr) - dg(ai, wi)
         acci[...] += dg(ar, wi) + dg(ai, wr)
@@ -254,30 +315,31 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
             # Bypass GEMM riding the same k-loop MAC: W_b[bo,bh]·x[bb,bh,s…]
             # → [bo,bb,s…]. The bo-leading layout keeps the minor (spatial)
             # dims in place so the epilogue's realign is a major-axes swap.
-            accb[...] += jax.lax.dot_general(
-                wb_ref[...], xblk, (((1,), (1,)), ((), ())),
-                preferred_element_type=acc)
+            accb[...] += mm(wb_ref[...], xblk, (((1,), (1,)), ((), ())))
 
         @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
         def _epilogue():
             # Padded inverse DFT chain; only the real part of the final
             # stage is materialized (real output).
             tr, ti = accr[...], acci[...]
+            if per_mode:  # [∏K,bb,bo] -> the shared [bb,K_R..K_1,bo]
+                shared = (tr.shape[1],) + rev_modes + (tr.shape[2],)
+                tr, ti = (jnp.swapaxes(t, 0, 1).reshape(shared)
+                          for t in (tr, ti))
             z = None
             for i in range(r):
-                axis = (r - 1 - i) if per_mode else (r - i)
+                axis = r - i
                 mr, mi = inv[2 * i][...], inv[2 * i + 1][...]
                 if i < r - 1:
-                    tr, ti = _cstage(tr, ti, mr, mi, axis, acc)
+                    tr, ti = _cstage(mm, tr, ti, mr, mi, axis)
                 else:
-                    z = (_dot(tr, mr, axis, acc)
-                         - _dot(ti, mi, axis, acc))
+                    z = _dot(mm, tr, mr, axis) - _dot(mm, ti, mi, axis)
             # Block epilogue: + bypass + bias → activation, all on the
             # f32 VMEM values — HBM sees only the final activation.
             if has_wb:
                 z = z + jnp.swapaxes(accb[...], 0, 1)
-            if has_bias:
-                z = z + bias_ref[...].reshape((1, -1) + (1,) * r)
+            if has_bias:  # widen before the reshape (see _colvec)
+                z = z + bias_ref[...].astype(acc).reshape((1, -1) + (1,) * r)
             if act == "gelu":
                 z = jax.nn.gelu(z, approximate=True)
             elif act == "gelu_vjp":
@@ -287,14 +349,11 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
                 # [bb,O,s…] (bo == padded O — single j step): the pointwise
                 # MLP contracts the full hidden width in VMEM and the ref
                 # write emits the model's output channels.
-                a2 = jax.lax.dot_general(
-                    proj_refs[0][...], z.astype(acc),
-                    (((1,), (1,)), ((), ())), preferred_element_type=acc)
+                a2 = mm(proj_refs[0][...], z.astype(acc),
+                        (((1,), (1,)), ((), ())))
                 a2 = jax.nn.gelu(a2 + _colvec(proj_refs[1], 1 + r),
                                  approximate=True)
-                out = jax.lax.dot_general(
-                    proj_refs[2][...], a2, (((1,), (0,)), ((), ())),
-                    preferred_element_type=acc)
+                out = mm(proj_refs[2][...], a2, (((1,), (0,)), ((), ())))
                 z = jnp.swapaxes(out + _colvec(proj_refs[3], 1 + r), 0, 1)
             y_ref[...] = z.astype(y_ref.dtype)
 
@@ -366,11 +425,15 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         x_spec = pl.BlockSpec((bb, bh) + spatial,
                               lambda i, j, k: (i, k) + zr)
     if per_mode:
-        w_spec = pl.BlockSpec((bo, bh) + wr.shape[2:],
-                              lambda i, j, k: (j, k) + zr)
-        acc_shape = rev_modes + (bb, bo)
+        wr, wi = (_hidden_blocked(_modes_leading(w, r), bh)
+                  for w in (wr, wi))
+        kf = wr.shape[1]
+        w_spec = pl.BlockSpec((None, kf, bo, bh),
+                              lambda i, j, k: (k, 0, j, 0))
+        acc_shape = (kf, bb, bo)
     else:
-        w_spec = pl.BlockSpec((bo, bh), lambda i, j, k: (j, k))
+        wr, wi = _hidden_blocked(wr, bh), _hidden_blocked(wi, bh)
+        w_spec = _hidden_spec(bo, bh, lambda i, j, k: (k, j))
         acc_shape = (bb,) + rev_modes + (bo,)
     m_specs = [pl.BlockSpec(m.shape, lambda i, j, k: (0, 0)) for m in mats]
     out_ch = proj[2].shape[0] if proj is not None else o
@@ -386,8 +449,8 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     acc = jnp.dtype(acc_dtype)
     scratch = [pltpu.VMEM(acc_shape, acc), pltpu.VMEM(acc_shape, acc)]
     if wb is not None:
-        operands.append(wb)
-        in_specs.append(pl.BlockSpec((bo, bh), lambda i, j, k: (j, k)))
+        operands.append(_hidden_blocked(wb, bh))
+        in_specs.append(_hidden_spec(bo, bh, lambda i, j, k: (k, j)))
         scratch.append(pltpu.VMEM((bo, bb) + spatial, acc))
     if bias is not None:
         operands.append(bias)
@@ -397,12 +460,15 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         in_specs.append(y_spec)
     if lift is not None:
         l1w, l1b, l2w, l2b = lift
-        operands += [l1w, l1b, l2w, l2b]
+        # The hidden-row operands as [H/bh, bh, …]: whole-dim windows.
+        operands += [l1w, l1b, l2w.reshape(h // bh, bh, -1),
+                     l2b.reshape(h // bh, bh, 1)]
         in_specs += [
             pl.BlockSpec(l1w.shape, lambda i, j, k: (0, 0)),
             pl.BlockSpec(l1b.shape, lambda i, j, k: (0, 0)),
-            pl.BlockSpec((bh, l2w.shape[1]), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((bh, 1), lambda i, j, k: (k, 0)),
+            pl.BlockSpec((None, bh, l2w.shape[1]),
+                         lambda i, j, k: (k, 0, 0)),
+            pl.BlockSpec((None, bh, 1), lambda i, j, k: (k, 0, 0)),
         ]
     if proj is not None:
         operands += list(proj)
@@ -415,7 +481,7 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     return pl.pallas_call(
         _make_fwd_kernel(r, per_mode, acc_dtype, wb is not None,
                          bias is not None, act, lift is not None,
-                         proj is not None),
+                         proj is not None, interpret, rev_modes),
         grid=grid,
         in_specs=in_specs,
         out_specs=y_spec,
@@ -448,9 +514,10 @@ def fused_fno_block_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
 # ---------------------------------------------------------------------------
 @functools.lru_cache(maxsize=None)
 def _make_core_kernel(n_spec: int, per_mode: bool,
-                      acc_dtype: str = "float32"):
+                      acc_dtype: str = "float32", interpret: bool = False,
+                      rev_modes: Tuple[int, ...] = ()):
     s = n_spec  # trailing already-spectral axes (K_R .. K_2)
-    acc = jnp.dtype(acc_dtype)
+    mm = _matmul(acc_dtype, interpret)
 
     def kernel(zr_ref, zi_ref, wr_ref, wi_ref, fr_ref, fi_ref,
                gr_ref, gi_ref, yr_ref, yi_ref, accr, acci):
@@ -461,18 +528,17 @@ def _make_core_kernel(n_spec: int, per_mode: bool,
 
         # Truncated cDFT along s_1 (the GEMM-adjacent stage): contract
         # dim 2 -> [bb,bh,K_R..K_2,K_1].
-        ar, ai = _cstage(zr_ref[...], zi_ref[...], fr_ref[...], fi_ref[...],
-                         2, acc)
+        ar, ai = _cstage(mm, zr_ref[...], zi_ref[...], fr_ref[...],
+                         fi_ref[...], 2)
         wr, wi = wr_ref[...], wi_ref[...]
-        if per_mode:
-            dims = (((1,), (1,)),
-                    (tuple(range(2, 3 + s)), tuple(range(2 + s, 1, -1))))
+        if per_mode:  # as in the full-fusion kernel: [∏K,bb,bh]·[∏K,bo,bh]
+            ar, ai = _modes_major(ar), _modes_major(ai)
+            dims = (((2,), (2,)), ((0,), (0,)))
         else:
             dims = (((1,), (1,)), ((), ()))
 
         def dg(a, w):
-            return jax.lax.dot_general(a, w, dims,
-                                       preferred_element_type=acc)
+            return mm(a, w, dims)
 
         accr[...] += dg(ar, wr) - dg(ai, wi)
         acci[...] += dg(ar, wi) + dg(ai, wr)
@@ -480,9 +546,12 @@ def _make_core_kernel(n_spec: int, per_mode: bool,
         @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
         def _epilogue():
             # Padded icDFT along s_1 (complex output pair).
+            tr, ti = accr[...], acci[...]
+            if per_mode:  # [∏K,bb,bo] -> [K_R..K_1,bb,bo]
+                tr, ti = (t.reshape(rev_modes + t.shape[1:])
+                          for t in (tr, ti))
             axis = s if per_mode else 1 + s
-            tr, ti = _cstage(accr[...], acci[...], gr_ref[...], gi_ref[...],
-                             axis, acc)
+            tr, ti = _cstage(mm, tr, ti, gr_ref[...], gi_ref[...], axis)
             yr_ref[...] = tr.astype(yr_ref.dtype)
             yi_ref[...] = ti.astype(yi_ref.dtype)
 
@@ -513,14 +582,17 @@ def fused_fnond_core_call(zr: jax.Array, zi: jax.Array, wr: jax.Array,
     z_spec = pl.BlockSpec((bb, bh, nx) + spec,
                           lambda i, j, k: (i, k, 0) + zs)
     if per_mode:
-        w_spec = pl.BlockSpec((bo, bh) + wr.shape[2:],
-                              lambda i, j, k: (j, k) + (0,) * (wr.ndim - 2))
+        wr, wi = (_hidden_blocked(_modes_leading(w, s + 1), bh)
+                  for w in (wr, wi))
+        w_spec = pl.BlockSpec((None, wr.shape[1], bo, bh),
+                              lambda i, j, k: (k, 0, j, 0))
         y_shape = spec + (b, o, nx)
         y_spec = pl.BlockSpec(spec + (bb, bo, nx),
                               lambda i, j, k: zs + (i, j, 0))
-        acc_shape = spec + (kx, bb, bo)
+        acc_shape = (math.prod(spec) * kx, bb, bo)
     else:
-        w_spec = pl.BlockSpec((bo, bh), lambda i, j, k: (j, k))
+        wr, wi = _hidden_blocked(wr, bh), _hidden_blocked(wi, bh)
+        w_spec = _hidden_spec(bo, bh, lambda i, j, k: (k, j))
         y_shape = (b,) + spec + (o, nx)
         y_spec = pl.BlockSpec((bb,) + spec + (bo, nx),
                               lambda i, j, k: (i,) + zs + (j, 0))
@@ -530,7 +602,7 @@ def fused_fnond_core_call(zr: jax.Array, zi: jax.Array, wr: jax.Array,
 
     acc = jnp.dtype(acc_dtype)
     return pl.pallas_call(
-        _make_core_kernel(s, per_mode, acc_dtype),
+        _make_core_kernel(s, per_mode, acc_dtype, interpret, spec + (kx,)),
         grid=grid,
         in_specs=[z_spec, z_spec, w_spec, w_spec, mat(fr), mat(fi),
                   mat(gr), mat(gi)],
@@ -561,9 +633,10 @@ def fused_fnond_core_call(zr: jax.Array, zi: jax.Array, wr: jax.Array,
 @functools.lru_cache(maxsize=None)
 def _make_wgrad_kernel(rank: int, per_mode: bool,
                        acc_dtype: str = "float32",
-                       with_bypass: bool = False):
+                       with_bypass: bool = False, interpret: bool = False):
     r = rank
     acc = jnp.dtype(acc_dtype)
+    mm = _matmul(acc_dtype, interpret)
 
     def kernel(*refs):
         x_ref, g_ref = refs[:2]
@@ -588,33 +661,41 @@ def _make_wgrad_kernel(rank: int, per_mode: bool,
                 accwb[...] = jnp.zeros_like(accwb)
                 accdb[...] = jnp.zeros_like(accdb)
 
-        ar, ai = _dft_chain(x_ref[...], xm, r, acc)  # A: [bb,bh,K_R..K_1]
-        hr, hi = _dft_chain(g_ref[...], gm, r, acc)  # Ĝ: [bb,bo,K_R..K_1]
+        def flat(t):  # [bb,c,…] -> [bb,c,∏…]: a minor-dims merge
+            return t.reshape(t.shape[:2] + (-1,))
 
-        if per_mode:  # batch the spectral axes, contract batch
-            dims = (((0,), (0,)),
-                    (tuple(range(2, 2 + r)), tuple(range(2, 2 + r))))
-        else:  # contract batch AND every spectral axis -> [bo,bh]
-            both = (0,) + tuple(range(2, 2 + r))
-            dims = ((both, both), ((), ()))
+        # A: [bb,bh,K_R..K_1] and Ĝ: [bb,bo,K_R..K_1], spectral axes
+        # flattened into the lane dim. Mosaic's matmul contracts exactly
+        # one dim and batches at most one, so the reduction over batch
+        # and modes is a batch-dim matmul over the modes, then a sum.
+        ar, ai = (flat(t) for t in _dft_chain(mm, x_ref[...], xm, r))
+        hr, hi = (flat(t) for t in _dft_chain(mm, g_ref[...], gm, r))
+
+        if per_mode:
+            # Modes become the batch dim ([K,bb,c]); contract batch ->
+            # [K,bo,bh], the accumulator's layout (rev_modes flattened).
+            ar, ai, hr, hi = (_modes_major(t) for t in (ar, ai, hr, hi))
+            dims = (((1,), (1,)), ((0,), (0,)))
+        else:
+            # Batch over bb, contract the flattened modes -> [bb,bo,bh].
+            dims = (((2,), (2,)), ((0,), (0,)))
 
         def rdot(p, q):
-            return jax.lax.dot_general(p, q, dims,
-                                       preferred_element_type=acc)
+            out = mm(p, q, dims)
+            return out if per_mode else jnp.sum(out, axis=0)
 
         accr[...] += rdot(hr, ar) - rdot(hi, ai)
         acci[...] += rdot(hr, ai) + rdot(hi, ar)
 
         if with_bypass:
             # Bypass cotangents from the refs already resident in VMEM:
-            # dW_b = Σ_{b,s} gz·x (contract batch + every spatial axis)
-            # and dbias = Σ_{b,s} gz — no extra HBM pass.
-            sp_axes = (0,) + tuple(range(2, 2 + r))
-            accwb[...] += jax.lax.dot_general(
-                g_ref[...], x_ref[...], ((sp_axes, sp_axes), ((), ())),
-                preferred_element_type=acc)
-            accdb[...] += jnp.sum(g_ref[...].astype(acc),
-                                  axis=sp_axes)[:, None]
+            # dW_b = Σ_{b,s} gz·x and dbias = Σ_{b,s} gz, contracted
+            # over the flattened spatial dims — no extra HBM pass.
+            g3, x3 = flat(g_ref[...]), flat(x_ref[...])
+            accwb[...] += jnp.sum(
+                mm(g3, x3, (((2,), (2,)), ((0,), (0,)))), axis=0)
+            accdb[...] += jnp.sum(jnp.sum(g3.astype(acc), axis=2,
+                                          keepdims=True), axis=0)
 
         @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
         def _epilogue():
@@ -642,8 +723,9 @@ def fused_fnond_wgrad_call(x: jax.Array, g: jax.Array, *mats: jax.Array,
     for g (each [n,k], axis s_R first), as produced by
     ``spectral.wgrad_operand_mats``.
 
-    Returns (dwr, dwi): [O,H] shared, or [K_R..K_1,O,H] per-mode (caller
-    transposes back to [O,H,K_1..K_R]). out_dtype sets the dW emission
+    Returns (dwr, dwi): [O,H] shared, or [∏K,O,H] per-mode with the modes
+    flattened in K_R..K_1 order (caller reshapes and transposes back to
+    [O,H,K_1..K_R]). out_dtype sets the dW emission
     dtype (PrecisionPolicy.param_dtype under mixed precision: cotangents
     accumulate at acc_dtype in VMEM, dW is cast once at the ref write).
 
@@ -663,14 +745,17 @@ def fused_fnond_wgrad_call(x: jax.Array, g: jax.Array, *mats: jax.Array,
     x_spec = pl.BlockSpec((bb, bh) + spatial, lambda i, j, kb: (kb, j) + zr)
     g_spec = pl.BlockSpec((bb, bo) + spatial, lambda i, j, kb: (kb, i) + zr)
     m_specs = [pl.BlockSpec(m.shape, lambda i, j, kb: (0, 0)) for m in mats]
+    # dW leaves hidden-blocked ([H/bh, …, O, bh]) and is re-laid out
+    # after the call.
     if per_mode:
-        dw_spec = pl.BlockSpec(rev_modes + (bo, bh),
-                               lambda i, j, kb: zr + (i, j))
-        dw_shape = rev_modes + (o, h)
-        acc_shape = rev_modes + (bo, bh)
+        kf = math.prod(rev_modes)
+        dw_spec = pl.BlockSpec((None, kf, bo, bh),
+                               lambda i, j, kb: (j, 0, i, 0))
+        dw_shape = (h // bh, kf, o, bh)
+        acc_shape = (kf, bo, bh)
     else:
-        dw_spec = pl.BlockSpec((bo, bh), lambda i, j, kb: (i, j))
-        dw_shape = (o, h)
+        dw_spec = _hidden_spec(bo, bh, lambda i, j, kb: (j, i))
+        dw_shape = (h // bh, o, bh)
         acc_shape = (bo, bh)
     od = jnp.dtype(out_dtype or x.dtype)
     out_sd = jax.ShapeDtypeStruct(dw_shape, od)
@@ -682,13 +767,13 @@ def fused_fnond_wgrad_call(x: jax.Array, g: jax.Array, *mats: jax.Array,
     if with_bypass:
         # dwb [O,H] per (i,j) block; dbias [O,1] is j-independent — every
         # j program re-derives and writes the identical block (idempotent).
-        out_specs += [pl.BlockSpec((bo, bh), lambda i, j, kb: (i, j)),
+        out_specs += [_hidden_spec(bo, bh, lambda i, j, kb: (j, i)),
                       pl.BlockSpec((bo, 1), lambda i, j, kb: (i, 0))]
-        out_shape += [jax.ShapeDtypeStruct((o, h), od),
+        out_shape += [jax.ShapeDtypeStruct((h // bh, o, bh), od),
                       jax.ShapeDtypeStruct((o, 1), od)]
         scratch += [pltpu.VMEM((bo, bh), acc), pltpu.VMEM((bo, 1), acc)]
-    return pl.pallas_call(
-        _make_wgrad_kernel(r, per_mode, acc_dtype, with_bypass),
+    out = pl.pallas_call(
+        _make_wgrad_kernel(r, per_mode, acc_dtype, with_bypass, interpret),
         grid=grid,
         in_specs=[x_spec, g_spec] + m_specs,
         out_specs=out_specs,
@@ -697,3 +782,7 @@ def fused_fnond_wgrad_call(x: jax.Array, g: jax.Array, *mats: jax.Array,
         compiler_params=_compiler_params(dimension_semantics=_SEMANTICS),
         interpret=interpret,
     )(x, g, *mats)
+    dwr, dwi = _hidden_unblocked(out[0]), _hidden_unblocked(out[1])
+    if with_bypass:
+        return dwr, dwi, _hidden_unblocked(out[2]), out[3]
+    return dwr, dwi

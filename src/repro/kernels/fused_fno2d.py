@@ -59,8 +59,8 @@ def fused_fno2d_wgrad_call(x: jax.Array, g: jax.Array, cr: jax.Array,
                            per_mode: bool, interpret: bool = False
                            ) -> Tuple[jax.Array, jax.Array]:
     """x: [B,H,X,Y] primal; g: [B,O,X,Y] cotangent; c,et: [Y,KY];
-    f,gt: [X,KX]. Returns (dwr, dwi): [O,H] shared or [KY,KX,O,H] per-mode
-    (caller transposes back to [O,H,KX,KY])."""
+    f,gt: [X,KX]. Returns (dwr, dwi): [O,H] shared or [KY·KX,O,H]
+    per-mode (caller reshapes and transposes back to [O,H,KX,KY])."""
     return engine.fused_fnond_wgrad_call(x, g, cr, ci, fr, fi, etr, eti,
                                          gtr, gti, bb=bb, bo=bo, bh=bh,
                                          per_mode=per_mode,

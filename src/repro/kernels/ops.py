@@ -73,12 +73,31 @@ def _pick_block(dim: int, pref: int) -> int:
     return min(cands, key=lambda b: (_rup(dim, b), -b))
 
 
+def legal_blocks(rank: int, b: int, o: int, h: int, bb: int, bo: int,
+                 bh: int) -> Tuple[int, int, int]:
+    """The (bb, bo, bh) every engine launch of a rank-R layer runs for
+    dims (B, O, H) under a block preference — the one clamp the ops
+    layer, the tuner and the VMEM estimator share. Mosaic tiles the last
+    two dims of every block by (8, 128) unless the block spans the whole
+    array dim. bo is the second-minor dim of the weight and bias windows,
+    so it is a multiple of 8 (O is padded to it). bh is a free k-loop tile
+    (the engine lays weights out hidden-blocked), except at rank 1, where
+    it is the second-minor dim of the [bb, bh, N] activation window."""
+    bh = _pick_block(h, max(bh, 8) if rank == 1 else bh)
+    return _pick_block(b, bb), _pick_block(o, max(bo, 8)), bh
+
+
+def tiling_legal(rank: int, o: int, h: int, bo: int, bh: int) -> bool:
+    """Whether (bo, bh) satisfies the tiling rule for a rank-R layer with
+    dims (O, H) — see :func:`legal_blocks`."""
+    return ((bo % 8 == 0 or bo == o)
+            and (rank > 1 or bh % 8 == 0 or bh == h))
+
+
 def _blocks(x, o, bb, bo, bh):
     """Resolve (bb,bo,bh) block sizes and padded (B,O,H) for x[B,H,...]."""
     b, h = x.shape[:2]
-    bb = _pick_block(b, bb)
-    bo = _pick_block(o, bo)
-    bh = _pick_block(h, bh)
+    bb, bo, bh = legal_blocks(x.ndim - 2, b, o, h, bb, bo, bh)
     return bb, bo, bh, _rup(b, bb), _rup(o, bo), _rup(h, bh)
 
 
@@ -411,11 +430,12 @@ def _fnond_wgrad(x, gy, modes, bb, bo, bh, interpret, per_mode, pol,
         acc_dtype=pol.accum_dtype, with_bypass=with_bypass)
     dwr, dwi = out[:2]
     extra = (out[2][:o, :h], out[3][:o, 0]) if with_bypass else ()
-    if per_mode:  # kernel emits [K_R..K_1,O,H] -> [O,H,K_1..K_R]
+    if per_mode:  # kernel emits [∏(K_R..K_1),O,H] -> [O,H,K_1..K_R]
+        rev = tuple(m.shape[1] for m in mats[:2 * r:2])
         perm = (r, r + 1) + tuple(range(r - 1, -1, -1))
         sl = (slice(o), slice(h)) + tuple(slice(m) for m in modes)
-        return (jnp.transpose(dwr, perm)[sl],
-                jnp.transpose(dwi, perm)[sl]) + extra
+        unflat = lambda d: jnp.transpose(d.reshape(rev + d.shape[1:]), perm)
+        return (unflat(dwr)[sl], unflat(dwi)[sl]) + extra
     return (dwr[:o, :h], dwi[:o, :h]) + extra
 
 
@@ -505,7 +525,9 @@ def _fnond_xla(x, wr, wi, modes, pol=None):
 # Block selection is owned by ``repro.tuning.resolve_launch_plans``
 # (override → tuned cache → this table); nothing outside the resolver
 # and the legacy ``analysis.vmem.resolve_blocks`` helper should read it.
-_BLOCK_DEFAULTS = {1: (8, 128, 128), 2: (2, 128, 32), 3: (1, 128, 16)}
+# Ranks ≥ 2 hold one sample's whole spatial slab per program, so their
+# batch block is 1.
+_BLOCK_DEFAULTS = {1: (8, 128, 128), 2: (1, 128, 32), 3: (1, 128, 16)}
 
 
 def _resolve_blocks(rank, bb, bo, bh):
@@ -828,14 +850,11 @@ def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
     o = wr.shape[0]
     h = lift[2].shape[1] if lift is not None else x.shape[1]
     kp = _mode_pad(modes)
-    pbb, pbo, pbh = plans.fwd
-    bb = _pick_block(b, pbb)
-    bh = _pick_block(h, pbh)
+    bb, bo, bh = legal_blocks(r, b, o, h, *plans.fwd)
     bp, hp = _rup(b, bb), _rup(h, bh)
     if proj is not None:
         bo = op_ = _rup(o, 8)
     else:
-        bo = _pick_block(o, pbo)
         op_ = _rup(o, bo)
     mats = spectral.fused_operand_mats(
         tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype, False,

@@ -12,30 +12,23 @@ from typing import Optional, Tuple
 import jax
 
 
-def make_compat_mesh(shape, axes):
-    """Version-safe ``jax.make_mesh`` — the single AxisType shim point
-    (ROADMAP.md §JAX version compat).
-
-    jax.sharding.AxisType landed after 0.4.x; omit axis_types when absent
-    (pre-AxisType meshes behave as Auto on every axis).
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type.Auto,) * len(axes)}
-              if axis_type is not None else {})
-    return jax.make_mesh(shape, axes, **kwargs)
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis explicitly ``Auto``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     if multi_pod:
-        return make_compat_mesh((2, 16, 16), ("pod", "data", "model"))
-    return make_compat_mesh((16, 16), ("data", "model"))
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh for subprocess tests on N virtual CPU devices."""
     if pod:
-        return make_compat_mesh((pod, data, model), ("pod", "data", "model"))
-    return make_compat_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 def batch_axes(mesh) -> Tuple[str, ...]:

@@ -19,12 +19,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import FNO_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tf
 from repro.models.frontend import fake_frontend_arrays
 from repro.train import serve_step
 
 
 def main() -> None:
+    enable_compile_cache()
     peek = argparse.ArgumentParser(add_help=False)
     peek.add_argument("--arch", default="qwen2-1.5b")
     known, _ = peek.parse_known_args()

@@ -26,7 +26,7 @@ from repro.configs import FNO_IDS, get_config
 from repro.configs.fno import with_precision
 from repro.core import fno as fno_mod
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.train import serve_fno_step as sfs
 
 
@@ -86,7 +86,10 @@ def _pick_tp(n_dev: int, hidden: int) -> int:
     return best
 
 
-def run(args) -> dict:
+def build(args):
+    """(cfg, ctx, params, dp, tp): the configured model, its DP×TP
+    sharding context over the local devices, and its seeded params —
+    what every serving mode (and ``chip_smoke.py``) starts from."""
     cfg = with_precision(get_config(args.arch, reduced=args.reduced),
                          args.dtype)
     fuse = args.path == "pallas" and not args.no_fuse_block
@@ -100,11 +103,16 @@ def run(args) -> dict:
             f"serve_fno: requested mesh dp{dp}xtp{tp} needs {dp * tp} "
             f"devices but only {n_dev} are visible — pass --dp/--tp whose "
             f"product fits the host (or omit them for the auto grid)")
-    mesh = make_compat_mesh((dp, tp), ("data", "model"))
+    mesh = make_mesh((dp, tp), ("data", "model"))
     ctx = shd.make_context(cfg, mesh, kind="serve")
+    params = fno_mod.init_fno(jax.random.PRNGKey(0), cfg)
+    return cfg, ctx, params, dp, tp
 
+
+def run(args) -> dict:
+    cfg, ctx, params, dp, tp = build(args)
+    fuse = cfg.fuse_block
     key = jax.random.PRNGKey(0)
-    params = fno_mod.init_fno(key, cfg)
     if args.chaos:
         return _run_chaos(args, cfg, ctx, params, key, dp, tp)
     if args.replay:

@@ -275,8 +275,8 @@ def count(hlo_text: str) -> Counts:
 # pallas_calls lower to plain HLO ops, so the kernel-launch regression guard
 # ("one FNO block == one pallas_call", analysis/jaxpr_lint.py) must count at
 # the jaxpr level, recursing through pjit / custom_vjp / scan / shard_map
-# sub-jaxprs. Duck-typed (hasattr) rather than imported so it survives the
-# jax.core → jax.extend.core migration (ROADMAP.md §JAX version compat).
+# sub-jaxprs. Duck-typed (hasattr): sub-jaxprs hide under several param
+# names and types, so nothing is imported from jax's core modules.
 # ---------------------------------------------------------------------------
 def iter_jaxpr_eqns(jaxpr, into_kernels: bool = True):
     """Yield every eqn of `jaxpr` and of all nested sub-jaxprs (pjit

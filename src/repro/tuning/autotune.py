@@ -5,18 +5,21 @@ TVM/Ansor-shaped search specialized to the fused FNO engine's tiny
 config matrix can emit (all ``FNO_IDS`` × {full, reduced} × {f32, bf16}
 × launch kinds):
 
-1. **generate** the candidate grid — bb ∈ {1, 2, 4, 8}, bo/bh ∈ {8, 16,
-   32, 64, 128}, plus the rank's static default — clamped to the probe
-   dims (``ops._pick_block``) and deduped on the effective triple;
+1. **generate** the candidate grid — bb ∈ {1, 2, 4, 8}, bo/bh ∈ {1, 2,
+   …, 128}, plus the rank's static default — clamped to the probe
+   dims and to Mosaic's tiling rule (``ops.legal_blocks``) and deduped on
+   the effective triple;
 2. **prune** statically with ``analysis.vmem.launch_estimate`` against
-   ``VMEM_BUDGET_BYTES`` — the estimator is deliberately a floor, so
-   anything it rejects is certainly infeasible on hardware;
+   ``VMEM_BUDGET_BYTES`` (the limit the kernels compile under);
 3. **measure** the top-K surviving candidates (static score: least pad
    waste, then largest bo/bh/bb) with the bench harness
    (``benchmarks.common.time_fn``) over jit-wrapped interpret-mode
    launches — only for probes small enough to interpret
    (``hidden·∏spatial ≤ MEASURE_ELEMS``; the full-size 2D/3D grids keep
-   their statically-scored winner, flagged ``source: "estimated"``);
+   their statically-scored winner, which is compiled for a described v5e
+   where libtpu is installed — a plan the compiler refuses for VMEM falls
+   through to the next, and the winner is flagged ``source: "compiled"``;
+   elsewhere ``"estimated"``);
 4. **persist** winners + evidence (VMEM estimate, wall time, probe
    shapes) via ``store.save_cache`` — the committed
    ``tuning/cache/blocks.json`` that ``resolve_launch_plans`` reads.
@@ -40,12 +43,20 @@ from repro.tuning import store
 MEASURE_ELEMS = 131_072
 
 _BB_GRID = (1, 2, 4, 8)
-# bo/bh down to 1: the big full-size spatial grids (fno3d keeps 2·bh·∏s
-# f32 elements of x windows resident) are only VMEM-feasible with bh < 8,
-# trading MXU tile width for fitting the budget at all.
+# Preferences only: ops.legal_blocks clamps every one to a plan Mosaic's
+# tiling rule admits (bo a multiple of 8; bh too at rank 1) and the
+# candidates dedupe on the clamped triple. bh down to 1: the full-size 3D
+# grid keeps bh·∏s elements of x resident per program.
 _BOH_GRID = (1, 2, 4, 8, 16, 32, 64, 128)
 _TOP_K = 3
 _PROBE_BATCH = 8
+_COMPILE_TRIES = 8
+# Static ranking prefers the largest batch block whose program stays under
+# a quarter of the VMEM budget. A larger program buys no MXU efficiency
+# (a full-width 2D slab already gives its DFT matmuls 8192 rows) and
+# Mosaic's compile time grows with it: fno2d block_fwd compiled for a
+# described v5e in 8.4 s at bb=1, ~20 s at bb=2, ~68 s at bb=4.
+_SOFT_BUDGET_FRACTION = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,14 +129,13 @@ def _candidates(w: Workload) -> List[Tuple[int, int, int]]:
     """Candidate grid, clamped to the probe dims and deduped on the
     effective triple (two preferences that clamp to the same launch are
     the same candidate)."""
-    from repro.kernels.ops import _BLOCK_DEFAULTS, _pick_block
+    from repro.kernels.ops import _BLOCK_DEFAULTS, legal_blocks
 
     raw = list(itertools.product(_BB_GRID, _BOH_GRID, _BOH_GRID))
     raw.append(_BLOCK_DEFAULTS[w.rank])
     seen, out = set(), []
-    for bb, bo, bh in raw:
-        eff = (_pick_block(_PROBE_BATCH, bb), _pick_block(w.hidden, bo),
-               _pick_block(w.hidden, bh))
+    for t in raw:
+        eff = legal_blocks(w.rank, _PROBE_BATCH, w.hidden, w.hidden, *t)
         if eff not in seen:
             seen.add(eff)
             out.append(eff)
@@ -144,9 +154,15 @@ def _pad_waste(w: Workload, t: Tuple[int, int, int]) -> float:
 
 def _static_rank(w: Workload, feasible):
     """Least pad waste first, then the largest bo (widest MXU output
-    tile), bh (longest k-loop windows), bb (fewest batch launches)."""
+    tile), bh (longest k-loop windows); then the largest bb (fewest batch
+    launches) among programs within the soft budget, the smallest
+    program beyond it."""
+    from repro.analysis.vmem import VMEM_BUDGET_BYTES
+
+    soft = VMEM_BUDGET_BYTES // _SOFT_BUDGET_FRACTION
     return sorted(feasible, key=lambda e: (
-        _pad_waste(w, e[0]), -e[0][1], -e[0][2], -e[0][0]))
+        _pad_waste(w, e[0]), -e[0][1], -e[0][2], max(e[1], soft),
+        -e[0][0]))
 
 
 def _feasible(w: Workload) -> Dict[str, List[Tuple[Tuple[int, int, int],
@@ -190,56 +206,108 @@ def _probe_arrays(w: Workload):
     return x, wr, wi, wb, bias, gy
 
 
-def _launch_fn(w: Workload, launch: str, triple: Tuple[int, int, int]):
-    """A jitted zero-arg closure running ONE launch of the given kind at
-    the probe shapes — the same internal entry points the custom_vjps
-    call, so measured time ranks real launches."""
-    import jax
+def _launch(w: Workload, launch: str, triple: Tuple[int, int, int],
+            interpret: bool):
+    """ONE launch of the given kind as fn(x, wr, wi, wb, bias, gy) — the
+    same internal entry points the custom_vjps call, so what is timed or
+    compiled is a real launch."""
     import jax.numpy as jnp
 
     from repro.kernels import ops
 
-    x, wr, wi, wb, bias, gy = _probe_arrays(w)
     bb, bo, bh = triple
     pol = w.policy()
     modes = w.modes
-
     if launch == "block_fwd":
-        def fn():
-            return ops._fnond_fused(x, wr, wi, modes, bb, bo, bh, True, pol,
-                                    wb=wb, bias=bias, act="gelu")
-    elif launch == "core":
-        def fn():
-            return ops._fnond_partial(x, wr, wi, modes, bb, bo, bh, True,
-                                      pol)
-    elif launch == "gz_recompute":
-        def fn():
-            return ops._fnond_fused(x, wr, wi, modes, bb, bo, bh, True, pol,
-                                    wb=wb, bias=bias, gy=gy, act="gelu_vjp")
-    elif launch == "dx_adjoint":
-        def fn():
-            return ops._fnond_fused(
-                gy, jnp.swapaxes(wr, 0, 1), jnp.swapaxes(wi, 0, 1), modes,
-                bb, bo, bh, True, pol, adjoint=True,
-                wb=jnp.swapaxes(wb, 0, 1))
-    else:  # wgrad
-        def fn():
-            return ops._fnond_wgrad(x, gy, modes, bb, bo, bh, True,
-                                    per_mode=w.per_mode, pol=pol,
-                                    with_bypass=True)
-    return jax.jit(fn)
+        return lambda x, wr, wi, wb, bias, gy: ops._fnond_fused(
+            x, wr, wi, modes, bb, bo, bh, interpret, pol, wb=wb, bias=bias,
+            act="gelu")
+    if launch == "core":
+        return lambda x, wr, wi, wb, bias, gy: ops._fnond_partial(
+            x, wr, wi, modes, bb, bo, bh, interpret, pol)
+    if launch == "gz_recompute":
+        return lambda x, wr, wi, wb, bias, gy: ops._fnond_fused(
+            x, wr, wi, modes, bb, bo, bh, interpret, pol, wb=wb, bias=bias,
+            gy=gy, act="gelu_vjp")
+    if launch == "dx_adjoint":
+        return lambda x, wr, wi, wb, bias, gy: ops._fnond_fused(
+            gy, jnp.swapaxes(wr, 0, 1), jnp.swapaxes(wi, 0, 1), modes, bb,
+            bo, bh, interpret, pol, adjoint=True, wb=jnp.swapaxes(wb, 0, 1))
+    return lambda x, wr, wi, wb, bias, gy: ops._fnond_wgrad(
+        x, gy, modes, bb, bo, bh, interpret, per_mode=w.per_mode, pol=pol,
+        with_bypass=True)
 
 
 def _measure(w: Workload, launch: str, triple, iters: int) -> float:
     import sys
+
+    import jax
 
     bench = _bench_dir()
     if bench not in sys.path:  # the harness is a top-level dir, not a pkg
         sys.path.insert(0, bench)
     from common import time_fn
 
-    fn = _launch_fn(w, launch, triple)
-    return time_fn(fn, warmup=1, iters=iters)
+    args = _probe_arrays(w)
+    fn = _launch(w, launch, triple, interpret=True)
+    return time_fn(jax.jit(lambda: fn(*args)), warmup=1, iters=iters)
+
+
+# ---------------------------------------------------------------------------
+# The compiler's verdict: a statically ranked winner is compiled for a
+# described TPU v5e (no chip needed, only libtpu). A plan Mosaic refuses —
+# most often for VMEM the estimate does not see, such as the in-kernel
+# relayouts of the per-mode CGEMM — falls through to the next candidate.
+# ---------------------------------------------------------------------------
+def _compiled_choice(w: Workload, launch: str, fits, chip, key: str, log):
+    """Walk the ranking until a plan compiles: (triple, est, source). A
+    VMEM refusal moves on to the next candidate whose estimate is at most
+    3/4 of the refused one's (the estimate misses the compiler's internal
+    scratch, so its neighbours would be refused too); any other refusal
+    is about the launch, not the plan, so the static winner stays
+    ("estimated") and the refusal is logged."""
+    cap, tries = float("inf"), 0
+    for t, est in fits:
+        if est > cap:
+            continue
+        why = _compile_refusal(w, launch, t, chip)
+        if why is None:
+            return t, est, "compiled"
+        log(f"  {key}: {t} refused: {why}")
+        tries += 1
+        if "memory space vmem" not in why or tries == _COMPILE_TRIES:
+            break
+        cap = 0.75 * est
+    return fits[0][0], fits[0][1], "estimated"
+
+
+def _described_chip():
+    """A one-device sharding on a described v5e, or None without libtpu."""
+    try:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception:  # noqa: BLE001 — no TPU compiler installed
+        return None
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_refusal(w: Workload, launch: str, triple, chip
+                     ) -> Optional[str]:
+    """None when the launch compiles for the described chip, else the
+    first line of the compiler's refusal."""
+    import jax
+
+    shapes = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+                   for a in jax.eval_shape(lambda: _probe_arrays(w)))
+    try:
+        jax.jit(_launch(w, launch, triple, interpret=False)).lower(
+            *shapes).compile()
+    except Exception as e:  # noqa: BLE001 — a refusal is a search result
+        return str(e).strip().splitlines()[0][:200]
+    return None
 
 
 def _bench_dir() -> str:
@@ -258,9 +326,15 @@ def tune(measure: str = "auto", smoke: bool = False,
     """Run the full search and persist the cache. ``measure``: "auto"
     (probes under :data:`MEASURE_ELEMS` get wall-timed), "all" (force
     timing everywhere — slow off-TPU), "none" (static scores only —
-    the CI smoke mode). Returns (cache_path, entries)."""
+    the CI smoke mode). Statically scored full-size winners are compiled
+    for a described v5e where libtpu is installed (``smoke`` skips it).
+    Returns (cache_path, entries)."""
     assert measure in ("auto", "all", "none"), measure
     entries: Dict[str, dict] = {}
+    chip = None if smoke else _described_chip()
+    if not smoke and chip is None:
+        log("  !! no v5e can be described here: full-size winners are "
+            "not compile-checked")
     for w in tunable_workloads(smoke=smoke):
         feasible = _feasible(w)
         timed = measure == "all" or (measure == "auto"
@@ -291,9 +365,13 @@ def tune(measure: str = "auto", smoke: bool = False,
                              source="measured")
             else:
                 t, est = fits[0]
-                log(f"  {key}: {t} ({est / 2**20:.1f} MiB est, static)")
+                source = "estimated"
+                if chip is not None and w.elems > MEASURE_ELEMS:
+                    t, est, source = _compiled_choice(w, launch, fits,
+                                                      chip, key, log)
+                log(f"  {key}: {t} ({est / 2**20:.1f} MiB est, {source})")
                 entry.update(bb=t[0], bo=t[1], bh=t[2], est_bytes=est,
-                             source="estimated")
+                             source=source)
             entries[key] = entry
     path = store.save_cache(entries, meta={"measure": measure,
                                            "smoke": smoke}, path=out)

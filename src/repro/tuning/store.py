@@ -12,8 +12,9 @@ Staleness contract (``check_tuning_cache``, wired into ``scripts/lint.py
 ``kernels.engine.BLOCK_SIGNATURE`` and ``meta.vmem_budget_bytes`` the
 current budget — a cache tuned against an older launch geometry or
 budget is an error, not a silent fallback. Each entry must parse as a
-valid key, carry a positive triple, and (re-estimated against its
-recorded probe shapes with the CURRENT estimator) still fit the budget.
+valid key, carry a positive triple that Mosaic's tiling rule admits
+(``ops.tiling_legal``), and (re-estimated against its recorded probe
+shapes with the CURRENT estimator) still fit the budget.
 """
 from __future__ import annotations
 
@@ -92,6 +93,7 @@ def check_tuning_cache(path: Optional[str] = None) -> List[Finding]:
     from repro.analysis.vmem import VMEM_BUDGET_BYTES, launch_estimate
     from repro.configs.base import PrecisionPolicy
     from repro.kernels.engine import BLOCK_SIGNATURE
+    from repro.kernels.ops import tiling_legal
     from repro.tuning import plans as P
 
     path = path or DEFAULT_CACHE_PATH
@@ -143,6 +145,16 @@ def check_tuning_cache(path: Optional[str] = None) -> List[Finding]:
             findings.append(Finding("tuning-cache", f"{rel}::{key}",
                                     "entry lacks the probe shapes needed "
                                     "to re-check feasibility"))
+            continue
+        hidden = int(probe["hidden"])
+        if not tiling_legal(parsed["rank"], hidden, hidden, triple[1],
+                            triple[2]):
+            findings.append(Finding(
+                "tuning-cache", f"{rel}::{key}",
+                f"illegal plan: (bo, bh)={triple[1:]} breaks Mosaic's "
+                f"(8, 128) tiling rule for hidden={hidden} (bo, and bh at "
+                f"rank 1, a multiple of 8 or the whole dim) — the kernel "
+                f"cannot compile for a TPU; regenerate the cache"))
             continue
         # Refit against the CURRENT estimator: a winner that no longer
         # fits means the byte model (or kernel) moved under the cache.

@@ -53,11 +53,11 @@ def test_gpipe_matches_sequential(subproc):
     subproc("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.distributed.pipeline import make_gpipe_fn
 
     S, M, mb, d = 4, 6, 2, 16
-    mesh = make_compat_mesh((S,), ("stage",))
+    mesh = make_mesh((S,), ("stage",))
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (S, d, d)) / d**0.5
     x = jax.random.normal(key, (M, mb, d))
@@ -83,19 +83,19 @@ def test_compressed_psum_error_feedback(subproc):
     subproc("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed import compression as comp
-    from repro.launch.mesh import make_compat_mesh
+    from repro.distributed.sharding import compat_shard_map
+    from repro.launch.mesh import make_mesh
 
     n = 8
-    mesh = make_compat_mesh((n,), ("dp",))
+    mesh = make_mesh((n,), ("dp",))
     key = jax.random.PRNGKey(0)
     g = jax.random.normal(key, (n, 64, 64))
 
     def one(gs, res):
         return comp.ef_psum(gs, res, "dp")
-    f = shard_map(one, mesh=mesh, in_specs=(P("dp"), P("dp")),
-                  out_specs=(P("dp"), P("dp")), check_rep=False)
+    f = compat_shard_map(one, mesh, in_specs=(P("dp"), P("dp")),
+                         out_specs=(P("dp"), P("dp")))
 
     res = jnp.zeros_like(g)
     exact = jnp.sum(g, axis=0)
@@ -167,8 +167,7 @@ def test_reduced_cells_compile_multipod(subproc, arch, shape):
     cell = cm.build_cell("{arch}", "{shape}", mesh, reduced=True)
     j = jax.jit(cell.step_fn, in_shardings=cell.in_shardings)
     co = j.lower(*cell.args).compile()
-    from repro.roofline.compat import cost_analysis_dict
-    ca = cost_analysis_dict(co)
+    ca = co.cost_analysis()
     assert ca.get("flops", 0) > 0
     print("cell OK", "{arch}", "{shape}")
     """)
@@ -590,8 +589,7 @@ def test_fno_cells_compile_dp_tp(subproc, shape, kw, want_tp):
     assert (cell.ctx.model_axis == "model") == {want_tp}, cell.ctx
     j = jax.jit(cell.step_fn, in_shardings=cell.in_shardings)
     co = j.lower(*cell.args).compile()
-    from repro.roofline.compat import cost_analysis_dict
-    ca = cost_analysis_dict(co)
+    ca = co.cost_analysis()
     assert ca.get("flops", 0) > 0
     print("fno cell OK", "{shape}")
     """)

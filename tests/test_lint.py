@@ -133,9 +133,9 @@ def test_mutation_doubled_psum_fires_collective_checker():
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import compat_shard_map
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jnp.zeros((4, 4))
 
     def once(xl):
@@ -158,9 +158,9 @@ def test_mutation_foreign_collective_fires_collective_checker():
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import compat_shard_map
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_compat_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     x = jnp.zeros((4, 4))
 
     def gathers(xl):
@@ -181,9 +181,9 @@ def test_mutation_scatter_budget_fires_both_ways():
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import compat_shard_map
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_compat_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jnp.zeros((4, 4))
 
     def scatters(xl):
@@ -359,16 +359,20 @@ def _lint_snippet(tmp_path, rel, code):
 
 def test_mutation_raw_shard_map_import_fires(tmp_path):
     fs = _lint_snippet(tmp_path, "distributed/rogue.py", """
-        from jax.experimental.shard_map import shard_map
+        import jax
+        f = jax.shard_map(lambda x: x, mesh=None, in_specs=(),
+                          out_specs=())
         """)
     assert len(fs) == 1 and fs[0].checker == "compat-shard-map"
     assert "compat_shard_map" in fs[0].message
-    assert fs[0].target == "distributed/rogue.py:2"
+    assert fs[0].target == "distributed/rogue.py:3"
 
 
 def test_shard_map_home_is_exempt(tmp_path):
     fs = _lint_snippet(tmp_path, "distributed/sharding.py", """
-        from jax.experimental.shard_map import shard_map
+        import jax
+        f = jax.shard_map(lambda x: x, mesh=None, in_specs=(),
+                          out_specs=())
         """)
     assert fs == []
 

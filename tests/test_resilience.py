@@ -518,13 +518,13 @@ def test_resilient_server_on_dp_mesh(subproc):
     from repro.core import fno as fno_mod
     from repro.distributed import faults as flt
     from repro.distributed import sharding as shd
-    from repro.launch.mesh import make_compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.train import serve_runtime as srt
 
     assert jax.device_count() == 8
     cfg = dataclasses.replace(get_config("fno2d", reduced=True),
                               path="pallas", fuse_block=True)
-    mesh = make_compat_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     ctx = shd.make_context(cfg, mesh, kind="serve")
     params = fno_mod.init_fno(jax.random.PRNGKey(0), cfg)
     plan = flt.FaultPlan([flt.Fault("kernel", at=0),

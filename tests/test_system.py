@@ -58,3 +58,24 @@ def test_all_configs_resolve():
         cfg = get_config(arch)
         red = get_config(arch, reduced=True)
         assert red.param_count() < cfg.param_count()
+
+
+def test_compile_cache_dir(monkeypatch):
+    """The persistent compile cache: $JAX_COMPILATION_CACHE_DIR wins and
+    nothing is set over it; otherwise the fixed .jax_cache/ at the
+    checkout root."""
+    import os
+
+    from repro.launch import compile_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert compile_cache.enable_compile_cache() == "/elsewhere"
+    assert updates == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(root, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)]

@@ -112,6 +112,41 @@ def test_cache_staleness_lint_fires(tmp_path):
     assert len(fs) == 1 and fs[0].severity == "warn"
 
 
+def test_illegal_plan_fires_tiling_lint(tmp_path):
+    # Mutation: a winner whose bo breaks Mosaic's (8, 128) tiling rule —
+    # the described-chip compile refuses such a kernel outright.
+    path = str(tmp_path / "illegal.json")
+    good = plans.plan_key(2, plans.shape_class(16, 16, (32, 32), (8, 8)),
+                          "shared", "f32", "block_fwd")
+    bad = plans.plan_key(1, plans.shape_class(16, 16, (64,), (16,)),
+                         "shared", "f32", "block_fwd")
+    store.save_cache({
+        good: _entry(8, 16, 4, probe={"batch": 8, "hidden": 16,
+                                      "spatial": [32, 32], "modes": [8, 8]}),
+        bad: _entry(8, 4, 16),
+    }, path=path)
+    fs = store.check_tuning_cache(path)
+    assert [f.target.split("::")[1] for f in fs] == [bad], fs
+    assert "illegal plan" in fs[0].message
+
+
+@pytest.mark.parametrize("rank", [1, 2, 3])
+def test_legal_blocks_obey_tiling_rule(rank):
+    # Every preference clamps to a plan the tiling rule admits, for
+    # aligned, ragged and tiny dims alike.
+    for dim in (1, 8, 16, 20, 64, 129, 200):
+        for pref in (1, 2, 4, 8, 16, 32, 64, 128):
+            bb, bo, bh = ops.legal_blocks(rank, 8, dim, dim, pref, pref,
+                                          pref)
+            o_pad, h_pad = -dim % bo + dim, -dim % bh + dim
+            assert ops.tiling_legal(rank, o_pad, h_pad, bo, bh), (
+                rank, dim, pref, bo, bh)
+            assert bo % 8 == 0 and h_pad - dim < max(dim, 8), (dim, bh)
+    # rank >= 2 keeps a small hidden tile free; rank 1 may not
+    assert ops.legal_blocks(2, 8, 64, 64, 1, 8, 4)[2] == 4
+    assert ops.legal_blocks(1, 8, 64, 64, 1, 8, 4)[2] == 8
+
+
 def test_committed_cache_is_fresh():
     fs = [f for f in store.check_tuning_cache() if f.severity == "error"]
     assert fs == [], fs
