@@ -106,12 +106,16 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
     ctx = current_context()
     ends_on = fuse and cfg.fuse_ends and (ctx is None
                                           or ctx.model_axis is None)
+    # The staged ends run under the named scopes "lift" and "projection",
+    # which the compiled ops carry in their op_name (the device trace's
+    # labels for them); the fused ends are the block_ends launches.
     if ends_on:
         h = x
     else:
-        h = jax.nn.gelu(_dense(params["lift1"], x))
-        h = _dense(params["lift2"], shard_activation(h, "fno_lift"))
-        h = shard_activation(h, "fno_hidden")
+        with jax.named_scope("lift"):
+            h = jax.nn.gelu(_dense(params["lift1"], x))
+            h = _dense(params["lift2"], shard_activation(h, "fno_lift"))
+            h = shard_activation(h, "fno_hidden")
     # An explicit cfg.block_plan pins the kernel launch plans; otherwise
     # the ops layer resolves them from the tuned cache (repro.tuning).
     bkw = {"block_plan": cfg.block_plan} if cfg.block_plan else {}
@@ -157,8 +161,10 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
         h = shard_activation(h, "fno_hidden")
     if ends_on:
         return shard_activation(h, "fno")
-    out = _dense(params["proj2"], jax.nn.gelu(_dense(params["proj1"], h)))
-    return shard_activation(out, "fno")
+    with jax.named_scope("projection"):
+        out = _dense(params["proj2"],
+                     jax.nn.gelu(_dense(params["proj1"], h)))
+        return shard_activation(out, "fno")
 
 
 def relative_l2(pred: jax.Array, target: jax.Array) -> jax.Array:

@@ -2,7 +2,8 @@
 
 Every ``pl.pallas_call`` in this package takes its compiler parameters
 from :func:`_compiler_params`, so the VMEM limit the kernels compile under
-is set in exactly one place.
+is set in exactly one place, and its ``name`` from :data:`LAUNCH_NAMES`,
+so that a device trace tells the launches apart.
 """
 from __future__ import annotations
 
@@ -15,6 +16,16 @@ from jax.experimental.pallas import tpu as pltpu
 # with a quarter of the core left to the compiler's own internal scratch.
 # analysis.vmem budgets block plans against this same number.
 VMEM_LIMIT_BYTES = 96 * 1024 * 1024
+
+
+# The name each launch kind gives its pallas_call. The compiled kernel
+# instruction, and so the device trace's op, reads "<name>.N", and the XLA
+# ops the compiler places around a launch carry the name in their
+# op_name. The tuned-plan launch kinds (tuning.plans.LAUNCH_KINDS) are
+# among them.
+LAUNCH_NAMES = ("block_fwd", "block_ends", "spectral_fwd", "gz_recompute",
+                "dx_adjoint", "wgrad", "core", "rdft", "cdft", "irdft",
+                "cgemm")
 
 
 def _compiler_params(**kwargs):

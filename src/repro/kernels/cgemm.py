@@ -53,6 +53,7 @@ def cgemm_call(ar: jax.Array, ai: jax.Array, br: jax.Array, bi: jax.Array,
     return pl.pallas_call(
         _cgemm_kernel,
         grid=grid,
+        name="cgemm",
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -53,6 +53,7 @@ def _rdft_call(x2d: jax.Array, cr: jax.Array, ci: jax.Array,
     return pl.pallas_call(
         _rdft_kernel,
         grid=grid,
+        name="rdft",
         in_specs=[spec_x, spec_m, spec_m],
         out_specs=[spec_o, spec_o],
         out_shape=[out_sd, out_sd],
@@ -85,6 +86,7 @@ def _cdft_call(xr2d: jax.Array, xi2d: jax.Array, fr: jax.Array,
     return pl.pallas_call(
         _cdft_kernel,
         grid=grid,
+        name="cdft",
         in_specs=[spec_x, spec_x, spec_m, spec_m],
         out_specs=[spec_o, spec_o],
         out_shape=[out_sd, out_sd],
@@ -105,6 +107,7 @@ def _irdft_call(xr2d: jax.Array, xi2d: jax.Array, er: jax.Array, ei: jax.Array,
     return pl.pallas_call(
         _irdft_kernel,
         grid=grid,
+        name="irdft",
         in_specs=[spec_x, spec_x, spec_m, spec_m],
         out_specs=spec_o,
         out_shape=jax.ShapeDtypeStruct((m, n), xr2d.dtype),

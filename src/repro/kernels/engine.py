@@ -69,7 +69,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compiler_params
+from repro.kernels import LAUNCH_NAMES, _compiler_params
 
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
@@ -360,11 +360,11 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
     return kernel
 
 
-@functools.partial(jax.jit, static_argnames=("bb", "bo", "bh", "interpret",
-                                             "out_dtype", "acc_dtype",
-                                             "act"))
+@functools.partial(jax.jit, static_argnames=("bb", "bo", "bh", "name",
+                                             "interpret", "out_dtype",
+                                             "acc_dtype", "act"))
 def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
-                     *mats: jax.Array, bb: int, bo: int, bh: int,
+                     *mats: jax.Array, bb: int, bo: int, bh: int, name: str,
                      interpret: bool = False, out_dtype: str = None,
                      acc_dtype: str = "float32", wb: jax.Array = None,
                      bias: jax.Array = None, gy: jax.Array = None,
@@ -376,6 +376,9 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     (mr, mi) operand pairs — R forward stages ([n,k], axis s_R first) then
     R inverse stages ([k,n], axis s_1 first), as produced by
     ``spectral.fused_operand_mats``. Returns y [B,O,s_1..s_R] real.
+    ``name`` is the launch kind (``LAUNCH_NAMES``: block_fwd, block_ends,
+    spectral_fwd, gz_recompute or dx_adjoint), which the caller knows and
+    the operands do not: the adjoint differs only in its DFT operands.
 
     All of B,O,H must divide by (bb,bo,bh); spatial/modes dims are whole
     blocks (ops.py pads). out_dtype overrides the output dtype (default:
@@ -407,6 +410,7 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     per_mode = wr.ndim == 2 + r
     assert len(mats) == 4 * r, (len(mats), r)
     assert act in ("linear", "gelu", "gelu_vjp"), act
+    assert name in LAUNCH_NAMES, name
     assert (gy is not None) == (act == "gelu_vjp"), act
     assert gy is None or (lift is None and proj is None), \
         "fused ends are forward-only (backward is the staged vjp)"
@@ -485,6 +489,7 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         grid=grid,
         in_specs=in_specs,
         out_specs=y_spec,
+        name=name,
         out_shape=jax.ShapeDtypeStruct((b, out_ch) + spatial,
                                        jnp.dtype(out_dtype or x.dtype)),
         scratch_shapes=scratch,
@@ -505,7 +510,7 @@ def fused_fno_block_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     return fused_fnond_call(x, wr, wi, *mats, bb=bb, bo=bo, bh=bh,
                             interpret=interpret, out_dtype=out_dtype,
                             acc_dtype=acc_dtype, wb=wb, bias=bias,
-                            act="gelu")
+                            act="gelu", name="block_fwd")
 
 
 # ---------------------------------------------------------------------------
@@ -608,6 +613,7 @@ def fused_fnond_core_call(zr: jax.Array, zi: jax.Array, wr: jax.Array,
                   mat(gr), mat(gi)],
         out_specs=[y_spec, y_spec],
         out_shape=[out_sd, out_sd],
+        name="core",
         scratch_shapes=[pltpu.VMEM(acc_shape, acc),
                         pltpu.VMEM(acc_shape, acc)],
         compiler_params=_compiler_params(dimension_semantics=_SEMANTICS),
@@ -778,6 +784,7 @@ def fused_fnond_wgrad_call(x: jax.Array, g: jax.Array, *mats: jax.Array,
         in_specs=[x_spec, g_spec] + m_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        name="wgrad",
         scratch_shapes=scratch,
         compiler_params=_compiler_params(dimension_semantics=_SEMANTICS),
         interpret=interpret,

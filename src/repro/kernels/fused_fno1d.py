@@ -30,7 +30,8 @@ def fused_fno1d_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     pads everything to (8,128)-aligned shapes).
     """
     return engine.fused_fnond_call(x, wr, wi, cr, ci, er, ei,
-                                   bb=bb, bo=bo, bh=bh, interpret=interpret)
+                                   bb=bb, bo=bo, bh=bh, name="spectral_fwd",
+                                   interpret=interpret)
 
 
 def fused_fno1d_wgrad_call(x: jax.Array, g: jax.Array, cr: jax.Array,

@@ -49,7 +49,7 @@ def fused_fno2d_full_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     """
     return engine.fused_fnond_call(x, wr, wi, cr, ci, fr, fi, gr, gi,
                                    er, ei, bb=bb, bo=bo, bh=bh,
-                                   interpret=interpret)
+                                   name="spectral_fwd", interpret=interpret)
 
 
 def fused_fno2d_wgrad_call(x: jax.Array, g: jax.Array, cr: jax.Array,

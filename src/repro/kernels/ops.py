@@ -303,8 +303,12 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     wbp = None if wb is None else _pad_axis(_pad_axis(wb, 0, op_), 1, hp)
     biasp = None if bias is None else _pad_axis(bias[:, None], 0, op_)
     gyp = None if gy is None else _pad_axis(_pad_axis(gy, 0, bp), 1, op_)
+    name = ("dx_adjoint" if adjoint else
+            "gz_recompute" if act == "gelu_vjp" else
+            "spectral_fwd" if wb is None else "block_fwd")
     y = engine.fused_fnond_call(xpad, wpad(wr), wpad(wi), *mats,
-                                bb=bb, bo=bo, bh=bh, interpret=interpret,
+                                bb=bb, bo=bo, bh=bh, name=name,
+                                interpret=interpret,
                                 out_dtype=out_dtype,
                                 acc_dtype=pol.accum_dtype,
                                 wb=wbp, bias=biasp, gy=gyp, act=act)
@@ -888,7 +892,8 @@ def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
         engine_proj = (mat(p1w, lp2, op_), col(p1b, lp2),
                        mat(p2w, coutp, lp2), col(p2b, coutp))
     y = engine.fused_fnond_call(xpad, wpad(wr), wpad(wi), *mats,
-                                bb=bb, bo=bo, bh=bh, interpret=interpret,
+                                bb=bb, bo=bo, bh=bh, name="block_ends",
+                                interpret=interpret,
                                 acc_dtype=pol.accum_dtype, wb=wbp,
                                 bias=biasp, act="gelu", lift=engine_lift,
                                 proj=engine_proj)

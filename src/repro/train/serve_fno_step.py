@@ -228,7 +228,13 @@ class FNOServer:
         the FINAL step's prediction; the jit cache keys on (bucket,
         steps). Oversize batches are chunked at the largest bucket; the
         tail chunk pads up to its own bucket; an empty batch returns an
-        empty output without touching the step."""
+        empty output without touching the step.
+
+        Each phase is a host span on the profiler's clock (a no-op unless
+        a profiler session is open): ``fno_server.pad`` (bucket choice and
+        padding), ``fno_server.step`` (the jitted call: with a host input,
+        its transfer to the device and the dispatch) and
+        ``fno_server.slice`` (dropping the padding, joining the chunks)."""
         if rollout_steps < 1:
             raise ValueError(f"rollout_steps must be >= 1, "
                              f"got {rollout_steps}")
@@ -238,14 +244,17 @@ class FNOServer:
                 (0, self.cfg.out_channels) + tuple(x.shape[2:]),
                 jnp.dtype(self.cfg.precision.compute_dtype))
         top = self.buckets[-1]
-        ys = []
+        outs = []
         for s in range(0, n, top):
-            chunk = x[s:s + top]
-            b = pick_bucket(chunk.shape[0], self.buckets)
-            xp, m = pad_to_bucket(chunk, b)
-            y = self._bucketed(xp, rollout_steps)
+            with jax.profiler.TraceAnnotation("fno_server.pad"):
+                chunk = x[s:s + top]
+                b = pick_bucket(chunk.shape[0], self.buckets)
+                xp, m = pad_to_bucket(chunk, b)
+            with jax.profiler.TraceAnnotation("fno_server.step"):
+                outs.append((self._bucketed(xp, rollout_steps), m))
             self.stats["padded"] += b - m
-            ys.append(y[:m])
         self.stats["requests"] += 1
         self.stats["samples"] += n
-        return jnp.concatenate(ys, 0) if len(ys) > 1 else ys[0]
+        with jax.profiler.TraceAnnotation("fno_server.slice"):
+            ys = [y[:m] for y, m in outs]
+            return jnp.concatenate(ys, 0) if len(ys) > 1 else ys[0]

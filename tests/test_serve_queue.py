@@ -316,3 +316,36 @@ def test_tier_over_real_server_matches_direct_calls():
         got = np.asarray(cbs.result(i).y)
         np.testing.assert_allclose(got, direct, rtol=0, atol=1e-6)
         assert np.isfinite(got).all()
+
+
+def test_server_phases_are_host_spans_in_a_profiler_trace(tmp_path):
+    """FNOServer records its phases as host spans on the profiler's clock:
+    per chunk a pad and a step, then one slice that joins the chunks."""
+    jax = pytest.importorskip("jax")
+    from repro.configs import get_config
+    from repro.core import fno as fno_mod
+    from repro.train import serve_fno_step as sfs
+
+    cfg = dataclasses.replace(get_config("fno2d", reduced=True), path="xla")
+    params = fno_mod.init_fno(jax.random.PRNGKey(0), cfg)
+    server = sfs.FNOServer(cfg, params, max_batch=4)
+    top = server.buckets[-1]
+    x = np.zeros((top + 1, cfg.in_channels) + tuple(cfg.spatial),
+                 np.float32)
+    want = np.asarray(server(x))  # compiles both buckets outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = np.asarray(server(x))
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(got, want)
+    path = next(tmp_path.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = sorted((e.start_ns, e.name) for plane in data.planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("fno_server."))
+    # One sample past the top bucket: two chunks.
+    assert [name for _, name in spans] == [
+        "fno_server.pad", "fno_server.step", "fno_server.pad",
+        "fno_server.step", "fno_server.slice"]

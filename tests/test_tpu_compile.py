@@ -6,7 +6,10 @@ forms, or its VMEM limit. These tests hand the TPU compiler the
 full-width block forward and its gradient with ``interpret=False`` and
 assert that a Mosaic kernel (``tpu_custom_call``) comes out for every
 launch: one for the forward, four under ``jax.value_and_grad``
-(block_fwd, gz_recompute, dx_adjoint, wgrad).
+(block_fwd, gz_recompute, dx_adjoint, wgrad), each instruction named for
+its launch kind. The whole fno2d serve step is compiled once more, to
+hold the names the device trace reads: the kernels' launch names and the
+model ends' named scopes.
 
 fno3d is not here: Mosaic still refuses it (ROADMAP S2).
 
@@ -14,7 +17,11 @@ The topology is described inside a module-scoped fixture, never at import
 time: only one process may hold the TPU library, and pytest-xdist workers
 all import every test file.
 """
+import collections
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +29,14 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import PrecisionPolicy
-from repro.kernels import ops
+from repro.kernels import LAUNCH_NAMES, ops
+
+# One instruction line of compiled HLO: "%name = <shape> opcode(...)",
+# where the shape may be a tuple.
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%(?P<name>[\w.\-]+) = "
+                    r"(?P<shape>.*?) (?P<op>[a-z][\w\-]*)\(")
+_OP_NAME = re.compile(r'op_name="(?P<path>[^"]*)"')
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1}
 
 
 @pytest.fixture(scope="module")
@@ -61,6 +75,39 @@ def _compiled_text(fn, args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_launches(text: str) -> collections.Counter:
+    """Mosaic kernels of a compiled program, counted by launch name (the
+    instruction name without its ".N")."""
+    return collections.Counter(
+        re.sub(r"\.\d+$", "", m.group("name"))
+        for line in text.splitlines()
+        if "tpu_custom_call" in line and (m := _INSTR.match(line)))
+
+
+def _entry_ops(text: str):
+    """(name, opcode, output bytes, op_name, label) of each instruction
+    of the entry computation; the label is the innermost op_name
+    component that is a launch name, "lift" or "projection", else None."""
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    out = []
+    for line in entry.splitlines():
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        nbytes = sum(
+            _BYTES.get(dt, 4) * math.prod(int(d) for d in dims.split(",")
+                                          if d)
+            for dt, dims in re.findall(r"(\w+)\[([\d,]*)\]",
+                                       m.group("shape")))
+        hit = _OP_NAME.search(line)
+        path = hit.group("path") if hit else ""
+        label = next((p for p in reversed(path.split("/"))
+                      if p in LAUNCH_NAMES + ("lift", "projection")), None)
+        out.append((m.group("name"), m.group("op"), nbytes, path, label))
+    return out
+
+
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
 @pytest.mark.parametrize("arch", ["fno1d", "fno2d", "fno2d-large"])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
@@ -84,6 +131,9 @@ def test_fused_block_compiles_for_v5e(one_chip, arch, dtype, grad):
         fn = block
     text = _compiled_text(fn, args)
     assert text.count("tpu_custom_call") == (4 if grad else 1), arch
+    want = (["block_fwd", "gz_recompute", "dx_adjoint", "wgrad"] if grad
+            else ["block_fwd"])
+    assert _kernel_launches(text) == collections.Counter(want), arch
 
 
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
@@ -101,3 +151,36 @@ def test_partial_block_compiles_for_v5e(one_chip, arch, dtype):
 
     text = _compiled_text(block, _block_shapes(cfg, pol, one_chip))
     assert text.count("tpu_custom_call") == 3, arch
+    assert _kernel_launches(text) == collections.Counter(
+        ["rdft", "core", "irdft"]), arch
+
+
+def test_serve_step_names_its_work_for_v5e(one_chip, monkeypatch):
+    """The fno2d serve step at batch 8, as the fused production path runs
+    it: the four block kernels are block_fwd launches, every large XLA op
+    around them carries a launch name or the scope of a model end, and
+    the lifting and projection einsums (the same einsum, told apart
+    only by their scopes) carry different labels."""
+    from repro.core import fno
+    from repro.train.serve_fno_step import make_fno_serve_step
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(get_config("fno2d"), path="pallas",
+                              fuse_block=True)
+    place = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip)
+    params = jax.tree.map(place, jax.eval_shape(
+        lambda k: fno.init_fno(k, cfg), jax.random.PRNGKey(0)))
+    x = jax.ShapeDtypeStruct((8, cfg.in_channels) + tuple(cfg.spatial),
+                             jnp.float32, sharding=one_chip)
+    text = _compiled_text(make_fno_serve_step(cfg), (params, {"x": x}))
+
+    assert _kernel_launches(text) == collections.Counter(
+        {"block_fwd": cfg.num_layers})
+    ops_ = _entry_ops(text)
+    large = [(name, label) for name, op, nbytes, _, label in ops_
+             if op in ("fusion", "copy") and nbytes >= 2 ** 20]
+    assert large and all(label for _, label in large), large
+    einsums = {label for _, op, _, path, label in ops_
+               if op == "fusion" and path.endswith("dot_general")}
+    assert einsums == {"lift", "projection"}, einsums
