@@ -15,8 +15,9 @@ Shape model (mirrors ``kernels/engine.py`` exactly):
   * the DFT operand mats use constant index maps (same block every
     program) and the VMEM accumulators are scratch → ×1;
   * accumulators live at ``accum_dtype`` with the shapes the kernels
-    declare (``(∏K,bb,bo)`` per-mode, ``(bb,)+rev_modes+(bo)`` shared,
-    plus the bypass scratch ``(bo,bb)+spatial`` for the block epilogue);
+    declare (two ``(∏K,bb,bo)`` per-mode, one realified ``(bb·∏K, 2bo)``
+    shared, plus the bypass scratch ``(bo,bb)+spatial`` for the block
+    epilogue);
   * every buffer is padded to Mosaic's tiles: the minor dim to 128
     lanes, the second-minor to 8 (32-bit) or 16 (16-bit) sublanes.
 
@@ -103,10 +104,20 @@ def _rev_modes(modes: Sequence[int]) -> Tuple[int, ...]:
     return (kp,) if len(modes) == 1 else tuple(reversed(modes))
 
 
-def _weight_window(rev, bo, bh, per_mode) -> Tuple[int, ...]:
-    """The kernel's hidden-blocked weight window: [bo, bh] shared,
-    [∏K, bo, bh] per-mode."""
-    return ((_prod(rev),) if per_mode else ()) + (bo, bh)
+def _weight_windows(rev, bo, bh, per_mode, dtype: str) -> int:
+    """Bytes of the forward kernel's weight windows, double-buffered: the
+    packed [2bo, 2bh] shared window, or the [∏K, bo, bh] per-mode pair."""
+    if per_mode:
+        return 2 * 2 * _tiled((_prod(rev), bo, bh), dtype)
+    return 2 * _tiled((2 * bo, 2 * bh), dtype)
+
+
+def _fwd_acc_bytes(rev, bb, bo, per_mode, dtype: str) -> int:
+    """The forward kernel's CGEMM accumulators: the per-mode pair
+    [∏K, bb, bo], or the one realified shared [bb·∏K, 2bo]."""
+    if per_mode:
+        return 2 * _tiled((_prod(rev), bb, bo), dtype)
+    return _tiled((bb * _prod(rev), 2 * bo), dtype)
 
 
 def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
@@ -121,12 +132,11 @@ def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
     sp = tuple(spatial)
     kp = _mode_pad(modes)
     rev = _rev_modes(modes)
-    mats = spectral.fused_operand_mats(sp, tuple(modes),
-                                       pol.spectral_dtype, adjoint, kp)
-    w = _weight_window(rev, bo, bh, per_mode)
+    mats = spectral.realified_operand_mats(sp, tuple(modes),
+                                           pol.spectral_dtype, adjoint, kp)
 
     operands = 2 * _tiled((bb, bh) + sp, cd)               # x window
-    operands += 2 * 2 * _tiled(w, cd)                      # wr + wi
+    operands += _weight_windows(rev, bo, bh, per_mode, cd)  # spectral W
     operands += _mats_bytes(mats)                          # constant mats
     operands += 2 * _tiled((bb, bo) + sp, out_dtype or cd)  # y window
     if with_epilogue:
@@ -136,8 +146,7 @@ def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
     if with_gy:
         operands += 2 * _tiled((bb, bo) + sp, cd)          # gy window
 
-    acc = ((_prod(rev), bb, bo) if per_mode else (bb,) + rev + (bo,))
-    scratch = 2 * _tiled(acc, ad)                          # accr + acci
+    scratch = _fwd_acc_bytes(rev, bb, bo, per_mode, ad)    # CGEMM acc
     if with_epilogue:
         scratch += _tiled((bo, bb) + sp, ad)               # bypass acc
     return LaunchEstimate(launch, operands, scratch)
@@ -182,7 +191,7 @@ def _wgrad_estimate(spatial, modes, bb, bo, bh, per_mode,
     rev = _rev_modes(modes)
     mats = spectral.wgrad_operand_mats(sp, tuple(modes),
                                        pol.spectral_dtype, kp)
-    dw = _weight_window(rev, bo, bh, per_mode)
+    dw = ((_prod(rev),) if per_mode else ()) + (bo, bh)    # dW window
 
     operands = 2 * _tiled((bb, bh) + sp, cd)               # x window
     operands += 2 * _tiled((bb, bo) + sp, cd)              # gz window
@@ -250,12 +259,11 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
     sp = tuple(spatial)
     kp = _mode_pad(modes)
     rev = _rev_modes(modes)
-    mats = spectral.fused_operand_mats(sp, tuple(modes),
-                                       pol.spectral_dtype, False, kp)
-    w = _weight_window(rev, op_, bh, per_mode)
+    mats = spectral.realified_operand_mats(sp, tuple(modes),
+                                           pol.spectral_dtype, False, kp)
 
     operands = 2 * _tiled((bb, cinp) + sp, cd)             # raw-x window
-    operands += 2 * 2 * _tiled(w, cd)                      # wr + wi
+    operands += _weight_windows(rev, op_, bh, per_mode, cd)  # spectral W
     operands += _mats_bytes(mats)                          # constant mats
     operands += 2 * _tiled((bb, coutp) + sp, cd)           # y window
     operands += 2 * (_tiled((op_, bh), cd)                 # wb + bias
@@ -266,8 +274,7 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
     operands += (_tiled((lp, op_), cd) + _tiled((lp, 1), cd)  # proj
                  + _tiled((coutp, lp), cd) + _tiled((coutp, 1), cd))
 
-    acc = ((_prod(rev), bb, op_) if per_mode else (bb,) + rev + (op_,))
-    scratch = 2 * _tiled(acc, ad)                          # accr + acci
+    scratch = _fwd_acc_bytes(rev, bb, op_, per_mode, ad)   # CGEMM acc
     scratch += _tiled((op_, bb) + sp, ad)                  # bypass acc
     scratch += _tiled((lp, bb) + sp, ad)                   # acca (lift)
     return LaunchEstimate("block_fwd_ends", operands, scratch)

@@ -198,6 +198,93 @@ def fused_operand_mats(spatial: Tuple[int, ...], modes: Tuple[int, ...],
     return tuple(out)
 
 
+def realified_plan(rank: int) -> Tuple[Tuple[Tuple[int, str], ...],
+                                        Tuple[Tuple[int, str], ...]]:
+    """Stage order of the forward kernel's realified DFT chains:
+    (forward stages, inverse stages), each a tuple of (spatial axis j,
+    form). A "lane" stage contracts the block's minor axis with the packed
+    operand on the right; a "sublane" stage contracts the second-minor
+    axis with the packed operand on the left, batched over the leading
+    axes. The first forward stage has a real input and the last inverse
+    stage a real output.
+
+    Rank ≥ 2 starts the forward chain and ends the inverse chain on axis
+    s_{R-1} as sublane stages, so neither the input block nor the output
+    block is transposed inside the kernel; rank 1 has no second-minor
+    spatial axis. The separable transforms commute, so any order gives
+    the same spectrum and the same output."""
+    if rank == 1:
+        return ((0, "lane"),), ((0, "lane"),)
+    rest = tuple((j, "lane") for j in range(rank - 3, -1, -1))
+    fwd = ((rank - 2, "sublane"), (rank - 1, "lane")) + rest
+    inv = ((rank - 1, "lane"),) + rest[::-1] + ((rank - 2, "sublane"),)
+    return fwd, inv
+
+
+@functools.lru_cache(maxsize=256)
+def realified_operand_mats(spatial: Tuple[int, ...],
+                           modes: Tuple[int, ...], dtype: str = "float32",
+                           adjoint: bool = False,
+                           pad_modes_to: int = 0) -> Tuple[np.ndarray, ...]:
+    """The forward kernel's DFT operands with real and imaginary parts
+    packed into one real matrix per stage, in ``realified_plan`` order:
+    R forward stages, then R inverse stages. A complex row [zr | zi]
+    times the packed matrix is the complex product in one matmul:
+
+      * real input (first forward stage): [Mr | Mi], [n, 2k];
+      * complex stage: [[Mr, Mi], [−Mi, Mr]], [2n, 2k] forward and
+        [2k, 2n] inverse;
+      * real output (last inverse stage): [Mr ; −Mi], [2k, n].
+
+    A sublane stage takes its operand on the left, so it gets the
+    transpose: [Mr | Mi]ᵀ, [2k, n], and [Mr ; −Mi]ᵀ, [n, 2k]. Built from
+    the same (mr, mi) pairs as ``fused_operand_mats`` (adjoint=True gives
+    the input-cotangent set), once per shape, on the host; pad_modes_to
+    zero-pads each half's modes extent as there."""
+    r = len(spatial)
+    pairs = []
+    for idx, pair in enumerate(_fused_mat_pairs(spatial, modes, adjoint,
+                                                "float32")):
+        if pad_modes_to:  # fwd [n,k] pads cols; inv [k,n] rows
+            pair = tuple(_pad_np(m, 1 if idx < r else 0, pad_modes_to)
+                         for m in pair)
+        pairs.append(pair)
+    dt = jnp.dtype(dtype)
+    return tuple(np.ascontiguousarray(m, dt)
+                 for m in pack_realified(pairs, r, np))
+
+
+def pack_realified(pairs, rank: int, xp=np) -> Tuple:
+    """Pack (mr, mi) operand pairs — R forward slots ([n, k], axis s_R
+    first) then R inverse slots ([k, n], axis s_1 first), the
+    ``fused_operand_mats`` order — into the ``realified_operand_mats``
+    forms. xp is numpy (host constants) or jax.numpy (operands given as
+    arrays)."""
+    def block(mr, mi):
+        return xp.concatenate([xp.concatenate([mr, mi], 1),
+                               xp.concatenate([-mi, mr], 1)], 0)
+
+    fwd_plan, inv_plan = realified_plan(rank)
+    out = []
+    for i, (j, form) in enumerate(fwd_plan):
+        mr, mi = pairs[rank - 1 - j]
+        if i == 0:
+            m = xp.concatenate([mr, mi], 1)
+            m = m.T if form == "sublane" else m
+        else:
+            m = block(mr, mi)
+        out.append(m)
+    for i, (j, form) in enumerate(inv_plan):
+        mr, mi = pairs[rank + j]
+        if i == rank - 1:
+            m = xp.concatenate([mr, -mi], 0)
+            m = m.T if form == "sublane" else m
+        else:
+            m = block(mr, mi)
+        out.append(m)
+    return tuple(out)
+
+
 @functools.lru_cache(maxsize=256)
 def wgrad_operand_mats(spatial: Tuple[int, ...], modes: Tuple[int, ...],
                        dtype: str = "float32",

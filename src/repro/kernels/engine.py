@@ -18,21 +18,28 @@ Grid and accumulator layout (identical for every rank):
     accumulator — Fig. 8;
   * truncation/zero-padding/pruning are implicit in the DFT operand shapes.
 
-Every contraction is arranged so no operand needs an in-kernel transpose
-(the TPU replacement for warp swizzling). ``jax.lax.dot_general`` removes
-the contracted axis and appends the new spectral axis last, so the forward
-chain over x[bb,bh,s_1..s_R] contracts the *current* axis of s_R, then
-s_{R-1}, …, then s_1, leaving the spectrum as [bb,bh,K_R,…,K_1]:
+The forward kernel carries each complex tensor as ONE real array whose
+real and imaginary parts are the two halves of one axis, and does every
+complex contraction as one real MXU matmul against an operand packed on
+the host (``spectral.realified_operand_mats``; the shared CGEMM weight
+[[wr, −wi], [wi, wr]]): a truncated mode extent of 32 would otherwise
+fill a quarter of the 128-wide MXU tile, in 2–4 matmuls per stage. For
+rank 2:
 
-    x[bb,bh,s_1..s_R] ─(R DFT stages)→ A[bb,bh,K_R..K_1]
-    A ·(bh) W[bo,bh]                 → acc[bb,K_R..K_1,bo]   (shared W)
-    acc ─(R iDFT stages)→ y[bb,bo,s_1..s_R]
+    x[bb,bh,s_1,s_2] ─(s_1, operand on the left)→ [bb,bh,re|im K_1,s_2]
+      ─(s_2)→ A[bb,bh,K_1,re|im K_2]
+    A ·(re|im bh) [[wr,−wi],[wi,wr]]  → acc[bb,K_2,K_1,re|im bo] (shared W)
+    acc ─(K_2)→ [bb,bo,K_1,re|im s_2] ─(K_1, on the left)→ y[bb,bo,s_1,s_2]
 
-For per-mode weights W[bo,bh,K_1..K_R] the CGEMM batches over the
-flattened spectral axes (W laid out [∏K,bo,bh] outside the kernel) and
-the accumulator is [∏K,bb,bo]. Rank 1 reproduces
-the original 1D kernel exactly; rank 2 the full-fusion 2D kernel; rank 3 is
-the new 3D FNO layer.
+(``realified_plan`` gives the stage order for any rank.) Neither the x
+block nor the y block is transposed in the kernel; the halves move
+between axes on the small spectra.
+
+For per-mode weights W[bo,bh,K_1..K_R] the CGEMM stays four real matmuls
+batched over the flattened spectral axes (W laid out [∏K,bo,bh] outside
+the kernel) into two accumulators [∏K,bb,bo]: packing a per-mode weight
+would double the weight bytes read per step. Rank 1 pads its modes to
+128 lanes; rank 2 is the full-fusion 2D kernel; rank 3 the 3D FNO layer.
 
 Three kernel families:
 
@@ -69,6 +76,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.spectral import realified_plan
 from repro.kernels import LAUNCH_NAMES, _compiler_params
 
 _SEMANTICS = ("parallel", "parallel", "arbitrary")
@@ -85,11 +93,12 @@ _SEMANTICS = ("parallel", "parallel", "arbitrary")
 # OPTIONAL operands absent from every launch kind the cache tunes — an
 # ends-fused launch reuses the block_fwd plan with bo pinned to the padded
 # O, so tuned winners for the default launches stay exactly valid.
-BLOCK_SIGNATURE = ("fnond-v2:grid=(b/bb,o/bo,h/bh);wgrad-grid=(o/bo,h/bh,"
-                   "b/bb);acc=rev_modes@accum+bypass;"
+BLOCK_SIGNATURE = ("fnond-v3:grid=(b/bb,o/bo,h/bh);wgrad-grid=(o/bo,h/bh,"
+                   "b/bb);acc=realified[bb*K,2bo]@accum+bypass;"
                    "per_mode-acc=flat_modes;weights=[H/bh,O,bh];"
-                   "vmem=96MiB;launches=block_fwd,gz_recompute,"
-                   "dx_adjoint,wgrad,core")
+                   "shared-weights=[H/bh,O/bo,2bo,2bh];"
+                   "mats=realified;vmem=96MiB;launches=block_fwd,"
+                   "gz_recompute,dx_adjoint,wgrad,core")
 
 
 def _matmul(acc_dtype: str, interpret: bool):
@@ -139,6 +148,66 @@ def _dft_chain(mm, z, mats, rank):
         zr, zi = _cstage(mm, zr, zi, mats[2 * i][...],
                          mats[2 * i + 1][...], 1 + rank - i)
     return zr, zi
+
+
+# ---------------------------------------------------------------------------
+# Realified chains (the forward kernel). A complex tensor is ONE real array
+# whose axes carry labels — "b", "h" (the contracted channels), "o", ("s",
+# j), ("k", j) — and one of whose axes, `cx`, holds the real part in its
+# first half and the imaginary part in its second. Each DFT stage is then
+# one real matmul with a packed operand (spectral.realified_operand_mats,
+# in spectral.realified_plan order), and so is the shared-weight CGEMM
+# (``_packed_weight``). Between contractions the halves move to the next
+# contracted axis: split along one axis, stacked along another — on major
+# or sublane axes, or on lanes at whole-vreg offsets. Where the halves sit
+# on the lane axis at an offset that is not a multiple of 128 the minor
+# axes are swapped first, so the split runs on sublanes.
+# ---------------------------------------------------------------------------
+def _moveaxis(z, axes, lab, pos):
+    """Move the axis labelled `lab` to position `pos` (negative)."""
+    i = axes.index(lab)
+    if i == len(axes) + pos:
+        return z, axes
+    rest = axes[:i] + axes[i + 1:]
+    at = len(rest) + pos + 1
+    return (jnp.moveaxis(z, i, pos), rest[:at] + (lab,) + rest[at:])
+
+
+def _halves(z, i):
+    """The (real, imaginary) halves of z along axis i."""
+    n = z.shape[i] // 2
+    return (jax.lax.slice_in_dim(z, 0, n, axis=i),
+            jax.lax.slice_in_dim(z, n, 2 * n, axis=i))
+
+
+def _restack(z, axes, cx, dst):
+    """Move the [re; im] halves from axis `cx` to axis `dst`."""
+    if axes[-1] == cx and (z.shape[-1] // 2) % 128:
+        z = jnp.swapaxes(z, -1, -2)
+        axes = axes[:-2] + (axes[-1], axes[-2])
+    return (jnp.concatenate(_halves(z, axes.index(cx)),
+                            axis=axes.index(dst)), axes)
+
+
+def _stage(mm, form, z, axes, cx, src, dst, m):
+    """One realified DFT stage: contract axis `src` (with the halves when
+    z is complex, cx not None) against the packed operand m, on the right
+    ("lane") or on the left, batched over the leading axes ("sublane").
+    The result axis `dst` takes src's place."""
+    if cx is not None and cx != src:
+        z, axes = _restack(z, axes, cx, src)
+    if form == "lane":
+        z, axes = _moveaxis(z, axes, src, -1)
+        z = _dot(mm, z, m, z.ndim - 1)
+    else:
+        z, axes = _moveaxis(z, axes, src, -2)
+        lead = z.shape[:-2]
+        zb = z.reshape((-1,) + z.shape[-2:])
+        mb = jnp.broadcast_to(m, zb.shape[:1] + m.shape)
+        z = mm(mb, zb, (((2,), (1,)), ((0,), (0,))))
+        z = z.reshape(lead + z.shape[1:])
+    i = axes.index(src)
+    return z, axes[:i] + (dst,) + axes[i + 1:]
 
 
 # ---------------------------------------------------------------------------
@@ -219,6 +288,44 @@ def _dgelu(z):
 #     y = W_p2ᵀ·gelu(W_p1ᵀ·z + b_p1) + b_p2 — and the ref write emits the
 #     model OUTPUT channels [bb, C_out, s…].
 # ---------------------------------------------------------------------------
+def fwd_contractions(rank: int, per_mode: bool, has_wb: bool = False,
+                     has_lift: bool = False,
+                     has_proj: bool = False) -> Tuple[int, int]:
+    """(contractions, realified) that one grid step of a forward kernel
+    issues — every ``dot_general`` of the body, the k==0 lift prologue
+    and the last-k epilogue included. Realified: each of the 2R DFT
+    stages and the shared-weight CGEMM, one real matmul per complex
+    product. The per-mode CGEMM stays 4 real matmuls over two
+    accumulators; the bypass and the end MLPs are real products. The
+    kernel checks this count as it traces."""
+    realified = 2 * rank + (0 if per_mode else 1)
+    plain = 4 * per_mode + has_wb + 2 * has_lift + 2 * has_proj
+    return realified + plain, realified
+
+
+def _packed_weight(wr: jax.Array, wi: jax.Array, bo: int,
+                   bh: int) -> jax.Array:
+    """Shared [O,H] weight pair -> the realified CGEMM operand
+    [[wr, −wi], [wi, wr]] cut into its (out, hidden) windows:
+    [H/bh, O/bo, 2bo, 2bh], each window whole in its minor dims."""
+    o, h = wr.shape
+
+    def blocked(w):
+        return w.reshape(o // bo, bo, h // bh, bh).transpose(2, 0, 1, 3)
+
+    wr, wi = blocked(wr), blocked(wi)
+    return jnp.concatenate([jnp.concatenate([wr, -wi], -1),
+                            jnp.concatenate([wi, wr], -1)], -2)
+
+
+def _packed_modes(mats, rank: int) -> Tuple[int, ...]:
+    """(k_1..k_R) read off the forward operands of
+    ``spectral.realified_operand_mats``."""
+    k = {j: (m.shape[0] if form == "sublane" else m.shape[1]) // 2
+         for (j, form), m in zip(realified_plan(rank)[0], mats)}
+    return tuple(k[j] for j in range(rank))
+
+
 @functools.lru_cache(maxsize=None)
 def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
                      has_wb: bool = False, has_bias: bool = False,
@@ -229,13 +336,17 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
     acc = jnp.dtype(acc_dtype)
     mm = _matmul(acc_dtype, interpret)
     has_gy = act == "gelu_vjp"
+    fwd_plan, inv_plan = realified_plan(r)
+    spatial_axes = tuple(("s", j) for j in range(r))
+    want = fwd_contractions(r, per_mode, has_wb, has_lift, has_proj)
 
     def kernel(*refs):
-        x_ref, wr_ref, wi_ref = refs[:3]
-        pos = 3
-        fwd = refs[pos:pos + 2 * r]
-        inv = refs[pos + 2 * r:pos + 4 * r]
-        pos += 4 * r
+        nw = 2 if per_mode else 1
+        x_ref, w_refs = refs[0], refs[1:1 + nw]
+        pos = 1 + nw
+        fwd = refs[pos:pos + r]
+        inv = refs[pos + r:pos + 2 * r]
+        pos += 2 * r
         wb_ref = bias_ref = gy_ref = accb = acca = None
         lift_refs = proj_refs = None
         if has_wb:
@@ -254,13 +365,24 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
             proj_refs = refs[pos:pos + 4]  # p1w [L,O], p1b, p2w, p2b
             pos += 4
         y_ref = refs[pos]
-        accr, acci = refs[pos + 1:pos + 3]
-        pos += 3
+        accs = refs[pos + 1:pos + 1 + nw]
+        pos += 1 + nw
         if has_wb:
             accb = refs[pos]
             pos += 1
         if has_lift:
             acca = refs[pos]
+
+        issued = [0, 0]  # contractions, realified ones
+
+        def counted(realified):
+            def f(a, b, dims):
+                issued[0] += 1
+                issued[1] += realified
+                return mm(a, b, dims)
+            return f
+
+        rmm, pmm = counted(True), counted(False)
 
         def _colvec(ref, nd):
             # [D,1] operand broadcast over the trailing batch/spatial dims.
@@ -270,70 +392,83 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
 
         @pl.when(pl.program_id(2) == 0)
         def _init():
-            accr[...] = jnp.zeros_like(accr)
-            acci[...] = jnp.zeros_like(acci)
+            for a in accs:
+                a[...] = jnp.zeros_like(a)
             if has_wb:
                 accb[...] = jnp.zeros_like(accb)
             if has_lift:
                 # Lift prologue, once per (i,j): a = gelu(W_l1ᵀ·x + b_l1)
                 # → [L, bb, s…], persisted across the hidden k-loop.
-                a = mm(lift_refs[0][...], x_ref[...],
-                       (((1,), (1,)), ((), ())))
+                a = pmm(lift_refs[0][...], x_ref[...],
+                        (((1,), (1,)), ((), ())))
                 a = a + _colvec(lift_refs[1], 1 + r)
                 acca[...] = jax.nn.gelu(a, approximate=True)
 
         if has_lift:
             # This k step's hidden block: h_k = W_l2ᵀ[k]·a + b_l2[k],
             # realigned [L,bb,…]→[bb,bh,…] by a major-axes swap.
-            hk = mm(lift_refs[2][...], acca[...], (((1,), (0,)), ((), ())))
+            hk = pmm(lift_refs[2][...], acca[...], (((1,), (0,)), ((), ())))
             hk = hk + _colvec(lift_refs[3], 1 + r)
             xblk = jnp.swapaxes(hk, 0, 1).astype(x_ref.dtype)
         else:
             xblk = x_ref[...]
 
-        # Truncated forward DFT chain — the FFT writing its A-tile to
-        # "shared memory" (VMEM registers).
-        ar, ai = _dft_chain(mm, xblk, fwd, r)
+        # Truncated forward DFT chain, realified — the FFT writing its
+        # A-tile to "shared memory" (VMEM registers).
+        z, axes, cx = xblk, ("b", "h") + spatial_axes, None
+        for (j, form), m in zip(fwd_plan, fwd):
+            z, axes = _stage(rmm, form, z, axes, cx, ("s", j), ("k", j),
+                             m[...])
+            cx = ("k", j)
 
         # CGEMM over hidden (the k-loop MAC).
-        wr, wi = wr_ref[...], wi_ref[...]
         if per_mode:
-            # The modes, flattened, are the one batch dim: A [∏K,bb,bh]
-            # against W [∏K,bo,bh] -> [∏K,bb,bo].
-            ar, ai = _modes_major(ar), _modes_major(ai)
+            # Four real matmuls: the halves in the K_R..K_1 order of the
+            # weight, the modes flattened into the one batch dim: A
+            # [∏K,bb,bh] against W [∏K,bo,bh] -> [∏K,bb,bo].
+            canon = ("b", "h") + tuple(("k", j) for j in reversed(range(r)))
+            perm = tuple(axes.index(a) for a in canon)
+            ar, ai = (_modes_major(jnp.transpose(t, perm))
+                      for t in _halves(z, axes.index(cx)))
+            wr, wi = w_refs[0][...], w_refs[1][...]
             dims = (((2,), (2,)), ((0,), (0,)))
+            accs[0][...] += pmm(ar, wr, dims) - pmm(ai, wi, dims)
+            accs[1][...] += pmm(ar, wi, dims) + pmm(ai, wr, dims)
         else:
-            dims = (((1,), (1,)), ((), ()))
-
-        def dg(a, w):
-            return mm(a, w, dims)
-
-        accr[...] += dg(ar, wr) - dg(ai, wi)
-        acci[...] += dg(ar, wi) + dg(ai, wr)
+            # One real matmul: the halves stacked on the hidden axis
+            # against [[wr, −wi], [wi, wr]] -> [bb, K…, re o | im o].
+            z, axes = _restack(z, axes, cx, "h")
+            t = rmm(z, w_refs[0][...], (((1,), (1,)), ((), ())))
+            spec_shape, spec_axes = t.shape, axes[:1] + axes[2:] + ("o",)
+            accs[0][...] += t.reshape(accs[0].shape)
 
         if has_wb:
             # Bypass GEMM riding the same k-loop MAC: W_b[bo,bh]·x[bb,bh,s…]
             # → [bo,bb,s…]. The bo-leading layout keeps the minor (spatial)
             # dims in place so the epilogue's realign is a major-axes swap.
-            accb[...] += mm(wb_ref[...], xblk, (((1,), (1,)), ((), ())))
+            accb[...] += pmm(wb_ref[...], xblk, (((1,), (1,)), ((), ())))
 
         @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
         def _epilogue():
-            # Padded inverse DFT chain; only the real part of the final
-            # stage is materialized (real output).
-            tr, ti = accr[...], acci[...]
-            if per_mode:  # [∏K,bb,bo] -> the shared [bb,K_R..K_1,bo]
-                shared = (tr.shape[1],) + rev_modes + (tr.shape[2],)
-                tr, ti = (jnp.swapaxes(t, 0, 1).reshape(shared)
-                          for t in (tr, ti))
-            z = None
-            for i in range(r):
-                axis = r - i
-                mr, mi = inv[2 * i][...], inv[2 * i + 1][...]
-                if i < r - 1:
-                    tr, ti = _cstage(mm, tr, ti, mr, mi, axis)
-                else:
-                    z = _dot(mm, tr, mr, axis) - _dot(mm, ti, mi, axis)
+            # Padded inverse DFT chain, realified; the last stage emits
+            # only the real part (real output).
+            if per_mode:  # [∏K,bb,bo] pair -> [bb, re K_R | im K_R, …, bo]
+                shared = (accs[0].shape[1],) + rev_modes + (accs[0].shape[2],)
+                z = jnp.concatenate(
+                    [jnp.swapaxes(a[...], 0, 1).reshape(shared)
+                     for a in accs], axis=1)
+                axes = (("b",) + tuple(("k", j) for j in reversed(range(r)))
+                        + ("o",))
+                cx = ("k", r - 1)
+            else:
+                z, axes, cx = accs[0][...].reshape(spec_shape), spec_axes, "o"
+            for i, ((j, form), m) in enumerate(zip(inv_plan, inv)):
+                z, axes = _stage(rmm, form, z, axes, cx, ("k", j), ("s", j),
+                                 m[...])
+                cx = ("s", j) if i < r - 1 else None
+            out_axes = ("b", "o") + spatial_axes
+            if axes != out_axes:
+                z = jnp.transpose(z, tuple(axes.index(a) for a in out_axes))
             # Block epilogue: + bypass + bias → activation, all on the
             # f32 VMEM values — HBM sees only the final activation.
             if has_wb:
@@ -349,13 +484,15 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
                 # [bb,O,s…] (bo == padded O — single j step): the pointwise
                 # MLP contracts the full hidden width in VMEM and the ref
                 # write emits the model's output channels.
-                a2 = mm(proj_refs[0][...], z.astype(acc),
-                        (((1,), (1,)), ((), ())))
+                a2 = pmm(proj_refs[0][...], z.astype(acc),
+                         (((1,), (1,)), ((), ())))
                 a2 = jax.nn.gelu(a2 + _colvec(proj_refs[1], 1 + r),
                                  approximate=True)
-                out = mm(proj_refs[2][...], a2, (((1,), (0,)), ((), ())))
+                out = pmm(proj_refs[2][...], a2, (((1,), (0,)), ((), ())))
                 z = jnp.swapaxes(out + _colvec(proj_refs[3], 1 + r), 0, 1)
             y_ref[...] = z.astype(y_ref.dtype)
+
+        assert tuple(issued) == want, (issued, want)
 
     return kernel
 
@@ -372,10 +509,10 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
                      proj: Tuple = None) -> jax.Array:
     """Whole rank-R FNO spectral layer — or FNO block — in one kernel.
 
-    x: [B,H,s_1..s_R] real; w: [O,H] or [O,H,K_1..K_R]; mats: flat
-    (mr, mi) operand pairs — R forward stages ([n,k], axis s_R first) then
-    R inverse stages ([k,n], axis s_1 first), as produced by
-    ``spectral.fused_operand_mats``. Returns y [B,O,s_1..s_R] real.
+    x: [B,H,s_1..s_R] real; w: [O,H] or [O,H,K_1..K_R]; mats: the 2R
+    packed real operands — R forward stages then R inverse stages in
+    ``spectral.realified_plan`` order, as produced by
+    ``spectral.realified_operand_mats``. Returns y [B,O,s_1..s_R] real.
     ``name`` is the launch kind (``LAUNCH_NAMES``: block_fwd, block_ends,
     spectral_fwd, gz_recompute or dx_adjoint), which the caller knows and
     the operands do not: the adjoint differs only in its DFT operands.
@@ -408,7 +545,7 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     spatial = x.shape[2:]
     o = wr.shape[0]
     per_mode = wr.ndim == 2 + r
-    assert len(mats) == 4 * r, (len(mats), r)
+    assert len(mats) == 2 * r, (len(mats), r)
     assert act in ("linear", "gelu", "gelu_vjp"), act
     assert name in LAUNCH_NAMES, name
     assert (gy is not None) == (act == "gelu_vjp"), act
@@ -416,8 +553,8 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         "fused ends are forward-only (backward is the staged vjp)"
     assert proj is None or bo == o, \
         "the projection epilogue contracts the full padded O: bo must == O"
-    # Spectral extents in accumulator order (K_R .. K_1).
-    rev_modes = tuple(m.shape[1] for m in mats[:2 * r:2])
+    modes = _packed_modes(mats, r)
+    rev_modes = modes[::-1]  # the per-mode weight's flattening order
     grid = (b // bb, o // bo, h // bh)
     zr = (0,) * r
 
@@ -429,16 +566,17 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         x_spec = pl.BlockSpec((bb, bh) + spatial,
                               lambda i, j, k: (i, k) + zr)
     if per_mode:
-        wr, wi = (_hidden_blocked(_modes_leading(w, r), bh)
-                  for w in (wr, wi))
-        kf = wr.shape[1]
-        w_spec = pl.BlockSpec((None, kf, bo, bh),
-                              lambda i, j, k: (k, 0, j, 0))
-        acc_shape = (kf, bb, bo)
+        ws = [_hidden_blocked(_modes_leading(w, r), bh) for w in (wr, wi)]
+        kf = ws[0].shape[1]
+        w_specs = [pl.BlockSpec((None, kf, bo, bh),
+                                lambda i, j, k: (k, 0, j, 0))] * 2
+        acc_shapes = [(kf, bb, bo)] * 2
     else:
-        wr, wi = _hidden_blocked(wr, bh), _hidden_blocked(wi, bh)
-        w_spec = _hidden_spec(bo, bh, lambda i, j, k: (k, j))
-        acc_shape = (bb,) + rev_modes + (bo,)
+        ws = [_packed_weight(wr, wi, bo, bh)]
+        w_specs = [pl.BlockSpec((None, None, 2 * bo, 2 * bh),
+                                lambda i, j, k: (k, j, 0, 0))]
+        # [bb, K…, re o | im o] with its leading axes merged.
+        acc_shapes = [(bb * math.prod(modes), 2 * bo)]
     m_specs = [pl.BlockSpec(m.shape, lambda i, j, k: (0, 0)) for m in mats]
     out_ch = proj[2].shape[0] if proj is not None else o
     if proj is not None:
@@ -448,10 +586,10 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         y_spec = pl.BlockSpec((bb, bo) + spatial,
                               lambda i, j, k: (i, j) + zr)
 
-    operands = [x, wr, wi, *mats]
-    in_specs = [x_spec, w_spec, w_spec] + m_specs
+    operands = [x, *ws, *mats]
+    in_specs = [x_spec, *w_specs] + m_specs
     acc = jnp.dtype(acc_dtype)
-    scratch = [pltpu.VMEM(acc_shape, acc), pltpu.VMEM(acc_shape, acc)]
+    scratch = [pltpu.VMEM(shape, acc) for shape in acc_shapes]
     if wb is not None:
         operands.append(_hidden_blocked(wb, bh))
         in_specs.append(_hidden_spec(bo, bh, lambda i, j, k: (k, j)))

@@ -16,7 +16,9 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+import jax.numpy as jnp
 
+from repro.core import spectral
 from repro.kernels import engine
 
 
@@ -29,7 +31,8 @@ def fused_fno1d_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     All of B,O,H must divide by (bb,bo,bh); K,N are whole blocks (ops.py
     pads everything to (8,128)-aligned shapes).
     """
-    return engine.fused_fnond_call(x, wr, wi, cr, ci, er, ei,
+    mats = spectral.pack_realified([(cr, ci), (er, ei)], 1, jnp)
+    return engine.fused_fnond_call(x, wr, wi, *mats,
                                    bb=bb, bo=bo, bh=bh, name="spectral_fwd",
                                    interpret=interpret)
 

@@ -21,7 +21,9 @@ from __future__ import annotations
 from typing import Tuple
 
 import jax
+import jax.numpy as jnp
 
+from repro.core import spectral
 from repro.kernels import engine
 
 
@@ -47,8 +49,9 @@ def fused_fno2d_full_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     x: [B,H,X,Y] real; w: [O,H] or [O,H,KX,KY]; c: [Y,KY]; f: [X,KX];
     g: [KX,X]; e: [KY,Y]. Returns y [B,O,X,Y] real.
     """
-    return engine.fused_fnond_call(x, wr, wi, cr, ci, fr, fi, gr, gi,
-                                   er, ei, bb=bb, bo=bo, bh=bh,
+    mats = spectral.pack_realified([(cr, ci), (fr, fi), (gr, gi), (er, ei)],
+                                   2, jnp)
+    return engine.fused_fnond_call(x, wr, wi, *mats, bb=bb, bo=bo, bh=bh,
                                    name="spectral_fwd", interpret=interpret)
 
 

@@ -248,7 +248,7 @@ def cgemm(ar: jax.Array, ai: jax.Array, br: jax.Array, bi: jax.Array, *,
 # fused kernels end-to-end. The layer is y = Re(((x·C…)∘W)·…E) — real-
 # linear in both x and W — so:
 #   * dx is the SAME fused DFT→CGEMM→iDFT pipeline run on the cotangent
-#     with transposed DFT operands (spectral.fused_operand_mats
+#     with transposed DFT operands (spectral.realified_operand_mats
 #     adjoint=True) and the weight swapped over (out, hidden);
 #   * dW is the fused rank-reduction kernel (engine.fused_fnond_wgrad_call):
 #     conj(Σ_b Ĝ·A) with both spectra computed in-kernel.
@@ -290,7 +290,7 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     per_mode = wr.ndim == 2 + r
     kp = _mode_pad(modes)
     bb, bo, bh, bp, op_, hp = _blocks(x, o, bb, bo, bh)
-    mats = spectral.fused_operand_mats(
+    mats = spectral.realified_operand_mats(
         tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype,
         adjoint, kp)
     xpad = _pad_axis(_pad_axis(x, 0, bp), 1, hp)
@@ -860,7 +860,7 @@ def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
         bo = op_ = _rup(o, 8)
     else:
         op_ = _rup(o, bo)
-    mats = spectral.fused_operand_mats(
+    mats = spectral.realified_operand_mats(
         tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype, False,
         kp)
 

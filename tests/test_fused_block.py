@@ -19,7 +19,8 @@ import pytest
 
 from repro.configs import get_config
 from repro.kernels import ops
-from repro.roofline.hlo_counter import count_pallas_calls
+from repro.roofline.hlo_counter import (count_pallas_calls,
+                                        jaxpr_primitive_counts)
 
 _CASES = {
     1: ((64,), (17,)),
@@ -75,6 +76,27 @@ def test_block_forward_is_one_pallas_call():
         fn = lambda x: ops.fno_block_nd(x, *args[1:], modes, path="pallas",
                                         variant="full")
         assert count_pallas_calls(fn, args[0]) == 1, rank
+
+
+@pytest.mark.parametrize("weight_mode,want", [("shared", (6, 5)),
+                                              ("per_mode", (9, 4))])
+def test_block_forward_contractions(weight_mode, want):
+    """One grid step of the rank-2 block forward issues 6 contractions,
+    each complex one a single realified matmul: two DFT stages each way
+    and the CGEMM, plus the real bypass GEMM (17 with the complex stages
+    split into real halves). The per-mode CGEMM stays 4 real matmuls."""
+    from repro.kernels import engine
+
+    per_mode = weight_mode == "per_mode"
+    assert engine.fwd_contractions(2, per_mode, has_wb=True) == want
+    rng = np.random.default_rng(6)
+    args, modes = _block_args(rng, 2, weight_mode)
+    fn = lambda *a: ops.fno_block_nd(*a, modes, path="pallas",
+                                     variant="full")
+    inside = (jaxpr_primitive_counts(fn, *args).get("dot_general", 0)
+              - jaxpr_primitive_counts(fn, *args, into_kernels=False)
+              .get("dot_general", 0))
+    assert inside == want[0]
 
 
 def test_block_grad_stays_on_fused_kernels():

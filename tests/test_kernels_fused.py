@@ -170,6 +170,43 @@ def test_operand_mats_cached():
     assert len(a) == 8 and len(c) == 8  # 4 stages × (re, im) at rank 2
 
 
+@pytest.mark.parametrize("spatial,modes,pad", [
+    ((64,), (17,), 128), ((64,), (17,), 0), ((16, 32), (5, 9), 0),
+    ((16, 32), (5, 9), 8), ((8, 8, 16), (3, 3, 5), 0)])
+@pytest.mark.parametrize("adjoint", [False, True])
+def test_realified_operands_reproduce_complex_product(spatial, modes, pad,
+                                                      adjoint):
+    """Each packed operand of the forward kernel, applied to random
+    [re | im] rows, gives the complex product with the (mr, mi) pair of
+    ``fused_operand_mats`` it packs: real in (first forward stage),
+    complex, real out (last inverse stage); sublane stages as the
+    transposed (left-hand) operand."""
+    from repro.core import spectral as sp
+    r = len(spatial)
+    flat = sp.fused_operand_mats(spatial, modes, "float32", adjoint, pad)
+    pairs = [(flat[2 * i], flat[2 * i + 1]) for i in range(2 * r)]
+    packed = sp.realified_operand_mats(spatial, modes, "float32", adjoint,
+                                       pad)
+    fwd_plan, inv_plan = sp.realified_plan(r)
+    stages = ([(pairs[r - 1 - j], form, i == 0, False)
+               for i, (j, form) in enumerate(fwd_plan)]
+              + [(pairs[r + j], form, False, i == r - 1)
+                 for i, (j, form) in enumerate(inv_plan)])
+    assert len(packed) == len(stages) == 2 * r
+    rng = np.random.default_rng(r + 10 * adjoint)
+    for ((mr, mi), form, real_in, real_out), p in zip(stages, packed):
+        m = mr.astype(np.float64) + 1j * mi.astype(np.float64)
+        q = p.T if form == "sublane" else p
+        z = rng.normal(size=(3, m.shape[0]))
+        if not real_in:
+            z = z + 1j * rng.normal(size=z.shape)
+        want = z @ m
+        want = want.real if real_out else np.concatenate(
+            [want.real, want.imag], 1)
+        rows = z.real if real_in else np.concatenate([z.real, z.imag], 1)
+        np.testing.assert_allclose(rows @ q, want, rtol=1e-5, atol=1e-5)
+
+
 def test_three_paths_agree():
     """ref == xla == pallas (the core fusion-correctness invariant)."""
     rng = np.random.default_rng(1234)
