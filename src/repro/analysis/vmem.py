@@ -15,9 +15,13 @@ Shape model (mirrors ``kernels/engine.py`` exactly):
   * the DFT operand mats use constant index maps (same block every
     program) and the VMEM accumulators are scratch → ×1;
   * accumulators live at ``accum_dtype`` with the shapes the kernels
-    declare (two ``(∏K,bb,bo)`` per-mode, one realified ``(bb·∏K, 2bo)``
+    declare (two ``(∏K,bb,bo)`` per-mode, or ``(bb,bo,K_1,K_2)`` where the
+    per-mode CGEMM runs on the VPU, one realified ``(bb·∏K, 2bo)``
     shared, plus the bypass scratch ``(bo,bb)+spatial`` for the block
     epilogue);
+  * the forward kernel family runs in ``spectral.kernel_layout``: a
+    rank-3 layer's trailing axes merged, its DFT operands Kronecker
+    products;
   * every buffer is padded to Mosaic's tiles: the minor dim to 128
     lanes, the second-minor to 8 (32-bit) or 16 (16-bit) sublanes.
 
@@ -106,7 +110,10 @@ def _rev_modes(modes: Sequence[int]) -> Tuple[int, ...]:
 
 def _weight_windows(rev, bo, bh, per_mode, dtype: str) -> int:
     """Bytes of the forward kernel's weight windows, double-buffered: the
-    packed [2bo, 2bh] shared window, or the [∏K, bo, bh] per-mode pair."""
+    packed [2bo, 2bh] shared window, or the per-mode pair — [bh, bo,
+    K_1, K_2] where the CGEMM runs on the VPU, else [∏K, bo, bh]."""
+    if per_mode and _vpu(rev):
+        return 2 * 2 * _tiled((bh, bo) + tuple(reversed(rev)), dtype)
     if per_mode:
         return 2 * 2 * _tiled((_prod(rev), bo, bh), dtype)
     return 2 * _tiled((2 * bo, 2 * bh), dtype)
@@ -114,10 +121,28 @@ def _weight_windows(rev, bo, bh, per_mode, dtype: str) -> int:
 
 def _fwd_acc_bytes(rev, bb, bo, per_mode, dtype: str) -> int:
     """The forward kernel's CGEMM accumulators: the per-mode pair
-    [∏K, bb, bo], or the one realified shared [bb·∏K, 2bo]."""
+    [bb, bo, K_1, K_2] (VPU) or [∏K, bb, bo], or the one realified shared
+    [bb·∏K, 2bo]."""
+    if per_mode and _vpu(rev):
+        return 2 * _tiled((bb, bo) + tuple(reversed(rev)), dtype)
     if per_mode:
         return 2 * _tiled((_prod(rev), bb, bo), dtype)
     return _tiled((bb * _prod(rev), 2 * bo), dtype)
+
+
+def _vpu(rev) -> bool:
+    from repro.kernels.engine import vpu_cgemm
+    return vpu_cgemm(len(rev), tuple(reversed(rev)))
+
+
+def _kernel_shapes(spatial, modes):
+    """(spatial, accumulator-order modes) of the forward kernel family:
+    ``spectral.kernel_layout``, with the rank-1 lane pad."""
+    from repro.core import spectral
+
+    sp, km = spectral.kernel_layout(spatial, modes)
+    return sp, (_rev_modes(modes) if len(modes) == 1
+                else tuple(reversed(km)))
 
 
 def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
@@ -129,10 +154,9 @@ def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
     from repro.kernels.ops import _mode_pad
 
     cd, ad = pol.compute_dtype, pol.accum_dtype
-    sp = tuple(spatial)
     kp = _mode_pad(modes)
-    rev = _rev_modes(modes)
-    mats = spectral.realified_operand_mats(sp, tuple(modes),
+    sp, rev = _kernel_shapes(spatial, modes)
+    mats = spectral.realified_operand_mats(tuple(spatial), tuple(modes),
                                            pol.spectral_dtype, adjoint, kp)
 
     operands = 2 * _tiled((bb, bh) + sp, cd)               # x window
@@ -256,10 +280,9 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
     coutp = _rup8(cfg.out_channels)
 
     cd, ad = pol.compute_dtype, pol.accum_dtype
-    sp = tuple(spatial)
     kp = _mode_pad(modes)
-    rev = _rev_modes(modes)
-    mats = spectral.realified_operand_mats(sp, tuple(modes),
+    sp, rev = _kernel_shapes(spatial, modes)
+    mats = spectral.realified_operand_mats(tuple(spatial), tuple(modes),
                                            pol.spectral_dtype, False, kp)
 
     operands = 2 * _tiled((bb, cinp) + sp, cd)             # raw-x window
@@ -285,7 +308,7 @@ def _norm_shapes(cfg_or_shapes, policy):
     ``(hidden, spatial, modes, per_mode)`` tuple."""
     if isinstance(cfg_or_shapes, FNOConfig):
         cfg = cfg_or_shapes
-        return (cfg.hidden, tuple(cfg.spatial), tuple(cfg.modes),
+        return (cfg.hidden, tuple(cfg.spatial), cfg.kept_modes,
                 cfg.weight_mode == "per_mode", policy or cfg.precision)
     h, spatial, modes, per_mode = cfg_or_shapes
     return (int(h), tuple(spatial), tuple(modes), bool(per_mode),

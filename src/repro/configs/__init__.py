@@ -33,6 +33,7 @@ _FNO_FACTORIES = {
     "fno2d": (fno.fno2d, fno.reduced_2d),
     "fno2d-large": (fno.fno2d_large, fno.reduced_2d),
     "fno3d": (fno.fno3d, fno.reduced_3d),
+    "fno3d-ns": (fno.fno3d_ns, fno.reduced_3d_ns),
 }
 
 ARCH_IDS: Tuple[str, ...] = tuple(_ARCH_MODULES)
@@ -75,7 +76,7 @@ def skip_reason(arch: str, shape: str) -> Optional[str]:
 
 def runnable_cells() -> Iterator[Tuple[str, str, Optional[str]]]:
     """Yield (arch, shape, skip_reason) for every seeded (arch × shape)
-    cell — the 40 assigned LM cells AND the FNO archs (56 total), so no
+    cell — the 40 assigned LM cells AND the FNO archs (60 total), so no
     config can exist without either a runnable cell or a stated skip
     reason (the contract ``analysis.ast_lint.check_config_registry``
     enforces)."""

@@ -211,6 +211,19 @@ class PrecisionPolicy:
         return self.compute_dtype != self.param_dtype
 
 
+def kept_modes(modes, truncation: str = "low") -> Tuple[int, ...]:
+    """Kept spectral bins per axis: ``modes`` itself under "low" (the
+    first k of every axis, TurboFNO); under "corners" 2k on every complex
+    axis (the k lowest and k highest bins, Li et al.) and k on the last,
+    the real-input axis. The spectral weight and every DFT operand are
+    sized by these counts."""
+    modes = tuple(int(m) for m in modes)
+    if truncation == "low":
+        return modes
+    assert truncation == "corners", truncation
+    return tuple(2 * m for m in modes[:-1]) + modes[-1:]
+
+
 @dataclasses.dataclass(frozen=True)
 class FNOConfig:
     """Fourier Neural Operator configuration (the paper's architecture)."""
@@ -222,9 +235,23 @@ class FNOConfig:
     in_channels: int
     out_channels: int
     spatial: Tuple[int, ...]  # (N,), (X, Y), or (X, Y, Z)
-    modes: Tuple[int, ...]  # kept low-frequency modes per spatial axis
+    modes: Tuple[int, ...]  # modes per spatial axis (per corner: truncation)
     weight_mode: str = "shared"  # shared (paper CGEMM) | per_mode (classic FNO)
     lifting_dim: int = 0  # 0 => 2*hidden
+    # Which spectral bins a block keeps (docs/DESIGN.md §3.4): "low", the
+    # first k of every axis (TurboFNO), or "corners", the k lowest and k
+    # highest of every complex axis and the first k of the last (Li et
+    # al.'s FNO). ``kept_modes`` gives the counts.
+    truncation: str = "low"
+    # The blocks' activation, also the one inside the lifting and
+    # projection MLPs: "gelu" (tanh form) or "relu" (Li et al.).
+    activation: str = "gelu"
+    # False leaves the last block unactivated (Li et al.'s FNO-3D).
+    act_last: bool = True
+    # 2: lift = lift2(act(lift1(x))), through lifting_dim; 1: one linear
+    # layer in_channels -> hidden (Li et al.'s fc0), and lifting_dim is
+    # the projection's width alone.
+    lift_layers: int = 2
     path: str = "xla"  # ref | xla | pallas
     dtype: str = "float32"  # precision preset name (PrecisionPolicy.from_name)
     policy: Optional[PrecisionPolicy] = None  # explicit override of `dtype`
@@ -265,20 +292,30 @@ class FNOConfig:
         return self.policy or PrecisionPolicy.from_name(self.dtype)
 
     @property
+    def kept_modes(self) -> Tuple[int, ...]:
+        return kept_modes(self.modes, self.truncation)
+
+    @property
     def truncation_ratio(self) -> Tuple[float, ...]:
         full = tuple(s // 2 + 1 for s in self.spatial)
         return tuple(m / f for m, f in zip(self.modes, full))
 
     def param_count(self) -> int:
-        h = self.hidden
+        """Every weight and bias: lifting MLP (or one layer), per block the
+        complex spectral weight (re+im; one per kept mode when per-mode)
+        and the 1x1 bypass with its bias, projection MLP."""
+        h, cin, cout = self.hidden, self.in_channels, self.out_channels
         lift = self.lifting_dim or 2 * h
-        p = self.in_channels * lift + lift * h  # lifting MLP
-        per_layer = 2 * h * h  # complex shared weight (re+im)
+        if self.lift_layers == 1:
+            p = cin * h + h
+        else:
+            p = cin * lift + lift + lift * h + h
+        per_layer = 2 * h * h
         if self.weight_mode == "per_mode":
-            per_layer *= math.prod(self.modes)
-        per_layer += h * h + h  # bypass 1x1 conv + bias
+            per_layer *= math.prod(self.kept_modes)
+        per_layer += h * h + h
         p += self.num_layers * per_layer
-        p += h * lift + lift * self.out_channels  # projection MLP
+        p += h * lift + lift + lift * cout + cout
         return p
 
     def validate(self) -> None:
@@ -287,6 +324,20 @@ class FNOConfig:
         for m, s in zip(self.modes, self.spatial):
             assert 0 < m <= s // 2, (
                 f"{self.name}: modes {m} must be <= {s // 2} (Nyquist excl.)")
+        assert self.truncation in ("low", "corners"), (
+            f"{self.name}: truncation must be 'low' or 'corners', got "
+            f"{self.truncation!r}")
+        assert self.activation in ("gelu", "relu"), (
+            f"{self.name}: activation must be 'gelu' or 'relu', got "
+            f"{self.activation!r}")
+        assert self.lift_layers in (1, 2), (
+            f"{self.name}: lift_layers must be 1 or 2, got "
+            f"{self.lift_layers!r}")
+        assert not self.fuse_ends or (
+            self.truncation == "low" and self.activation == "gelu"
+            and self.act_last and self.lift_layers == 2), (
+            f"{self.name}: fuse_ends folds the two-layer GELU ends of a "
+            f"low-corner model into the kernel; set it off")
         if self.block_plan is not None:
             assert len(self.block_plan) == 3 and all(
                 isinstance(v, int) and v >= 0 for v in self.block_plan), (

@@ -5,7 +5,10 @@ N1=128 / N2=256 (Table 1), truncation ratios 25% and 50% (Sec. 3.1), hidden
 dims 32-128 (Sec. 5). ``fno2d-large`` is the end-to-end training target
 (~100M params with per-mode weights). ``fno3d`` is the Navier–Stokes-class
 rank-3 workload (Li et al. 2020 §5.3 uses 64³ grids; we keep the same 25%
-per-axis truncation) running on the rank-generic fused engine.
+per-axis truncation) running on the rank-generic fused engine; it has no
+published source. ``fno3d-ns`` is Li et al.'s published FNO-3D for
+Navier–Stokes at its own widths: per-mode weights on the ± corners, ReLU,
+an unactivated last block and a one-layer lift.
 """
 import dataclasses
 
@@ -14,6 +17,7 @@ from repro.configs.base import FNOConfig, PrecisionPolicy
 ARCH_ID_1D = "fno1d"
 ARCH_ID_2D = "fno2d"
 ARCH_ID_3D = "fno3d"
+ARCH_ID_3D_NS = "fno3d-ns"
 
 
 def with_precision(cfg: FNOConfig, dtype: str) -> FNOConfig:
@@ -99,6 +103,23 @@ def fno3d() -> FNOConfig:
     )
 
 
+def fno3d_ns() -> FNOConfig:
+    """Li et al. 2020 (arXiv:2010.08895) §5.3, FNO-3D for Navier–Stokes at
+    ν = 1e-3: ten observed vorticity frames and the (x, y, t) grids on a
+    64×64 torus × 40 output steps -> the 40 next frames. fc0 13 -> 20,
+    four blocks of width 20 with per-mode weights on the ± corners of 8
+    modes (16·16·8 kept bins), ReLU after all but the last, fc1 20 -> 128,
+    ReLU, fc2 128 -> 1. The paper's eval-mode BatchNorm folds into each
+    block's weights and bias and is left out."""
+    return FNOConfig(
+        name="fno3d-ns", ndim=3, hidden=20, num_layers=4,
+        in_channels=13, out_channels=1,
+        spatial=(64, 64, 40), modes=(8, 8, 8),
+        weight_mode="per_mode", lifting_dim=128, truncation="corners",
+        activation="relu", act_last=False, lift_layers=1,
+    )
+
+
 def reduced_1d() -> FNOConfig:
     import dataclasses
     return dataclasses.replace(
@@ -116,3 +137,12 @@ def reduced_3d() -> FNOConfig:
     return dataclasses.replace(
         fno3d(), hidden=8, num_layers=2, spatial=(16, 16, 16),
         modes=(4, 4, 4))
+
+
+def reduced_3d_ns() -> FNOConfig:
+    """fno3d-ns cut for the CPU: the merged trailing axis keeps 8·16 bins,
+    so the per-mode CGEMM takes the same VPU form as at full size."""
+    import dataclasses
+    return dataclasses.replace(
+        fno3d_ns(), hidden=8, num_layers=2, spatial=(16, 16, 32),
+        modes=(4, 4, 16), lifting_dim=16)

@@ -1,8 +1,14 @@
 """Fourier Neural Operator models (1D / 2D / 3D), built on SpectralConv.
 
 Architecture (paper Fig. 1 / Li et al. 2020):
-  lifting pointwise MLP  →  L × [spectral conv + 1x1 bypass conv + GELU]
+  lifting pointwise MLP  →  L × [spectral conv + 1x1 bypass conv + act]
   →  projection pointwise MLP.
+
+The configuration picks the activation (GELU, or ReLU as in Li et al.),
+whether the last block is activated, a two-layer or one-layer lift, and
+the spectral truncation (TurboFNO's low corner or Li et al.'s ± corners);
+the defaults are TurboFNO's FNO. Li et al.'s FNO-3D (``fno3d-ns``) is
+fc0 → 3 × [K + W, ReLU] → [K + W] → fc1 → ReLU → fc2.
 
 With ``cfg.fuse_block`` each whole block — spectral + bypass + bias +
 GELU — runs as ONE pallas_call per layer on the pallas path, forward and
@@ -28,8 +34,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import FNOConfig
-from repro.core import spectral_conv as sc
+from repro.core import spectral, spectral_conv as sc
 from repro.distributed.sharding import current_context, shard_activation
+from repro.kernels import ops
 
 
 def _dense_init(key, din, dout, dtype=jnp.float32):
@@ -56,14 +63,18 @@ def init_fno(key: jax.Array, cfg: FNOConfig) -> Dict[str, Any]:
     dtype = jnp.dtype(cfg.precision.param_dtype)
     lift = cfg.lifting_dim or 2 * cfg.hidden
     keys = jax.random.split(key, 4 + 2 * cfg.num_layers)
-    modes = tuple(cfg.modes)
+    modes = cfg.kept_modes
     params: Dict[str, Any] = {
-        "lift1": _dense_init(keys[0], cfg.in_channels, lift, dtype),
-        "lift2": _dense_init(keys[1], lift, cfg.hidden, dtype),
         "proj1": _dense_init(keys[2], cfg.hidden, lift, dtype),
         "proj2": _dense_init(keys[3], lift, cfg.out_channels, dtype),
         "blocks": [],
     }
+    if cfg.lift_layers == 1:  # one layer in -> hidden; keys[1] unused
+        params["lift1"] = _dense_init(keys[0], cfg.in_channels, cfg.hidden,
+                                      dtype)
+    else:
+        params["lift1"] = _dense_init(keys[0], cfg.in_channels, lift, dtype)
+        params["lift2"] = _dense_init(keys[1], lift, cfg.hidden, dtype)
     for i in range(cfg.num_layers):
         params["blocks"].append({
             "spectral": sc.init_spectral_nd(keys[4 + 2 * i], cfg.hidden,
@@ -75,9 +86,22 @@ def init_fno(key: jax.Array, cfg: FNOConfig) -> Dict[str, Any]:
     return params
 
 
+def input_layout(cfg: FNOConfig, path: str = None) -> Tuple[int, ...]:
+    """The trailing shape ``apply_fno`` keeps its activations in between
+    the ends: on the fused pallas path with staged ends the forward
+    kernel's layout (``spectral.kernel_layout``: a rank-3 grid's trailing
+    axes merged), else the grid. An input given in it needs no relayout
+    on the device, and a host input reshaped to it crosses to the device
+    as lane-dense rows (``FNOServer``)."""
+    if (path or cfg.path) == "pallas" and cfg.fuse_block and not cfg.fuse_ends:
+        return spectral.kernel_layout(cfg.spatial, cfg.kept_modes)[0]
+    return tuple(cfg.spatial)
+
+
 def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
               *, path: str = None, variant: str = "full") -> jax.Array:
-    """x: [B, in_channels, *spatial] -> [B, out_channels, *spatial].
+    """x: [B, in_channels, *spatial] -> [B, out_channels, *spatial]; x may
+    also come with its trailing axes in ``input_layout``.
 
     Runs at cfg.precision.compute_dtype (the single activation cast lives
     here; the spectral kernels receive the policy and keep their f32
@@ -109,11 +133,26 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
     # The staged ends run under the named scopes "lift" and "projection",
     # which the compiled ops carry in their op_name (the device trace's
     # labels for them); the fused ends are the block_ends launches.
+    act = lambda z: ops.activate(z, cfg.activation)  # noqa: E731
+    # Between its ends a fused model keeps the activations in the forward
+    # kernel's layout (spectral.kernel_layout: a rank-3 grid's trailing
+    # axes merged), so the pointwise ends and the block kernels meet
+    # without a relayout; each block sees its natural shape, and the
+    # reshapes around it cancel.
+    spatial = (tuple(x.shape[2:]) if x.ndim == 2 + cfg.ndim
+               else tuple(cfg.spatial))
+    kspatial = (spectral.kernel_layout(spatial, cfg.kept_modes)[0]
+                if fuse and not ends_on else spatial)
+    x = x.reshape(x.shape[:2] + kspatial)
+    as_grid = lambda a: a.reshape(a.shape[:2] + spatial)  # noqa: E731
     if ends_on:
         h = x
+    elif cfg.lift_layers == 1:
+        with jax.named_scope("lift"):
+            h = shard_activation(_dense(params["lift1"], x), "fno_hidden")
     else:
         with jax.named_scope("lift"):
-            h = jax.nn.gelu(_dense(params["lift1"], x))
+            h = act(_dense(params["lift1"], x))
             h = _dense(params["lift2"], shard_activation(h, "fno_lift"))
             h = shard_activation(h, "fno_hidden")
     # An explicit cfg.block_plan pins the kernel launch plans; otherwise
@@ -121,7 +160,11 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
     bkw = {"block_plan": cfg.block_plan} if cfg.block_plan else {}
     last = cfg.num_layers - 1
     mlp = lambda p: (p["w"], p["b"])
+    modes = tuple(cfg.modes)
+    spec_kw = dict(policy=pol, truncation=cfg.truncation, **bkw)
     for i, blk in enumerate(params["blocks"]):
+        block_act = (cfg.activation if i < last or cfg.act_last
+                     else "linear")
         if fuse:
             # TP collective layout per layer position (DESIGN.md §6):
             # interior layers complete their sharded k-loop with a
@@ -135,36 +178,35 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
                     if ends_on and i == 0 else None)
             proj = (mlp(params["proj1"]) + mlp(params["proj2"])
                     if ends_on and i == last else None)
-            h = sc.apply_fno_block_nd(blk["spectral"], blk["bypass"], h,
-                                      tuple(cfg.modes), path=path,
-                                      variant=variant, policy=pol,
+            h = sc.apply_fno_block_nd(blk["spectral"], blk["bypass"],
+                                      as_grid(h), modes, path=path,
+                                      variant=variant, act=block_act,
                                       tp_layout=layout,
                                       tp_overlap=cfg.tp_overlap,
                                       ends=((lift, proj)
                                             if lift or proj else None),
-                                      **bkw)
+                                      **spec_kw)
+            h = h.reshape(h.shape[:2] + kspatial)
             h = shard_activation(h, "fno" if (ends_on and i == last)
                                  else "fno_hidden")
             continue
         if cfg.ndim == 1:
-            s = sc.apply_spectral_1d(blk["spectral"], h, cfg.modes[0],
+            s = sc.apply_spectral_1d(blk["spectral"], h, modes[0],
                                      path=path, policy=pol, **bkw)
         elif cfg.ndim == 2:
-            s = sc.apply_spectral_2d(blk["spectral"], h, tuple(cfg.modes),
-                                     path=path, variant=variant, policy=pol,
-                                     **bkw)
+            s = sc.apply_spectral_2d(blk["spectral"], h, modes, path=path,
+                                     variant=variant, **spec_kw)
         else:
-            s = sc.apply_spectral_3d(blk["spectral"], h, tuple(cfg.modes),
-                                     path=path, variant=variant, policy=pol,
-                                     **bkw)
-        h = jax.nn.gelu(s.astype(h.dtype) + _dense(blk["bypass"], h))
+            s = sc.apply_spectral_3d(blk["spectral"], h, modes, path=path,
+                                     variant=variant, **spec_kw)
+        h = ops.activate(s.astype(h.dtype) + _dense(blk["bypass"], h),
+                         block_act)
         h = shard_activation(h, "fno_hidden")
     if ends_on:
         return shard_activation(h, "fno")
     with jax.named_scope("projection"):
-        out = _dense(params["proj2"],
-                     jax.nn.gelu(_dense(params["proj1"], h)))
-        return shard_activation(out, "fno")
+        out = _dense(params["proj2"], act(_dense(params["proj1"], h)))
+        return shard_activation(as_grid(out), "fno")
 
 
 def relative_l2(pred: jax.Array, target: jax.Array) -> jax.Array:

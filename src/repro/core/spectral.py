@@ -8,6 +8,13 @@ matrix shapes, and everything runs on the MXU.
 
 Conventions: transforms act on the LAST axis. Complex tensors are carried as
 (real, imag) pairs of real arrays.
+
+Truncation (docs/DESIGN.md §3.4): "low" keeps the first k bins of every
+axis (TurboFNO); "corners" keeps bins {0..k-1} ∪ {n-k..n-1} of every
+complex axis and the first k of the real-input (last) axis (Li et al.'s
+classic FNO). Either way the transform stays a matmul against the kept
+rows; ``kept_modes`` gives the kept count per axis, which is what the
+spectral weight and every operand extent are sized by.
 """
 from __future__ import annotations
 
@@ -17,6 +24,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.configs.base import kept_modes  # noqa: F401  (re-exported)
 
 
 # ---------------------------------------------------------------------------
@@ -57,25 +66,38 @@ def irdft_mats(n: int, modes: int, dtype: str = "float32") -> Tuple[np.ndarray, 
     return ((c * np.cos(ang) / n).astype(dtype), (c * np.sin(ang) / n).astype(dtype))
 
 
+def kept_bins(n: int, kept: int, truncation: str = "low") -> np.ndarray:
+    """The bin indices a complex axis of length n keeps: the first `kept`
+    ("low"), or the kept/2 lowest and kept/2 highest ("corners")."""
+    if truncation == "low":
+        return np.arange(kept)
+    assert kept % 2 == 0 and kept <= n, (n, kept)
+    h = kept // 2
+    return np.concatenate([np.arange(h), np.arange(n - h, n)])
+
+
 @functools.lru_cache(maxsize=64)
 def cdft_mats(n: int, modes: int, inverse: bool = False,
-              dtype: str = "float32") -> Tuple[np.ndarray, np.ndarray]:
-    """Complex truncated DFT matrix.
+              dtype: str = "float32",
+              truncation: str = "low") -> Tuple[np.ndarray, np.ndarray]:
+    """Complex truncated DFT matrix over the kept bins b_m
+    (``kept_bins(n, modes, truncation)``).
 
-    forward: F[k, m] = e^{-2πi km/N},  [n, modes]   (keep first `modes` rows)
-    inverse: E[m, j] = e^{+2πi mj/N}/N, [modes, n]  (zero-pad implicit)
+    forward: F[k, m] = e^{-2πi k·b_m/N},  [n, modes]
+    inverse: E[m, j] = e^{+2πi b_m·j/N}/N, [modes, n]  (zero-pad implicit)
 
     NOTE (paper-faithful): TurboFNO keeps only the FIRST dimX fraction of the
-    complex axis — positive low frequencies only, no hermitian pair. The
-    truncate→pad→inverse round trip is therefore a projection, not identity
-    (classic FNO keeps ± corners instead; see docs/DESIGN.md §3.4).
+    complex axis ("low") — positive low frequencies only, no hermitian pair.
+    The truncate→pad→inverse round trip is therefore a projection, not
+    identity. Classic FNO keeps ± corners ("corners"; docs/DESIGN.md §3.4).
     """
+    bins = kept_bins(n, modes, truncation)
     if not inverse:
         k = np.arange(n)[:, None]
-        m = np.arange(modes)[None, :]
+        m = bins[None, :]
         ang = 2.0 * np.pi * k * m / n
         return (np.cos(ang).astype(dtype), (-np.sin(ang)).astype(dtype))
-    m = np.arange(modes)[:, None]
+    m = bins[:, None]
     j = np.arange(n)[None, :]
     ang = 2.0 * np.pi * m * j / n
     return ((np.cos(ang) / n).astype(dtype), (np.sin(ang) / n).astype(dtype))
@@ -116,13 +138,15 @@ def irdft_adjoint_mats(n: int, modes: int, dtype: str = "float32") -> Tuple[np.n
 
 @functools.lru_cache(maxsize=64)
 def cdft_adjoint_mats(n: int, modes: int, inverse: bool = False,
-                      dtype: str = "float32") -> Tuple[np.ndarray, np.ndarray]:
+                      dtype: str = "float32",
+                      truncation: str = "low"
+                      ) -> Tuple[np.ndarray, np.ndarray]:
     """Transposed complex-DFT operands.
 
     forward transposed: [modes, n] (backward inverse slot);
     inverse transposed: [n, modes] (backward forward slot).
     """
-    fr, fi = cdft_mats(n, modes, inverse, dtype)
+    fr, fi = cdft_mats(n, modes, inverse, dtype, truncation)
     return np.ascontiguousarray(fr.T), np.ascontiguousarray(fi.T)
 
 
@@ -148,8 +172,10 @@ def _pad_np(a: np.ndarray, axis: int, to: int) -> np.ndarray:
     return np.pad(a, cfg)
 
 
-def _fused_mat_pairs(spatial, modes, adjoint, dtype):
-    """numpy (mr, mi) pairs: R forward-slot then R inverse-slot operands."""
+def _fused_mat_pairs(spatial, modes, adjoint, dtype, truncation="low"):
+    """numpy (mr, mi) pairs: R forward-slot then R inverse-slot operands.
+    ``modes`` are kept counts (``kept_modes``); truncation picks the kept
+    bins of the complex axes."""
     r = len(spatial)
     fwd, inv = [], []
     for i in range(r):  # forward stages transform axes s_R, s_{R-1}, …, s_1
@@ -159,23 +185,56 @@ def _fused_mat_pairs(spatial, modes, adjoint, dtype):
             fwd.append(irdft_adjoint_mats(n, k, dtype) if adjoint
                        else rdft_mats(n, k, dtype))
         else:
-            fwd.append(cdft_adjoint_mats(n, k, True, dtype) if adjoint
-                       else cdft_mats(n, k, False, dtype))
+            fwd.append(cdft_adjoint_mats(n, k, True, dtype, truncation)
+                       if adjoint
+                       else cdft_mats(n, k, False, dtype, truncation))
     for ax in range(r):  # inverse stages transform axes s_1, …, s_R
         n, k = spatial[ax], modes[ax]
         if ax == r - 1:
             inv.append(rdft_adjoint_mats(n, k, dtype) if adjoint
                        else irdft_mats(n, k, dtype))
         else:
-            inv.append(cdft_adjoint_mats(n, k, False, dtype) if adjoint
-                       else cdft_mats(n, k, True, dtype))
+            inv.append(cdft_adjoint_mats(n, k, False, dtype, truncation)
+                       if adjoint
+                       else cdft_mats(n, k, True, dtype, truncation))
     return fwd + inv
+
+
+def kernel_layout(spatial, modes) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+    """The (spatial, kept modes) the forward kernel family runs on. Rank 3
+    runs as rank 2 over its trailing axes merged, [s_1, s_2·s_3] with
+    k_2·k_3 modes: a minor axis shorter than 128 lanes (Li et al.'s 40 time
+    steps) would be padded to 128 in HBM and VMEM, and Mosaic reshapes
+    neither it nor the flattened spectrum. The merged axis is contracted
+    against the Kronecker product of the two axes' operands
+    (``_merge_trailing``). Ranks 1 and 2 are their own layout."""
+    spatial, modes = tuple(spatial), tuple(modes)
+    if len(spatial) != 3:
+        return spatial, modes
+    return ((spatial[0], spatial[1] * spatial[2]),
+            (modes[0], modes[1] * modes[2]))
+
+
+def _merge_trailing(pairs):
+    """Rank-3 (mr, mi) pairs in ``_fused_mat_pairs`` order -> the rank-2
+    pairs of ``kernel_layout``: the s_2 and s_3 operands combined into one
+    complex Kronecker product, rows and columns in (s_2, s_3) row-major
+    order, the order of the merged axis and of the weight's merged modes."""
+    def kron(p, q):
+        a = p[0].astype(np.float64) + 1j * p[1].astype(np.float64)
+        b = q[0].astype(np.float64) + 1j * q[1].astype(np.float64)
+        m = np.kron(a, b)
+        return m.real.astype(np.float32), m.imag.astype(np.float32)
+
+    f3, f2, f1, i1, i2, i3 = pairs
+    return [kron(f2, f3), f1, i1, kron(i2, i3)]
 
 
 @functools.lru_cache(maxsize=256)
 def fused_operand_mats(spatial: Tuple[int, ...], modes: Tuple[int, ...],
                        dtype: str = "float32", adjoint: bool = False,
-                       pad_modes_to: int = 0) -> Tuple[np.ndarray, ...]:
+                       pad_modes_to: int = 0,
+                       truncation: str = "low") -> Tuple[np.ndarray, ...]:
     """Flat operand tuple for the rank-generic fused forward/adjoint
     kernel: (cr,ci) per forward stage then (er,ei) per inverse stage.
 
@@ -188,7 +247,7 @@ def fused_operand_mats(spatial: Tuple[int, ...], modes: Tuple[int, ...],
     dt = jnp.dtype(dtype)
     out = []
     for idx, (mr, mi) in enumerate(_fused_mat_pairs(spatial, modes, adjoint,
-                                                    "float32")):
+                                                    "float32", truncation)):
         if pad_modes_to:
             axis = 1 if idx < r else 0  # fwd [n,k] pads cols; inv [k,n] rows
             mr = _pad_np(mr, axis, pad_modes_to)
@@ -225,7 +284,9 @@ def realified_plan(rank: int) -> Tuple[Tuple[Tuple[int, str], ...],
 def realified_operand_mats(spatial: Tuple[int, ...],
                            modes: Tuple[int, ...], dtype: str = "float32",
                            adjoint: bool = False,
-                           pad_modes_to: int = 0) -> Tuple[np.ndarray, ...]:
+                           pad_modes_to: int = 0,
+                           truncation: str = "low"
+                           ) -> Tuple[np.ndarray, ...]:
     """The forward kernel's DFT operands with real and imaginary parts
     packed into one real matrix per stage, in ``realified_plan`` order:
     R forward stages, then R inverse stages. A complex row [zr | zi]
@@ -240,11 +301,14 @@ def realified_operand_mats(spatial: Tuple[int, ...],
     transpose: [Mr | Mi]ᵀ, [2k, n], and [Mr ; −Mi]ᵀ, [n, 2k]. Built from
     the same (mr, mi) pairs as ``fused_operand_mats`` (adjoint=True gives
     the input-cotangent set), once per shape, on the host; pad_modes_to
-    zero-pads each half's modes extent as there."""
-    r = len(spatial)
+    zero-pads each half's modes extent as there. A rank-3 shape gets the
+    2·2 operands of its ``kernel_layout``."""
+    raw = _fused_mat_pairs(spatial, modes, adjoint, "float32", truncation)
+    if len(spatial) == 3:
+        raw = _merge_trailing(raw)
+    r = len(raw) // 2
     pairs = []
-    for idx, pair in enumerate(_fused_mat_pairs(spatial, modes, adjoint,
-                                                "float32")):
+    for idx, pair in enumerate(raw):
         if pad_modes_to:  # fwd [n,k] pads cols; inv [k,n] rows
             pair = tuple(_pad_np(m, 1 if idx < r else 0, pad_modes_to)
                          for m in pair)
@@ -288,15 +352,18 @@ def pack_realified(pairs, rank: int, xp=np) -> Tuple:
 @functools.lru_cache(maxsize=256)
 def wgrad_operand_mats(spatial: Tuple[int, ...], modes: Tuple[int, ...],
                        dtype: str = "float32",
-                       pad_modes_to: int = 0) -> Tuple[np.ndarray, ...]:
+                       pad_modes_to: int = 0,
+                       truncation: str = "low") -> Tuple[np.ndarray, ...]:
     """Flat operand tuple for the fused weight-gradient kernel: R forward
     stages for the primal spectrum A, then R adjoint-forward stages
     (transposed inverse transforms) that push the output cotangent into the
     spectral domain as Ĝ. All [n, k]-oriented, axis s_R first."""
     r = len(spatial)
     dt = jnp.dtype(dtype)
-    pairs = (_fused_mat_pairs(spatial, modes, False, "float32")[:r]
-             + _fused_mat_pairs(spatial, modes, True, "float32")[:r])
+    pairs = (_fused_mat_pairs(spatial, modes, False, "float32",
+                              truncation)[:r]
+             + _fused_mat_pairs(spatial, modes, True, "float32",
+                                truncation)[:r])
     out = []
     for mr, mi in pairs:
         if pad_modes_to:
@@ -393,11 +460,11 @@ def padded_irdft(xr: jax.Array, xi: jax.Array, n: int) -> jax.Array:
             - jax.lax.dot_general(xi, ei, dims, preferred_element_type=f32))
 
 
-def truncated_cdft(xr: jax.Array, xi: jax.Array,
-                   modes: int) -> Tuple[jax.Array, jax.Array]:
-    """Complex DFT along last axis keeping first `modes` bins."""
+def truncated_cdft(xr: jax.Array, xi: jax.Array, modes: int,
+                   truncation: str = "low") -> Tuple[jax.Array, jax.Array]:
+    """Complex DFT along last axis keeping `modes` bins (``kept_bins``)."""
     n = xr.shape[-1]
-    fr, fi = cdft_mats(n, modes, False, "float32")
+    fr, fi = cdft_mats(n, modes, False, "float32", truncation)
     fr, fi = jnp.asarray(fr, xr.dtype), jnp.asarray(fi, xr.dtype)
     dims = (((xr.ndim - 1,), (0,)), ((), ()))
     f32 = jnp.float32
@@ -405,10 +472,12 @@ def truncated_cdft(xr: jax.Array, xi: jax.Array,
     return dot(xr, fr) - dot(xi, fi), dot(xr, fi) + dot(xi, fr)
 
 
-def padded_icdft(xr: jax.Array, xi: jax.Array, n: int) -> Tuple[jax.Array, jax.Array]:
-    """Inverse complex DFT from first-`modes` bins zero-padded to n."""
+def padded_icdft(xr: jax.Array, xi: jax.Array, n: int,
+                 truncation: str = "low") -> Tuple[jax.Array, jax.Array]:
+    """Inverse complex DFT from the kept bins (``kept_bins``), the rest
+    zero, to length n."""
     modes = xr.shape[-1]
-    er, ei = cdft_mats(n, modes, True, "float32")
+    er, ei = cdft_mats(n, modes, True, "float32", truncation)
     er, ei = jnp.asarray(er, xr.dtype), jnp.asarray(ei, xr.dtype)
     dims = (((xr.ndim - 1,), (0,)), ((), ()))
     f32 = jnp.float32

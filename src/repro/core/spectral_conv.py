@@ -86,10 +86,12 @@ def apply_fno_block_nd(spec_params: Dict[str, jax.Array],
                        variant: str = "full",
                        policy: Optional[PrecisionPolicy] = None,
                        tp_layout: str = "psum", tp_overlap: bool = False,
-                       ends: Optional[Tuple] = None,
+                       ends: Optional[Tuple] = None, act: str = "gelu",
                        **kw) -> jax.Array:
-    """One whole FNO block — gelu(spectral(x) + 1×1 bypass + bias) — as a
-    single fused kernel on the pallas path (ops.fno_block_nd), any rank.
+    """One whole FNO block — act(spectral(x) + 1×1 bypass + bias) — as a
+    single fused kernel on the pallas path (ops.fno_block_nd), any rank;
+    act is "gelu", "relu" or "linear", and ``truncation`` (in kw) picks
+    the kept bins (ops.fno_block_nd).
 
     spec_params: {"wr","wi"} from init_spectral_nd; byp_params: {"w","b"}
     from core.fno._dense_init, where w is [C_in, C_out] (einsum
@@ -115,13 +117,14 @@ def apply_fno_block_nd(spec_params: Dict[str, jax.Array],
             x, spec_params["wr"], spec_params["wi"], wb, byp_params["b"],
             tuple(modes), mesh=ctx.mesh, batch_axes=ctx.batch_axes,
             model_axis=ctx.model_axis, variant=variant, policy=policy,
-            tp_layout=tp_layout, tp_overlap=tp_overlap,
+            act=act, tp_layout=tp_layout, tp_overlap=tp_overlap,
             ends=ends if has_ends else None, **kw)
     if has_ends:
+        assert kw.pop("truncation", "low") == "low", "fused ends: low corner"
         return ops.fno_block_ends_nd(
             x, spec_params["wr"], spec_params["wi"], wb, byp_params["b"],
             tuple(modes), lift=ends[0], proj=ends[1], path=path,
             variant=variant, policy=policy, **kw)
     return ops.fno_block_nd(x, spec_params["wr"], spec_params["wi"], wb,
                             byp_params["b"], tuple(modes), path=path,
-                            variant=variant, policy=policy, **kw)
+                            variant=variant, policy=policy, act=act, **kw)

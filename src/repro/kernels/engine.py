@@ -35,11 +35,18 @@ rank 2:
 block nor the y block is transposed in the kernel; the halves move
 between axes on the small spectra.
 
-For per-mode weights W[bo,bh,K_1..K_R] the CGEMM stays four real matmuls
-batched over the flattened spectral axes (W laid out [∏K,bo,bh] outside
-the kernel) into two accumulators [∏K,bb,bo]: packing a per-mode weight
-would double the weight bytes read per step. Rank 1 pads its modes to
-128 lanes; rank 2 is the full-fusion 2D kernel; rank 3 the 3D FNO layer.
+For per-mode weights W[bo,bh,K_1..K_R] the CGEMM is not packed: packing
+would double the weight bytes read per step. Where the spectrum's
+complex minor axis fills whole 128-lane vregs (``vpu_cgemm``) it runs on
+the VPU, four elementwise products per hidden channel over the modes as
+the spectrum lays them out (W laid out [H,O,K_1,K_2]), into two
+accumulators [bb,bo,K_1,K_2]: with a few dozen channels each mode's
+matmul would fill a sliver of an MXU tile, and a [∏K,bo,bh] weight
+window would be padded to 128 lanes. Otherwise it stays four real
+matmuls batched over the flattened spectral axes (W laid out [∏K,bo,bh]
+outside the kernel) into two accumulators [∏K,bb,bo]. Rank 1 pads its
+modes to 128 lanes; rank 2 is the full-fusion 2D kernel; rank 3 runs as
+rank 2 over its trailing axes merged (``spectral.kernel_layout``).
 
 Three kernel families:
 
@@ -50,10 +57,11 @@ Three kernel families:
     standard FNO block ``gelu(spectral(h) + bypass(h) + bias)`` contracts
     the same hidden axis as the CGEMM k-loop, so its GEMM rides the same
     grid into a third VMEM accumulator and the last-k epilogue applies
-    ``+bypass → +bias → gelu`` before the single ref write — one
-    pallas_call for the whole FNO block. ``act="gelu_vjp"`` is the
-    backward recompute: the epilogue forms ``gz = gy·gelu'(z)`` from the
-    recomputed pre-activation without materializing z in HBM.
+    ``+bypass → +bias → activation`` (GELU, ReLU or none) before the
+    single ref write — one pallas_call for the whole FNO block.
+    ``act="gelu_vjp"``/``"relu_vjp"`` is the backward recompute: the
+    epilogue forms ``gz = gy·act'(z)`` from the recomputed pre-activation
+    without materializing z in HBM.
   * ``fused_fnond_core_call``  — paper-faithful partial fusion: only the
     DFT stage adjacent to the CGEMM is fused (complex in/out); the outer
     R-1 transforms run as standalone kernels (dft.py), matching TurboFNO,
@@ -93,10 +101,12 @@ _SEMANTICS = ("parallel", "parallel", "arbitrary")
 # OPTIONAL operands absent from every launch kind the cache tunes — an
 # ends-fused launch reuses the block_fwd plan with bo pinned to the padded
 # O, so tuned winners for the default launches stay exactly valid.
-BLOCK_SIGNATURE = ("fnond-v3:grid=(b/bb,o/bo,h/bh);wgrad-grid=(o/bo,h/bh,"
+BLOCK_SIGNATURE = ("fnond-v4:grid=(b/bb,o/bo,h/bh);wgrad-grid=(o/bo,h/bh,"
                    "b/bb);acc=realified[bb*K,2bo]@accum+bypass;"
-                   "per_mode-acc=flat_modes;weights=[H/bh,O,bh];"
+                   "per_mode-acc=flat_modes|vpu[bb,bo,K1,K2];"
+                   "weights=[H/bh,O,bh]|vpu[H,O,K1,K2];"
                    "shared-weights=[H/bh,O/bo,2bo,2bh];"
+                   "fwd-rank3=merged[s1,s2*s3];"
                    "mats=realified;vmem=96MiB;launches=block_fwd,"
                    "gz_recompute,dx_adjoint,wgrad,core")
 
@@ -288,18 +298,30 @@ def _dgelu(z):
 #     y = W_p2ᵀ·gelu(W_p1ᵀ·z + b_p1) + b_p2 — and the ref write emits the
 #     model OUTPUT channels [bb, C_out, s…].
 # ---------------------------------------------------------------------------
+def vpu_cgemm(rank: int, modes: Tuple[int, ...]) -> bool:
+    """Whether a per-mode CGEMM of a rank-`rank` kernel over kept `modes`
+    runs on the VPU: the forward chain leaves the rank-2 spectrum as
+    [bb, bh, K_1, re K_2 | im K_2], and each half is whole vregs when
+    K_2 is a multiple of 128 (the merged rank-3 layout of Li et al.'s
+    FNO-3D: 16·8 modes)."""
+    return rank == 2 and modes[-1] % 128 == 0
+
+
 def fwd_contractions(rank: int, per_mode: bool, has_wb: bool = False,
-                     has_lift: bool = False,
-                     has_proj: bool = False) -> Tuple[int, int]:
+                     has_lift: bool = False, has_proj: bool = False,
+                     vpu: bool = False) -> Tuple[int, int]:
     """(contractions, realified) that one grid step of a forward kernel
     issues — every ``dot_general`` of the body, the k==0 lift prologue
     and the last-k epilogue included. Realified: each of the 2R DFT
     stages and the shared-weight CGEMM, one real matmul per complex
     product. The per-mode CGEMM stays 4 real matmuls over two
-    accumulators; the bypass and the end MLPs are real products. The
-    kernel checks this count as it traces."""
+    accumulators, or none where it runs on the VPU (``vpu_cgemm``); the
+    bypass and the end MLPs are real products. ``rank`` is the kernel's
+    (``spectral.kernel_layout``: 2 for a rank-3 layer). The kernel checks
+    this count as it traces."""
     realified = 2 * rank + (0 if per_mode else 1)
-    plain = 4 * per_mode + has_wb + 2 * has_lift + 2 * has_proj
+    plain = (4 * (per_mode and not vpu) + has_wb + 2 * has_lift
+             + 2 * has_proj)
     return realified + plain, realified
 
 
@@ -331,14 +353,14 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
                      has_wb: bool = False, has_bias: bool = False,
                      act: str = "linear", has_lift: bool = False,
                      has_proj: bool = False, interpret: bool = False,
-                     rev_modes: Tuple[int, ...] = ()):
+                     rev_modes: Tuple[int, ...] = (), vpu: bool = False):
     r = rank
     acc = jnp.dtype(acc_dtype)
     mm = _matmul(acc_dtype, interpret)
-    has_gy = act == "gelu_vjp"
+    has_gy = act in ("gelu_vjp", "relu_vjp")
     fwd_plan, inv_plan = realified_plan(r)
     spatial_axes = tuple(("s", j) for j in range(r))
-    want = fwd_contractions(r, per_mode, has_wb, has_lift, has_proj)
+    want = fwd_contractions(r, per_mode, has_wb, has_lift, has_proj, vpu)
 
     def kernel(*refs):
         nw = 2 if per_mode else 1
@@ -422,7 +444,16 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
             cx = ("k", j)
 
         # CGEMM over hidden (the k-loop MAC).
-        if per_mode:
+        if vpu:
+            # Elementwise over the modes, where they lie: A [bb,bh,K_1,K_2]
+            # halves against W [bh,bo,K_1,K_2], summed over the hidden
+            # block -> [bb,bo,K_1,K_2].
+            assert axes == ("b", "h", ("k", 0), ("k", 1)) and cx == axes[3]
+            ar, ai = (t[:, :, None] for t in _halves(z, 3))
+            wr, wi = w_refs[0][...], w_refs[1][...]
+            accs[0][...] += jnp.sum(ar * wr - ai * wi, axis=1)
+            accs[1][...] += jnp.sum(ar * wi + ai * wr, axis=1)
+        elif per_mode:
             # Four real matmuls: the halves in the K_R..K_1 order of the
             # weight, the modes flattened into the one batch dim: A
             # [∏K,bb,bh] against W [∏K,bo,bh] -> [∏K,bb,bo].
@@ -452,7 +483,10 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
         def _epilogue():
             # Padded inverse DFT chain, realified; the last stage emits
             # only the real part (real output).
-            if per_mode:  # [∏K,bb,bo] pair -> [bb, re K_R | im K_R, …, bo]
+            if vpu:  # [bb,bo,K_1,K_2] pair -> [bb, bo, K_1, re|im K_2]
+                z = jnp.concatenate([a[...] for a in accs], axis=3)
+                axes, cx = ("b", "o", ("k", 0), ("k", 1)), ("k", 1)
+            elif per_mode:  # [∏K,bb,bo] pair -> [bb, re K_R | im K_R, …, bo]
                 shared = (accs[0].shape[1],) + rev_modes + (accs[0].shape[2],)
                 z = jnp.concatenate(
                     [jnp.swapaxes(a[...], 0, 1).reshape(shared)
@@ -477,8 +511,12 @@ def _make_fwd_kernel(rank: int, per_mode: bool, acc_dtype: str = "float32",
                 z = z + bias_ref[...].astype(acc).reshape((1, -1) + (1,) * r)
             if act == "gelu":
                 z = jax.nn.gelu(z, approximate=True)
+            elif act == "relu":
+                z = jnp.maximum(z, 0.0)
             elif act == "gelu_vjp":
                 z = gy_ref[...].astype(acc) * _dgelu(z)
+            elif act == "relu_vjp":
+                z = jnp.where(z > 0.0, gy_ref[...].astype(acc), 0.0)
             if has_proj:
                 # Projection epilogue on the activated block output z
                 # [bb,O,s…] (bo == padded O — single j step): the pointwise
@@ -526,8 +564,10 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     Block epilogue (all optional, see ``fused_fno_block_call``):
     wb [O,H] accumulates the 1×1 bypass GEMM alongside the CGEMM k-loop;
     bias [O,1] adds per-out-channel; act picks the epilogue nonlinearity —
-    "linear" (default), "gelu" (forward block), or "gelu_vjp" (backward
-    recompute: requires gy [B,O,s_1..s_R] and emits gy·gelu'(z)).
+    "linear" (default), "gelu" or "relu" (forward block), or "gelu_vjp" /
+    "relu_vjp" (backward recompute: requires gy [B,O,s_1..s_R] and emits
+    gy·act'(z)). A per-mode weight whose CGEMM runs on the VPU
+    (``vpu_cgemm``) comes as [O,H,K_1,K_2].
 
     Fused model ends (forward block kernels only — incompatible with gy):
     lift = (l1w [L,C_in], l1b [L,1], l2w [H,L], l2b [H,1]) folds the
@@ -546,15 +586,16 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     o = wr.shape[0]
     per_mode = wr.ndim == 2 + r
     assert len(mats) == 2 * r, (len(mats), r)
-    assert act in ("linear", "gelu", "gelu_vjp"), act
+    assert act in ("linear", "gelu", "relu", "gelu_vjp", "relu_vjp"), act
     assert name in LAUNCH_NAMES, name
-    assert (gy is not None) == (act == "gelu_vjp"), act
+    assert (gy is not None) == act.endswith("_vjp"), act
     assert gy is None or (lift is None and proj is None), \
         "fused ends are forward-only (backward is the staged vjp)"
     assert proj is None or bo == o, \
         "the projection epilogue contracts the full padded O: bo must == O"
     modes = _packed_modes(mats, r)
     rev_modes = modes[::-1]  # the per-mode weight's flattening order
+    vpu = per_mode and vpu_cgemm(r, modes)
     grid = (b // bb, o // bo, h // bh)
     zr = (0,) * r
 
@@ -565,7 +606,12 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     else:
         x_spec = pl.BlockSpec((bb, bh) + spatial,
                               lambda i, j, k: (i, k) + zr)
-    if per_mode:
+    if vpu:
+        ws = [jnp.swapaxes(w, 0, 1) for w in (wr, wi)]  # [H, O, K_1, K_2]
+        w_specs = [pl.BlockSpec((bh, bo) + modes,
+                                lambda i, j, k: (k, j, 0, 0))] * 2
+        acc_shapes = [(bb, bo) + modes] * 2
+    elif per_mode:
         ws = [_hidden_blocked(_modes_leading(w, r), bh) for w in (wr, wi)]
         kf = ws[0].shape[1]
         w_specs = [pl.BlockSpec((None, kf, bo, bh),
@@ -623,7 +669,7 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     return pl.pallas_call(
         _make_fwd_kernel(r, per_mode, acc_dtype, wb is not None,
                          bias is not None, act, lift is not None,
-                         proj is not None, interpret, rev_modes),
+                         proj is not None, interpret, rev_modes, vpu),
         grid=grid,
         in_specs=in_specs,
         out_specs=y_spec,

@@ -65,7 +65,11 @@ def _pick_block(dim: int, pref: int) -> int:
     the larger block (fewer grid steps). This keeps prime/odd extents
     from forcing near-2× padding — e.g. dim=129 under pref=128 pads to
     136 via b=8, not to 256 via b=128 — while exact-fit dims still get
-    the largest divisor ≤ pref."""
+    the largest divisor ≤ pref. A preference equal to the dim is taken as
+    it is: a block that spans the whole dim is always legal (Li et al.'s
+    width 20 runs as one 20-wide block, not padded to 24)."""
+    if pref == dim:
+        return dim
     if pref < 8:
         cands = range(1, pref + 1)
     else:
@@ -271,9 +275,17 @@ def _default_policy(x, wr) -> PrecisionPolicy:
                            compute_dtype=xd, spectral_dtype=xd)
 
 
+def _trailing(a, shape):
+    """a [B or O, H or O, …] with its trailing axes reshaped to `shape`:
+    into or out of ``spectral.kernel_layout`` (rank 3 merges its last two
+    axes, a row-major reshape)."""
+    return a.reshape(a.shape[:2] + tuple(shape))
+
+
 def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
                  adjoint: bool = False, out_dtype: str = None,
-                 wb=None, bias=None, gy=None, act: str = "linear"):
+                 wb=None, bias=None, gy=None, act: str = "linear",
+                 truncation: str = "low"):
     """Pad to block multiples and invoke the rank-generic fused kernel.
 
     adjoint=True runs the input-cotangent pipeline: transposed DFT
@@ -281,18 +293,26 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     overrides the emission dtype (backward emits dx at the primal dtype
     straight from the accumulator). wb [O,H] / bias [O] / act extend the
     kernel with the block epilogue (bypass GEMM riding the k-loop,
-    +bias → activation at the ref write); gy feeds the "gelu_vjp"
-    backward-recompute epilogue.
+    +bias → activation at the ref write); gy feeds the "gelu_vjp" /
+    "relu_vjp" backward-recompute epilogue. ``modes`` are kept counts
+    (``spectral.kept_modes``). A rank-3 layer runs in its kernel layout
+    (``spectral.kernel_layout``): x, gy, y and a per-mode weight are
+    reshaped around the call, which XLA folds away between blocks.
     """
-    r = len(modes)
-    b, h = x.shape[:2]
+    spatial = tuple(x.shape[2:])
+    ksp, kmodes = spectral.kernel_layout(spatial, modes)
+    x = _trailing(x, ksp)
+    gy = None if gy is None else _trailing(gy, ksp)
+    per_mode = wr.ndim == 2 + len(modes)
+    if per_mode:
+        wr, wi = (_trailing(w, kmodes) for w in (wr, wi))
+    b = x.shape[0]
     o = wr.shape[0]
-    per_mode = wr.ndim == 2 + r
     kp = _mode_pad(modes)
     bb, bo, bh, bp, op_, hp = _blocks(x, o, bb, bo, bh)
     mats = spectral.realified_operand_mats(
-        tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype,
-        adjoint, kp)
+        spatial, _modes_key(modes), pol.spectral_dtype, adjoint, kp,
+        truncation)
     xpad = _pad_axis(_pad_axis(x, 0, bp), 1, hp)
 
     def wpad(w):
@@ -304,7 +324,7 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     biasp = None if bias is None else _pad_axis(bias[:, None], 0, op_)
     gyp = None if gy is None else _pad_axis(_pad_axis(gy, 0, bp), 1, op_)
     name = ("dx_adjoint" if adjoint else
-            "gz_recompute" if act == "gelu_vjp" else
+            "gz_recompute" if act.endswith("_vjp") else
             "spectral_fwd" if wb is None else "block_fwd")
     y = engine.fused_fnond_call(xpad, wpad(wr), wpad(wi), *mats,
                                 bb=bb, bo=bo, bh=bh, name=name,
@@ -312,7 +332,7 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
                                 out_dtype=out_dtype,
                                 acc_dtype=pol.accum_dtype,
                                 wb=wbp, bias=biasp, gy=gyp, act=act)
-    return y[:b, :o]
+    return _trailing(y[:b, :o], spatial)
 
 
 def _outer_fwd_batched(x, spatial, modes, interpret, operand_dtype=None,
@@ -414,7 +434,8 @@ def _fnond_partial(x, wr, wi, modes, bb, bo, bh, interpret, pol):
 
 
 def _fnond_wgrad(x, gy, modes, bb, bo, bh, interpret, per_mode, pol,
-                 out_dtype: str = None, with_bypass: bool = False):
+                 out_dtype: str = None, with_bypass: bool = False,
+                 truncation: str = "low"):
     """Fused weight cotangent: conj(Σ_b Ĝ·A) rank reduction; dW emitted at
     out_dtype (the param dtype under mixed precision). with_bypass=True
     (fused-block backward) appends (dwb [O,H], dbias [O]) from the same
@@ -425,7 +446,8 @@ def _fnond_wgrad(x, gy, modes, bb, bo, bh, interpret, per_mode, pol,
     kp = _mode_pad(modes)
     bb, bo, bh, bp, op_, hp = _blocks(x, o, bb, bo, bh)
     mats = spectral.wgrad_operand_mats(
-        tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype, kp)
+        tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype, kp,
+        truncation)
     xpad = _pad_axis(_pad_axis(x, 0, bp), 1, hp)
     gpad = _pad_axis(_pad_axis(gy, 0, bp), 1, op_)
     out = engine.fused_fnond_wgrad_call(
@@ -443,7 +465,16 @@ def _fnond_wgrad(x, gy, modes, bb, bo, bh, interpret, per_mode, pol,
     return (dwr[:o, :h], dwi[:o, :h]) + extra
 
 
-def _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret, pol):
+def _check_partial(variant, truncation):
+    """The paper-faithful partial fusion keeps TurboFNO's low corner."""
+    if variant != "full" and truncation != "low":
+        raise NotImplementedError(
+            f"variant={variant!r} keeps the low corner only; "
+            f"truncation={truncation!r} runs on the full-fusion variant")
+
+
+def _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret, pol,
+                       truncation):
     # The compute-dtype casts live INSIDE the custom_vjp: primals (and
     # therefore the cotangents the caller sees) stay at the caller's
     # dtypes, while the kernels run at pol.compute_dtype. `plans` is the
@@ -452,24 +483,27 @@ def _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret, pol):
     cp = jnp.dtype(pol.compute_dtype)
     x, wr, wi = x.astype(cp), wr.astype(cp), wi.astype(cp)
     if variant == "full":
-        return _fnond_fused(x, wr, wi, modes, *plans.fwd, interpret, pol)
+        return _fnond_fused(x, wr, wi, modes, *plans.fwd, interpret, pol,
+                            truncation=truncation)
     return _fnond_partial(x, wr, wi, modes, *plans.core, interpret, pol)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _spectral_layer_nd_pallas(x, wr, wi, modes, variant, plans, interpret,
-                              pol):
+                              pol, truncation):
     return _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret,
-                              pol)
+                              pol, truncation)
 
 
-def _fnond_vjp_fwd(x, wr, wi, modes, variant, plans, interpret, pol):
+def _fnond_vjp_fwd(x, wr, wi, modes, variant, plans, interpret, pol,
+                   truncation):
     y = _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret,
-                           pol)
+                           pol, truncation)
     return y, (x, wr, wi)
 
 
-def _fnond_vjp_bwd(modes, variant, plans, interpret, pol, res, gy):
+def _fnond_vjp_bwd(modes, variant, plans, interpret, pol, truncation, res,
+                   gy):
     # partial and full compute the same linear map, so one adjoint (the
     # fully fused one) serves both variants. Mixed precision: operands run
     # at pol.compute_dtype, the accumulators at pol.accum_dtype (f32), and
@@ -481,24 +515,26 @@ def _fnond_vjp_bwd(modes, variant, plans, interpret, pol, res, gy):
     wrc, wic = wr.astype(cp), wi.astype(cp)
     dx = _fnond_fused(gy, jnp.swapaxes(wrc, 0, 1), jnp.swapaxes(wic, 0, 1),
                       modes, *plans.dx, interpret, pol, adjoint=True,
-                      out_dtype=jnp.dtype(x.dtype).name)
+                      out_dtype=jnp.dtype(x.dtype).name,
+                      truncation=truncation)
     dwr, dwi = _fnond_wgrad(x.astype(cp), gy, modes, *plans.wgrad,
                             interpret,
                             per_mode=wr.ndim == 2 + len(modes), pol=pol,
-                            out_dtype=jnp.dtype(wr.dtype).name)
+                            out_dtype=jnp.dtype(wr.dtype).name,
+                            truncation=truncation)
     return (dx.astype(x.dtype), dwr.astype(wr.dtype), dwi.astype(wi.dtype))
 
 
 _spectral_layer_nd_pallas.defvjp(_fnond_vjp_fwd, _fnond_vjp_bwd)
 
 
-def _fnond_xla(x, wr, wi, modes, pol=None):
+def _fnond_xla(x, wr, wi, modes, pol=None, truncation: str = "low"):
     """Staged matmul formulation of the rank-R layer, fused by XLA.
 
     With a policy, operands are cast to the compute dtype first and the
     result is emitted at it — the parity reference for the pallas path at
     matching precision (accumulation stays f32 via preferred_element_type
-    inside the spectral helpers)."""
+    inside the spectral helpers). ``modes`` are kept counts."""
     if pol is not None:
         cp = jnp.dtype(pol.compute_dtype)
         x, wr, wi = x.astype(cp), wr.astype(cp), wi.astype(cp)
@@ -509,7 +545,8 @@ def _fnond_xla(x, wr, wi, modes, pol=None):
     for j in range(1, r):  # cDFT along s_{R-1}…s_1 -> [B,H,K_R..K_1]
         zr = jnp.moveaxis(zr, -(j + 1), -1)
         zi = jnp.moveaxis(zi, -(j + 1), -1)
-        zr, zi = spectral.truncated_cdft(zr, zi, modes[r - 1 - j])
+        zr, zi = spectral.truncated_cdft(zr, zi, modes[r - 1 - j],
+                                         truncation)
     fwd = "uvw"[:r]           # K_1..K_R (the weight layout order)
     rev = fwd[::-1]           # K_R..K_1 (the spectrum layout order)
     eq = (f"oh{fwd},bh{rev}->bo{rev}" if per_mode
@@ -517,7 +554,7 @@ def _fnond_xla(x, wr, wi, modes, pol=None):
     yr = jnp.einsum(eq, wr, zr) - jnp.einsum(eq, wi, zi)
     yi = jnp.einsum(eq, wr, zi) + jnp.einsum(eq, wi, zr)
     for j in range(r - 1):  # icDFT along s_1…s_{R-1}
-        yr, yi = spectral.padded_icdft(yr, yi, spatial[j])
+        yr, yi = spectral.padded_icdft(yr, yi, spatial[j], truncation)
         yr = jnp.moveaxis(yr, -1, 2 + j)
         yi = jnp.moveaxis(yi, -1, 2 + j)
     y = spectral.padded_irdft(yr, yi, spatial[-1])
@@ -555,21 +592,25 @@ def _resolve_plans(x, wr, modes, pol, bb, bo, bh,
 
 
 def _spectral_layer_nd(x, wr, wi, modes, path, variant, bb, bo, bh,
-                       interpret, policy=None, block_plan=None):
+                       interpret, policy=None, block_plan=None,
+                       truncation="low"):
+    """The rank-R spectral layer at kept counts ``modes``
+    (``spectral.kept_modes``)."""
     modes = _modes_key(modes)
     if path == "ref":
         if policy is not None:  # oracle runs in f32, emits at compute dtype
             y32 = ref_k.ref_fnond(x.astype(jnp.float32),
                                   wr.astype(jnp.float32),
-                                  wi.astype(jnp.float32), modes)
+                                  wi.astype(jnp.float32), modes, truncation)
             return y32.astype(policy.compute_dtype)
-        return ref_k.ref_fnond(x, wr, wi, modes)
+        return ref_k.ref_fnond(x, wr, wi, modes, truncation)
     if path == "xla":
-        return _fnond_xla(x, wr, wi, modes, policy)
+        return _fnond_xla(x, wr, wi, modes, policy, truncation)
+    _check_partial(variant, truncation)
     pol = policy or _default_policy(x, wr)
     plans = _resolve_plans(x, wr, modes, pol, bb, bo, bh, block_plan)
     return _spectral_layer_nd_pallas(x, wr, wi, modes, variant, plans,
-                                     _interpret(interpret), pol)
+                                     _interpret(interpret), pol, truncation)
 
 
 def spectral_layer_1d(x: jax.Array, wr: jax.Array, wi: jax.Array,
@@ -599,9 +640,11 @@ def spectral_layer_2d(x: jax.Array, wr: jax.Array, wi: jax.Array,
                       bh: int = 0,
                       interpret: Optional[bool] = None,
                       policy: Optional[PrecisionPolicy] = None,
-                      block_plan: Optional[Tuple[int, int, int]] = None
-                      ) -> jax.Array:
-    """Full 2D FNO spectral layer, TurboFNO truncation convention.
+                      block_plan: Optional[Tuple[int, int, int]] = None,
+                      truncation: str = "low") -> jax.Array:
+    """Full 2D FNO spectral layer, TurboFNO truncation convention by
+    default (``truncation="corners"``: Li et al.'s ± corners, a weight
+    [O,H,2kx,ky]).
 
     x: [B,H,X,Y]; w: [O,H] or [O,H,kx,ky]. variant: "partial" fuses only
     around the CGEMM (paper-faithful); "full" fuses the entire layer
@@ -609,8 +652,10 @@ def spectral_layer_2d(x: jax.Array, wr: jax.Array, wi: jax.Array,
     custom_vjp (fused backward for both variants). policy / block
     selection: see spectral_layer_1d.
     """
-    return _spectral_layer_nd(x, wr, wi, modes, path, variant, bb, bo, bh,
-                              interpret, policy, block_plan)
+    return _spectral_layer_nd(x, wr, wi,
+                              spectral.kept_modes(modes, truncation), path,
+                              variant, bb, bo, bh, interpret, policy,
+                              block_plan, truncation)
 
 
 def spectral_layer_3d(x: jax.Array, wr: jax.Array, wi: jax.Array,
@@ -619,9 +664,10 @@ def spectral_layer_3d(x: jax.Array, wr: jax.Array, wi: jax.Array,
                       bh: int = 0,
                       interpret: Optional[bool] = None,
                       policy: Optional[PrecisionPolicy] = None,
-                      block_plan: Optional[Tuple[int, int, int]] = None
-                      ) -> jax.Array:
-    """Full 3D FNO spectral layer (Navier–Stokes-class workloads).
+                      block_plan: Optional[Tuple[int, int, int]] = None,
+                      truncation: str = "low") -> jax.Array:
+    """Full 3D FNO spectral layer (Navier–Stokes-class workloads);
+    truncation as in spectral_layer_2d (corners: [O,H,2kx,2ky,kz]).
 
     x: [B,H,X,Y,Z]; w: [O,H] or [O,H,kx,ky,kz]. Same engine, rank pinned
     to 3: variant "full" fuses the whole layer in one kernel; "partial"
@@ -630,8 +676,10 @@ def spectral_layer_3d(x: jax.Array, wr: jax.Array, wi: jax.Array,
     path="pallas" is differentiable via custom_vjp (fused backward for
     both variants). policy / block selection: see spectral_layer_1d.
     """
-    return _spectral_layer_nd(x, wr, wi, modes, path, variant, bb, bo, bh,
-                              interpret, policy, block_plan)
+    return _spectral_layer_nd(x, wr, wi,
+                              spectral.kept_modes(modes, truncation), path,
+                              variant, bb, bo, bh, interpret, policy,
+                              block_plan, truncation)
 
 
 # ---------------------------------------------------------------------------
@@ -664,22 +712,35 @@ def _block_tail(s, x, wb, bias, out_dtype, act: str = "gelu"):
                      preferred_element_type=jnp.float32)
     z = (s.astype(jnp.float32) + byp
          + bias.astype(jnp.float32).reshape((1, -1) + (1,) * (x.ndim - 2)))
+    return activate(z, act).astype(out_dtype)
+
+
+ACTIVATIONS = ("gelu", "relu", "linear")
+
+
+def activate(z, act: str):
+    """The block activation: tanh-approximate GELU, ReLU, or none."""
     if act == "gelu":
-        z = jax.nn.gelu(z)
-    return z.astype(out_dtype)
+        return jax.nn.gelu(z)
+    if act == "relu":
+        return jax.nn.relu(z)
+    assert act == "linear", act
+    return z
 
 
-def _fno_block_oracle(x, wr, wi, wb, bias, modes, path, pol, act="gelu"):
+def _fno_block_oracle(x, wr, wi, wb, bias, modes, path, pol, act="gelu",
+                      truncation="low"):
     """Staged parity oracle: spectral layer (ref/xla path) + XLA bypass +
-    bias + activation — the exact math the one-kernel pallas path fuses."""
+    bias + activation — the exact math the one-kernel pallas path fuses.
+    ``modes`` are kept counts."""
     s = _spectral_layer_nd(x, wr, wi, modes, path, "full", 0, 0, 0,
-                           None, pol)
+                           None, pol, truncation=truncation)
     cp = jnp.dtype(pol.compute_dtype) if pol is not None else x.dtype
     return _block_tail(s, x.astype(cp), wb, bias, s.dtype, act)
 
 
 def _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
-                    interpret, pol, act, out_dtype):
+                    interpret, pol, act, out_dtype, truncation):
     # Same cast contract as the spectral layer: compute-dtype casts live
     # inside the custom_vjp so the caller's primal/cotangent dtypes are
     # preserved (PrecisionPolicy — ROADMAP.md §Precision policy).
@@ -691,7 +752,8 @@ def _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
     x, wr, wi, wb, bias = (a.astype(cp) for a in (x, wr, wi, wb, bias))
     if variant == "full":
         return _fnond_fused(x, wr, wi, modes, *plans.fwd, interpret, pol,
-                            wb=wb, bias=bias, act=act, out_dtype=od.name)
+                            wb=wb, bias=bias, act=act, out_dtype=od.name,
+                            truncation=truncation)
     # Paper-faithful partial fusion keeps the multi-kernel spectral
     # pipeline; the block tail (bypass+bias+act) runs as XLA ops. The
     # BACKWARD still uses the fully fused adjoint (one linear map).
@@ -700,31 +762,32 @@ def _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
 
 
 @functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+                   nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _fno_block_nd_pallas(x, wr, wi, wb, bias, modes, variant, plans,
-                         interpret, pol, act, out_dtype):
+                         interpret, pol, act, out_dtype, truncation):
     return _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
-                           interpret, pol, act, out_dtype)
+                           interpret, pol, act, out_dtype, truncation)
 
 
 def _fno_block_vjp_fwd(x, wr, wi, wb, bias, modes, variant, plans,
-                       interpret, pol, act, out_dtype):
+                       interpret, pol, act, out_dtype, truncation):
     y = _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
-                        interpret, pol, act, out_dtype)
+                        interpret, pol, act, out_dtype, truncation)
     return y, (x, wr, wi, wb, bias)
 
 
 def _fno_block_vjp_bwd(modes, variant, plans, interpret, pol, act,
-                       out_dtype, res, gy):
+                       out_dtype, truncation, res, gy):
     x, wr, wi, wb, bias = res
     cp = jnp.dtype(pol.compute_dtype)
     xc, wrc, wic, wbc, biasc = (a.astype(cp) for a in (x, wr, wi, wb, bias))
     gyc = gy.astype(cp)
-    if act == "gelu":
+    if act != "linear":
         # (1) recompute the pre-activation through the fused forward and
-        # form gz = gy·gelu'(z) in the epilogue — z never reaches HBM.
+        # form gz = gy·act'(z) in the epilogue — z never reaches HBM.
         gz = _fnond_fused(xc, wrc, wic, modes, *plans.gz, interpret, pol,
-                          wb=wbc, bias=biasc, gy=gyc, act="gelu_vjp")
+                          wb=wbc, bias=biasc, gy=gyc, act=act + "_vjp",
+                          truncation=truncation)
     else:
         # Linear block (the TP-sharded partial): z IS the output, so the
         # incoming cotangent is gz directly — no recompute kernel.
@@ -735,13 +798,14 @@ def _fno_block_vjp_bwd(modes, variant, plans, interpret, pol, act,
     dx = _fnond_fused(gz, jnp.swapaxes(wrc, 0, 1), jnp.swapaxes(wic, 0, 1),
                       modes, *plans.dx, interpret, pol, adjoint=True,
                       out_dtype=jnp.dtype(x.dtype).name,
-                      wb=jnp.swapaxes(wbc, 0, 1))
+                      wb=jnp.swapaxes(wbc, 0, 1), truncation=truncation)
     # (3) dW, dW_b, dbias from ONE extended wgrad kernel, emitted at the
     # param dtype straight from the f32 accumulators.
     dwr, dwi, dwb, db = _fnond_wgrad(
         xc, gz, modes, *plans.wgrad, interpret,
         per_mode=wr.ndim == 2 + len(modes), pol=pol,
-        out_dtype=jnp.dtype(wr.dtype).name, with_bypass=True)
+        out_dtype=jnp.dtype(wr.dtype).name, with_bypass=True,
+        truncation=truncation)
     return (dx.astype(x.dtype), dwr.astype(wr.dtype), dwi.astype(wi.dtype),
             dwb.astype(wb.dtype), db.astype(bias.dtype))
 
@@ -757,11 +821,14 @@ def fno_block_nd(x: jax.Array, wr: jax.Array, wi: jax.Array, wb: jax.Array,
                  policy: Optional[PrecisionPolicy] = None,
                  act: str = "gelu",
                  out_dtype: Optional[str] = None,
-                 block_plan: Optional[Tuple[int, int, int]] = None
+                 block_plan: Optional[Tuple[int, int, int]] = None,
+                 truncation: str = "low"
                  ) -> jax.Array:
     """One whole FNO block: y = act(spectral(x) + x·W_bᵀ + bias).
 
-    x: [B,H,s_1..s_R]; wr/wi: [O,H] or [O,H,k_1..k_R] spectral weight;
+    x: [B,H,s_1..s_R]; wr/wi: [O,H] or [O,H,k_1..k_R] spectral weight
+    (``truncation="corners"``: [O,H,2k_1,..,2k_{R-1},k_R], the ± corners
+    of Li et al.; default "low", TurboFNO's first k bins);
     wb: [O,H] bypass 1×1 conv (y_o += Σ_h x_h·wb[o,h]); bias: [O].
 
     path="pallas" + variant="full" lowers the ENTIRE block to one
@@ -774,23 +841,27 @@ def fno_block_nd(x: jax.Array, wr: jax.Array, wi: jax.Array, wb: jax.Array,
     or ``block_plan`` override component-wise across all five launches.
     policy: see spectral_layer_1d.
 
-    act: "gelu" (the standard block) or "linear" (pre-activation only —
-    the TP-sharded dispatch reduces partial pre-activations with a psum
-    BEFORE the nonlinearity; its backward skips the gz-recompute kernel).
+    act: "gelu" (the standard block), "relu" (Li et al.'s FNO-3D) or
+    "linear" (pre-activation only — a model's unactivated last block, and
+    the TP-sharded dispatch, which reduces partial pre-activations with a
+    psum BEFORE the nonlinearity; its backward skips the gz-recompute
+    kernel).
 
     out_dtype (pallas path only) overrides the ref-write emission dtype —
     the TP dispatch emits partials at the accumulator dtype so the psum
     stays f32 under the bf16 policy (ROADMAP.md §Precision policy).
     """
-    modes = _modes_key(modes)
-    assert act in ("gelu", "linear"), act
+    modes = spectral.kept_modes(modes, truncation)
+    assert act in ACTIVATIONS, act
     if path in ("ref", "xla"):
         return _fno_block_oracle(x, wr, wi, wb, bias, modes, path, policy,
-                                 act)
+                                 act, truncation)
+    _check_partial(variant, truncation)
     pol = policy or _default_policy(x, wr)
     plans = _resolve_plans(x, wr, modes, pol, bb, bo, bh, block_plan)
     return _fno_block_nd_pallas(x, wr, wi, wb, bias, modes, variant, plans,
-                                _interpret(interpret), pol, act, out_dtype)
+                                _interpret(interpret), pol, act, out_dtype,
+                                truncation)
 
 
 # ---------------------------------------------------------------------------
@@ -849,7 +920,12 @@ def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
     x, wr, wi, wb, bias = (a.astype(cp) for a in (x, wr, wi, wb, bias))
     lift = None if lift is None else tuple(a.astype(cp) for a in lift)
     proj = None if proj is None else tuple(a.astype(cp) for a in proj)
-    r = len(modes)
+    spatial = tuple(x.shape[2:])
+    ksp, kmodes = spectral.kernel_layout(spatial, modes)
+    x = _trailing(x, ksp)
+    if wr.ndim == 2 + len(modes):
+        wr, wi = (_trailing(w, kmodes) for w in (wr, wi))
+    r = len(kmodes)
     b = x.shape[0]
     o = wr.shape[0]
     h = lift[2].shape[1] if lift is not None else x.shape[1]
@@ -861,8 +937,7 @@ def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
     else:
         op_ = _rup(o, bo)
     mats = spectral.realified_operand_mats(
-        tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype, False,
-        kp)
+        spatial, _modes_key(modes), pol.spectral_dtype, False, kp)
 
     def wpad(w):
         if wr.ndim == 2 + r and kp:
@@ -898,7 +973,7 @@ def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
                                 bias=biasp, act="gelu", lift=engine_lift,
                                 proj=engine_proj)
     cout = proj[3].shape[0] if proj is not None else o
-    return y[:b, :cout]
+    return _trailing(y[:b, :cout], spatial)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
@@ -1013,7 +1088,8 @@ def fno_block_nd_sharded(x: jax.Array, wr: jax.Array, wi: jax.Array,
                          tp_layout: str = "psum",
                          tp_overlap: bool = False,
                          ends: Optional[Tuple] = None,
-                         block_plan: Optional[Tuple[int, int, int]] = None
+                         block_plan: Optional[Tuple[int, int, int]] = None,
+                         truncation: str = "low"
                          ) -> jax.Array:
     """``fno_block_nd`` under shard_map on a (DP×TP) mesh — the production
     dispatch behind ``core.spectral_conv.apply_fno_block_nd`` whenever a
@@ -1063,7 +1139,8 @@ def fno_block_nd_sharded(x: jax.Array, wr: jax.Array, wi: jax.Array,
     has_ends = ends is not None and any(e is not None for e in ends)
     if has_ends:
         # Ends replicate — pure-DP dispatch only (core.fno guards TP off).
-        assert not tp_on and act == "gelu", (tp_on, act)
+        assert not tp_on and act == "gelu" and truncation == "low", (
+            tp_on, act, truncation)
         ends_specs = jax.tree_util.tree_map(
             lambda a: P(*(None,) * a.ndim), ends)
         fn = compat_shard_map(
@@ -1077,7 +1154,8 @@ def fno_block_nd_sharded(x: jax.Array, wr: jax.Array, wi: jax.Array,
     def local(xl, wrl, wil, wbl, bl):
         if not tp_on:
             return fno_block_nd(xl, wrl, wil, wbl, bl, modes,
-                                path="pallas", act=act, **kw)
+                                path="pallas", act=act,
+                                truncation=truncation, **kw)
         # Partial pre-activations emit at the ACCUMULATOR dtype (f32 under
         # the bf16 policy) so the cross-shard contraction — reduction +
         # bias + activation — stays f32 end-to-end; the single down-cast
@@ -1089,7 +1167,8 @@ def fno_block_nd_sharded(x: jax.Array, wr: jax.Array, wi: jax.Array,
         z = fno_block_nd(xl, wrl, wil, wbl,
                          jnp.zeros((wrl.shape[0],), bl.dtype), modes,
                          path="pallas", act="linear",
-                         out_dtype=pol.accum_dtype, **kw)
+                         out_dtype=pol.accum_dtype, truncation=truncation,
+                         **kw)
         if scatter:
             # bl arrives pre-sliced to this shard's chunk (bspec).
             z = (ring_scatter_sum(z, model_axis, tp) if tp_overlap
@@ -1097,9 +1176,7 @@ def fno_block_nd_sharded(x: jax.Array, wr: jax.Array, wi: jax.Array,
         else:
             z = jax.lax.psum(z, model_axis)
         z = z + bl.astype(z.dtype).reshape((1, -1) + (1,) * r)
-        if act == "gelu":
-            z = jax.nn.gelu(z, approximate=True)
-        return z.astype(jnp.dtype(pol.compute_dtype))
+        return activate(z, act).astype(jnp.dtype(pol.compute_dtype))
 
     fn = compat_shard_map(
         local, mesh,

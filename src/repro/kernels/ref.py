@@ -49,13 +49,44 @@ def ref_cgemm(ar, ai, br, bi) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 # -- fused-layer oracles -----------------------------------------------------
-def ref_fnond(x: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarray,
-              modes: Tuple[int, ...]) -> jnp.ndarray:
-    """Staged rank-R FNO spectral layer, TurboFNO truncation convention.
+def _keep(xf, axis: int, kept: int, truncation: str):
+    """The kept bins of a full complex axis: the first `kept` ("low"), or
+    the kept/2 lowest and kept/2 highest ("corners")."""
+    if truncation == "low":
+        return jax.lax.slice_in_dim(xf, 0, kept, axis=axis)
+    h, n = kept // 2, xf.shape[axis]
+    return jnp.concatenate([jax.lax.slice_in_dim(xf, 0, h, axis=axis),
+                            jax.lax.slice_in_dim(xf, n - h, n, axis=axis)],
+                           axis=axis)
 
-    x: [B, H, s_1..s_R]; keeps the LOW corner ``[:k_1, …, :k_R]`` only
-    (paper Fig. 4 — "first dimX/DimX fraction"), unlike classic FNO's ±
-    corners. W: [O, H] or [O, H, k_1..k_R]. Output [B, O, s_1..s_R].
+
+def _unkeep(yf, axis: int, n: int, truncation: str):
+    """Zero-pad kept bins back to a full axis of length n."""
+    kept = yf.shape[axis]
+    if truncation == "low":
+        pad = [(0, 0)] * yf.ndim
+        pad[axis] = (0, n - kept)
+        return jnp.pad(yf, pad)
+    h = kept // 2
+    zeros = list(yf.shape)
+    zeros[axis] = n - kept
+    return jnp.concatenate([jax.lax.slice_in_dim(yf, 0, h, axis=axis),
+                            jnp.zeros(zeros, yf.dtype),
+                            jax.lax.slice_in_dim(yf, h, kept, axis=axis)],
+                           axis=axis)
+
+
+def ref_fnond(x: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarray,
+              modes: Tuple[int, ...],
+              truncation: str = "low") -> jnp.ndarray:
+    """Staged rank-R FNO spectral layer.
+
+    x: [B, H, s_1..s_R]; ``modes`` are kept counts per axis. truncation
+    "low" keeps the LOW corner ``[:k_1, …, :k_R]`` only (TurboFNO, paper
+    Fig. 4 — "first dimX/DimX fraction"); "corners" keeps classic FNO's ±
+    corners of every complex axis (k/2 lowest and k/2 highest bins) and
+    the first k_R of the rFFT axis. W: [O, H] or [O, H, k_1..k_R]. Output
+    [B, O, s_1..s_R].
 
     rFFT along s_R, FFT along the rest → truncate → CGEMM over hidden →
     zero-pad → inverse transforms. Built on jnp.fft (NOT the matmul
@@ -65,21 +96,18 @@ def ref_fnond(x: jnp.ndarray, wr: jnp.ndarray, wi: jnp.ndarray,
     spatial = x.shape[2:]
     xf = jnp.fft.rfft(x.astype(jnp.float32), axis=-1)[..., :modes[-1]]
     for j in range(r - 1):  # FFT along s_{R-1}, …, s_1 (axes in place)
-        ax = -2 - j
-        xf = jnp.fft.fft(xf, axis=ax)
-        xf = jax.lax.slice_in_dim(xf, 0, modes[r - 2 - j],
-                                  axis=xf.ndim + ax)
+        ax = xf.ndim - 2 - j
+        xf = _keep(jnp.fft.fft(xf, axis=ax), ax, modes[r - 2 - j],
+                   truncation)
     w = (wr + 1j * wi).astype(jnp.complex64)
     ms = "uvw"[:r]
     eq = (f"oh{ms},bh{ms}->bo{ms}" if w.ndim > 2
           else f"oh,bh{ms}->bo{ms}")
     yf = jnp.einsum(eq, w, xf)
-    pad = [(0, 0), (0, 0)]
-    pad += [(0, n - k) for n, k in zip(spatial[:-1], modes[:-1])]
-    pad += [(0, spatial[-1] // 2 + 1 - modes[-1])]
-    yf = jnp.pad(yf, pad)
     for j in range(r - 1):  # inverse FFT along s_1, …, s_{R-1}
-        yf = jnp.fft.ifft(yf, n=spatial[j], axis=2 + j)
+        yf = jnp.fft.ifft(_unkeep(yf, 2 + j, spatial[j], truncation),
+                          axis=2 + j)
+    yf = _unkeep(yf, yf.ndim - 1, spatial[-1] // 2 + 1, "low")
     return jnp.fft.irfft(yf, n=spatial[-1], axis=-1).astype(jnp.float32)
 
 

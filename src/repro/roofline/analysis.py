@@ -187,7 +187,7 @@ def fno_model_flops(cfg, batch: int, *, training: bool = True) -> float:
     sp = math.prod(cfg.spatial)
     lift = cfg.lifting_dim or 2 * h
     r = cfg.ndim
-    spatial, modes = list(cfg.spatial), list(cfg.modes)
+    spatial, modes = list(cfg.spatial), list(cfg.kept_modes)
     cur = list(spatial)
 
     def stage(ch, ax, real):
@@ -257,14 +257,14 @@ def fno_model_bytes(cfg, batch: int, *, variant: str = "full",
     sp = math.prod(cfg.spatial)
     lift = cfg.lifting_dim or 2 * h
     act = batch * h * sp  # one hidden activation tensor (elements)
-    wmul = math.prod(cfg.modes) if cfg.weight_mode == "per_mode" else 1
+    wmul = math.prod(cfg.kept_modes) if cfg.weight_mode == "per_mode" else 1
     wc = 2 * h * o * wmul  # complex spectral weight (re+im)
     byp_w = h * o + o  # bypass 1x1 weight + bias
-    mats = 4 * sum(n * k for n, k in zip(cfg.spatial, cfg.modes))
+    mats = 4 * sum(n * k for n, k in zip(cfg.spatial, cfg.kept_modes))
 
     spectral_fwd = (act + wc + act) * cb + mats * sb
     if variant == "partial" and cfg.ndim >= 2:
-        kout = math.prod(cfg.modes[1:])
+        kout = math.prod(cfg.kept_modes[1:])
         inter = 2 * batch * (h + o) * cfg.spatial[0] * kout  # complex pairs
         spectral_fwd += 2 * inter * cb  # write + re-read between launches
 

@@ -23,6 +23,7 @@ from typing import Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import FNOConfig
 from repro.core import fno as fno_mod
@@ -168,6 +169,11 @@ class FNOServer:
         # Un-jitted, exposed for trace guards: a K-step rollout must trace
         # exactly num_layers pallas_calls regardless of K (lint_rollout).
         self.rollout_step_fn = rollout_step_fn
+        # A host request crosses to the device already in the layout the
+        # model runs in (a free reshape of a numpy array): a rank-3 grid's
+        # short minor axis would otherwise be tiled to 128 lanes on the
+        # way, and relaid on the device.
+        self.host_layout = fno_mod.input_layout(cfg, path)
         self._step = jax.jit(step_fn)
         self._rollout = jax.jit(rollout_step_fn, static_argnames=("steps",))
         self.stats = {"requests": 0, "samples": 0, "padded": 0}
@@ -250,6 +256,8 @@ class FNOServer:
                 chunk = x[s:s + top]
                 b = pick_bucket(chunk.shape[0], self.buckets)
                 xp, m = pad_to_bucket(chunk, b)
+                if rollout_steps == 1 and isinstance(xp, np.ndarray):
+                    xp = xp.reshape(xp.shape[:2] + self.host_layout)
             with jax.profiler.TraceAnnotation("fno_server.step"):
                 outs.append((self._bucketed(xp, rollout_steps), m))
             self.stats["padded"] += b - m

@@ -116,7 +116,7 @@ def tunable_workloads(smoke: bool = False) -> List[Workload]:
             for dtype in ("f32", "bf16"):
                 w = Workload(
                     label=f"{arch}/{tag}", rank=cfg.ndim, hidden=cfg.hidden,
-                    spatial=tuple(cfg.spatial), modes=tuple(cfg.modes),
+                    spatial=tuple(cfg.spatial), modes=cfg.kept_modes,
                     per_mode=cfg.weight_mode == "per_mode", dtype=dtype)
                 cell = (w.rank, w.klass, w.layout, w.dtype)
                 if cell not in seen:
@@ -131,7 +131,8 @@ def _candidates(w: Workload) -> List[Tuple[int, int, int]]:
     the same candidate)."""
     from repro.kernels.ops import _BLOCK_DEFAULTS, legal_blocks
 
-    raw = list(itertools.product(_BB_GRID, _BOH_GRID, _BOH_GRID))
+    boh = _BOH_GRID + (w.hidden,)  # the whole dim is always a legal block
+    raw = list(itertools.product(_BB_GRID, boh, boh))
     raw.append(_BLOCK_DEFAULTS[w.rank])
     seen, out = set(), []
     for t in raw:

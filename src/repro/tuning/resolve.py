@@ -40,7 +40,7 @@ def _norm_workload(cfg_or_shapes, policy):
     if isinstance(cfg_or_shapes, FNOConfig):
         cfg = cfg_or_shapes
         return (cfg.hidden, cfg.hidden, tuple(cfg.spatial),
-                tuple(cfg.modes), cfg.weight_mode == "per_mode",
+                cfg.kept_modes, cfg.weight_mode == "per_mode",
                 policy or cfg.precision, cfg.block_plan)
     h, spatial, modes, per_mode = cfg_or_shapes
     return (int(h), int(h), tuple(spatial), tuple(modes), bool(per_mode),

@@ -71,11 +71,11 @@ def test_prefill_decode_consistency(arch):
 
 
 def test_cell_grid_and_skips():
-    # 10 LM archs + 4 FNO archs, 4 shapes each — EVERY seeded config is
+    # 10 LM archs + 5 FNO archs, 4 shapes each — EVERY seeded config is
     # enumerated (the registry audit contract,
     # analysis.ast_lint.check_config_registry).
     cells = list(runnable_cells())
-    assert len(cells) == 56
+    assert len(cells) == 60
     skips = [(a, s) for a, s, r in cells if r]
     assert ("hubert-xlarge", "decode_32k") in skips
     assert ("hubert-xlarge", "long_500k") in skips
@@ -90,7 +90,7 @@ def test_cell_grid_and_skips():
     # FNO archs (fno2d-large included): train + batched-serve cells run,
     # decode shapes carry a reason
     by = {(a, s): r for a, s, r in cells}
-    for a in ("fno1d", "fno2d", "fno2d-large", "fno3d"):
+    for a in ("fno1d", "fno2d", "fno2d-large", "fno3d", "fno3d-ns"):
         assert by[(a, "train_4k")] is None
         assert by[(a, "prefill_32k")] is None
         assert by[(a, "decode_32k")]

@@ -180,11 +180,15 @@ def test_realified_operands_reproduce_complex_product(spatial, modes, pad,
     [re | im] rows, gives the complex product with the (mr, mi) pair of
     ``fused_operand_mats`` it packs: real in (first forward stage),
     complex, real out (last inverse stage); sublane stages as the
-    transposed (left-hand) operand."""
+    transposed (left-hand) operand. A rank-3 shape packs the pairs of
+    its kernel layout: the trailing axes' pairs merged into one
+    Kronecker product."""
     from repro.core import spectral as sp
     r = len(spatial)
     flat = sp.fused_operand_mats(spatial, modes, "float32", adjoint, pad)
     pairs = [(flat[2 * i], flat[2 * i + 1]) for i in range(2 * r)]
+    if r == 3:
+        pairs, r = sp._merge_trailing(pairs), 2
     packed = sp.realified_operand_mats(spatial, modes, "float32", adjoint,
                                        pad)
     fwd_plan, inv_plan = sp.realified_plan(r)

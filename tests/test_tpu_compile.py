@@ -11,7 +11,10 @@ its launch kind. The whole fno2d serve step is compiled once more, to
 hold the names the device trace reads: the kernels' launch names and the
 model ends' named scopes.
 
-fno3d is not here: Mosaic still refuses it (ROADMAP S2).
+Rank 3 runs in the engine's merged layout (``spectral.kernel_layout``):
+the forward of fno3d and of Li et al.'s fno3d-ns compiles; their weight
+gradient does not yet (the rank-3 wgrad kernel flattens its spectrum in
+a reshape Mosaic refuses, ROADMAP S2), so rank 3 is compiled forward only.
 
 The topology is described inside a module-scoped fixture, never at import
 time: only one process may hold the TPU library, and pytest-xdist workers
@@ -63,7 +66,7 @@ def one_chip():
 def _block_shapes(cfg, pol, sharding, batch=8):
     """Shapes of one full-width block's operands, placed on the chip."""
     h = cfg.hidden
-    w = (h, h) + (tuple(cfg.modes) if cfg.weight_mode == "per_mode" else ())
+    w = (h, h) + (cfg.kept_modes if cfg.weight_mode == "per_mode" else ())
     sd = lambda shape, dt: jax.ShapeDtypeStruct(shape, jnp.dtype(dt),
                                                 sharding=sharding)
     return (sd((batch, h) + tuple(cfg.spatial), pol.compute_dtype),
@@ -134,6 +137,25 @@ def test_fused_block_compiles_for_v5e(one_chip, arch, dtype, grad):
     want = (["block_fwd", "gz_recompute", "dx_adjoint", "wgrad"] if grad
             else ["block_fwd"])
     assert _kernel_launches(text) == collections.Counter(want), arch
+
+
+@pytest.mark.parametrize("arch", ["fno3d", "fno3d-ns"])
+def test_rank3_block_forward_compiles_for_v5e(one_chip, arch):
+    """A rank-3 block forward at full width, f32: one block_fwd kernel
+    over the merged [s_1, s_2·s_3] layout; fno3d-ns with its own
+    truncation (± corners), activation (ReLU) and per-mode weight."""
+    cfg = get_config(arch)
+    pol = PrecisionPolicy.from_name("f32")
+
+    def block(x, wr, wi, wb, bias):
+        return ops.fno_block_nd(x, wr, wi, wb, bias, cfg.modes,
+                                path="pallas", variant="full", policy=pol,
+                                act=cfg.activation,
+                                truncation=cfg.truncation, interpret=False)
+
+    text = _compiled_text(block, _block_shapes(cfg, pol, one_chip, batch=2))
+    assert text.count("tpu_custom_call") == 1, arch
+    assert _kernel_launches(text) == collections.Counter(["block_fwd"])
 
 
 @pytest.mark.parametrize("dtype", ["f32", "bf16"])
