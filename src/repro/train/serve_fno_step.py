@@ -30,6 +30,12 @@ from repro.core import fno as fno_mod
 from repro.distributed import sharding as shd
 from repro.tuning import resolve_block_plan, serve_quantum
 
+# Bytes of host input per jitted step once a host request is large enough
+# to pipeline: chunk k+1 crosses the host link while chunk k computes.
+# About 3 ms on a TPU v5e's ~10 GB/s host link; a request at or under it
+# goes over in one step.
+_PIPELINE_CHUNK_BYTES = 32 << 20
+
 
 def make_fno_serve_step(cfg: FNOConfig, *, path: Optional[str] = None,
                         variant: str = "full"):
@@ -176,7 +182,8 @@ class FNOServer:
         self.host_layout = fno_mod.input_layout(cfg, path)
         self._step = jax.jit(step_fn)
         self._rollout = jax.jit(rollout_step_fn, static_argnames=("steps",))
-        self.stats = {"requests": 0, "samples": 0, "padded": 0}
+        self.stats = {"requests": 0, "samples": 0, "padded": 0,
+                      "chunks": 0}
 
     def collective_plan(self) -> Dict[str, object]:
         """The serving step's TP collective plan as metadata (ISSUE 8) —
@@ -226,21 +233,45 @@ class FNOServer:
             return self._step(self.params, {"x": xp})
         return self._rollout(self.params, {"x": xp}, steps=rollout_steps)
 
+    def _pipeline_chunk(self, x, rollout_steps: int) -> Optional[int]:
+        """Samples per chunk of a host request pipelined through the host
+        link: the smallest bucket that carries ``_PIPELINE_CHUNK_BYTES``
+        (the top bucket if none does). None for a device array, a rollout
+        or a request at or under that size: nothing to pipeline."""
+        if (rollout_steps != 1 or not isinstance(x, np.ndarray)
+                or x.nbytes <= _PIPELINE_CHUNK_BYTES):
+            return None
+        per_sample = x[0].nbytes
+        return next((b for b in self.buckets
+                     if b * per_sample >= _PIPELINE_CHUNK_BYTES),
+                    self.buckets[-1])
+
     def __call__(self, x: jax.Array, rollout_steps: int = 1) -> jax.Array:
         """Serve one request batch x [n, C_in, *spatial] -> [n, C_out, …].
 
         ``rollout_steps > 1`` runs the device-resident autoregressive
         rollout (one lax.scan — the carry never leaves HBM) and returns
         the FINAL step's prediction; the jit cache keys on (bucket,
-        steps). Oversize batches are chunked at the largest bucket; the
-        tail chunk pads up to its own bucket; an empty batch returns an
-        empty output without touching the step.
+        steps). An empty batch returns an empty output without touching
+        the step.
+
+        The request runs as chunks, each padded to its own bucket and
+        dispatched without waiting on any answer; the answers are joined
+        on the device. Oversize batches are chunked at the largest
+        bucket. A host (numpy) request of more than
+        ``_PIPELINE_CHUNK_BYTES`` served in one step is instead chunked at
+        the smallest bucket that carries that many bytes, and each chunk
+        is sent with ``jax.device_put`` after the chunk before last has
+        landed: the device computes chunk k while chunk k+1 crosses the
+        host link, and the link is never shared by more than two chunks.
+        ``stats["chunks"]`` counts the jitted steps dispatched.
 
         Each phase is a host span on the profiler's clock (a no-op unless
         a profiler session is open): ``fno_server.pad`` (bucket choice and
         padding), ``fno_server.step`` (the jitted call: with a host input,
-        its transfer to the device and the dispatch) and
-        ``fno_server.slice`` (dropping the padding, joining the chunks)."""
+        its transfer to the device and the dispatch), one pair per chunk,
+        and ``fno_server.slice`` (dropping the padding, joining the
+        chunks)."""
         if rollout_steps < 1:
             raise ValueError(f"rollout_steps must be >= 1, "
                              f"got {rollout_steps}")
@@ -249,18 +280,27 @@ class FNOServer:
             return jnp.zeros(
                 (0, self.cfg.out_channels) + tuple(x.shape[2:]),
                 jnp.dtype(self.cfg.precision.compute_dtype))
-        top = self.buckets[-1]
-        outs = []
-        for s in range(0, n, top):
+        piped = self._pipeline_chunk(x, rollout_steps)
+        size = piped or self.buckets[-1]
+        outs, sent = [], []
+        for s in range(0, n, size):
             with jax.profiler.TraceAnnotation("fno_server.pad"):
-                chunk = x[s:s + top]
+                chunk = x[s:s + size]
                 b = pick_bucket(chunk.shape[0], self.buckets)
                 xp, m = pad_to_bucket(chunk, b)
                 if rollout_steps == 1 and isinstance(xp, np.ndarray):
                     xp = xp.reshape(xp.shape[:2] + self.host_layout)
             with jax.profiler.TraceAnnotation("fno_server.step"):
+                if piped:
+                    # Every chunk sent at once would share the link, and
+                    # the first would land late.
+                    if len(sent) == 2:
+                        sent.pop(0).block_until_ready()
+                    xp = jax.device_put(xp)
+                    sent.append(xp)
                 outs.append((self._bucketed(xp, rollout_steps), m))
             self.stats["padded"] += b - m
+            self.stats["chunks"] += 1
         self.stats["requests"] += 1
         self.stats["samples"] += n
         with jax.profiler.TraceAnnotation("fno_server.slice"):

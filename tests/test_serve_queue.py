@@ -318,7 +318,8 @@ def test_tier_over_real_server_matches_direct_calls():
         assert np.isfinite(got).all()
 
 
-def test_server_phases_are_host_spans_in_a_profiler_trace(tmp_path):
+def test_server_phases_are_host_spans_in_a_profiler_trace(tmp_path,
+                                                          monkeypatch):
     """FNOServer records its phases as host spans on the profiler's clock:
     per chunk a pad and a step, then one slice that joins the chunks."""
     jax = pytest.importorskip("jax")
@@ -328,7 +329,7 @@ def test_server_phases_are_host_spans_in_a_profiler_trace(tmp_path):
 
     cfg = dataclasses.replace(get_config("fno2d", reduced=True), path="xla")
     params = fno_mod.init_fno(jax.random.PRNGKey(0), cfg)
-    server = sfs.FNOServer(cfg, params, max_batch=4)
+    server = sfs.FNOServer(cfg, params, max_batch=16)
     top = server.buckets[-1]
     x = np.zeros((top + 1, cfg.in_channels) + tuple(cfg.spatial),
                  np.float32)
@@ -349,3 +350,103 @@ def test_server_phases_are_host_spans_in_a_profiler_trace(tmp_path):
     assert [name for _, name in spans] == [
         "fno_server.pad", "fno_server.step", "fno_server.pad",
         "fno_server.step", "fno_server.slice"]
+
+    # A host request of one top bucket, above the pipeline's chunk bytes:
+    # one pad/step pair per transfer-sized chunk (the smallest bucket
+    # here, two chunks), then one slice.
+    x = x[:top]
+    monkeypatch.setattr(sfs, "_PIPELINE_CHUNK_BYTES", x[0].nbytes)
+    chunks = top // server.buckets[0]
+    want = np.asarray(server(x))
+    trace_dir = tmp_path / "pipelined"
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        got = np.asarray(server(x))
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(got, want)
+    path = next(trace_dir.rglob("*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(str(path))
+    spans = sorted((e.start_ns, e.name) for plane in data.planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith("fno_server."))
+    assert [name for _, name in spans] == (
+        ["fno_server.pad", "fno_server.step"] * chunks
+        + ["fno_server.slice"])
+
+
+# ---------------------------------------------------------------------------
+# a large host request pipelined in transfer-sized chunks
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def xla_server():
+    """The reduced fno2d on the XLA path behind a (8, 16, 32) bucket ladder
+    (its tuned batch block is 8), and a full-bucket host request of
+    distinct samples."""
+    jax = pytest.importorskip("jax")
+    from repro.configs import get_config
+    from repro.core import fno as fno_mod
+    from repro.train import serve_fno_step as sfs
+
+    cfg = dataclasses.replace(get_config("fno2d", reduced=True), path="xla")
+    params = fno_mod.init_fno(jax.random.PRNGKey(0), cfg)
+    server = sfs.FNOServer(cfg, params, max_batch=32)
+    assert server.buckets == (8, 16, 32)
+    x = np.random.default_rng(0).normal(
+        size=(32, cfg.in_channels) + tuple(cfg.spatial)).astype(np.float32)
+    return server, x
+
+
+def _pipelined(monkeypatch, server, x, samples):
+    """Set the chunk bytes to ``samples`` samples of ``x``; returns the
+    server's chunk counter before the call that follows."""
+    from repro.train import serve_fno_step as sfs
+    monkeypatch.setattr(sfs, "_PIPELINE_CHUNK_BYTES", samples * x[0].nbytes)
+    return server.stats["chunks"]
+
+
+@pytest.mark.parametrize("samples, chunks", [(1, 4), (8, 4), (9, 2),
+                                             (32, 1), (33, 1)])
+def test_large_host_request_splits_into_transfer_sized_chunks(
+        xla_server, monkeypatch, samples, chunks):
+    # The chunk is the smallest bucket carrying the chunk bytes (1 or 8
+    # samples → bucket 8, 9 → bucket 16); a request at or under them
+    # stays one chunk.
+    server, x = xla_server
+    before = _pipelined(monkeypatch, server, x, samples)
+    server(x)
+    assert server.stats["chunks"] - before == chunks
+
+
+@pytest.mark.parametrize("n, chunks", [(32, 4), (20, 3)])
+def test_pipelined_request_matches_the_unsplit_step(xla_server, monkeypatch,
+                                                    n, chunks):
+    # 20 samples: two chunks of 8 and a tail of 4 padded to bucket 8.
+    server, x = xla_server
+    x = x[:n]
+    whole = np.asarray(server(x))  # one chunk at the default chunk bytes
+    before = _pipelined(monkeypatch, server, x, 8)
+    got = np.asarray(server(x))
+    assert server.stats["chunks"] - before == chunks
+    # Each sample's forward pass is independent of its batch.
+    np.testing.assert_allclose(got, whole, rtol=0, atol=1e-6)
+
+
+def test_device_request_is_not_split(xla_server, monkeypatch):
+    jax = pytest.importorskip("jax")
+    server, x = xla_server
+    before = _pipelined(monkeypatch, server, x, 8)
+    y = server(jax.device_put(x))
+    assert server.stats["chunks"] - before == 1
+    assert y.shape[0] == len(x)
+
+
+def test_repeated_pipelined_request_compiles_nothing(xla_server,
+                                                     monkeypatch):
+    server, x = xla_server
+    _pipelined(monkeypatch, server, x, 8)
+    np.asarray(server(x))
+    entries = server._step._cache_size()
+    np.asarray(server(x))
+    assert server._step._cache_size() == entries
