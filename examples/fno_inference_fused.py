@@ -1,20 +1,17 @@
 """Serve an FNO with the fused TurboFNO kernel and compare the three
 execution paths on identical inputs — parity + per-path wall time + the
-derived HBM-traffic model that explains the TPU speedup.
+modeled HBM traffic of a staged and a whole-block-fused forward.
 
     PYTHONPATH=src python examples/fno_inference_fused.py
 """
-import os
-import sys
 import time
 
 import jax
 import jax.numpy as jnp
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from benchmarks.pipelines import traffic_bytes  # noqa: E402
 from repro.configs import get_config
 from repro.core import fno
+from repro.roofline.analysis import fno_model_bytes
 
 cfg = get_config("fno2d", reduced=True)
 key = jax.random.PRNGKey(1)
@@ -38,12 +35,8 @@ for name, fn in apply.items():
         if name == "pallas" else ""
     print(f"path={name:7s}  {dt*1e3:8.1f} ms/call  max|Δ|={err:.2e} {note}")
 
-h = cfg.hidden
-n = cfg.spatial[0]
-k = cfg.modes[0]
-base = traffic_bytes(4, h, h, n, k, "baseline")
-fused = traffic_bytes(4, h, h, n, k, "fused_full")
-print(f"\nderived HBM traffic per layer (TPU model): staged {base/2**20:.1f}"
-      f" MiB vs fused {fused/2**20:.1f} MiB — {base/fused:.1f}x reduction;"
-      f"\nthe layer is memory-bound on v5e, so this ratio bounds the fused"
-      f" kernel's speedup (EXPERIMENTS.md §Paper-claims).")
+staged = fno_model_bytes(cfg, 4, training=False, fuse_block=False)
+fused = fno_model_bytes(cfg, 4, training=False, fuse_block=True)
+print(f"\nmodeled HBM traffic per forward: staged blocks {staged/2**20:.1f}"
+      f" MiB vs fused blocks {fused/2**20:.1f} MiB — {staged/fused:.1f}x"
+      f" less (a model, not a measurement).")

@@ -17,7 +17,7 @@ that contract on a forced dp2×tp4 CPU mesh:
 Pure CPU, seconds — the interpret-mode kernels execute on tiny reduced
 shapes. The modeled wire-byte claim (0.5x per interior layer vs the psum
 layout, unchanged by the ring) lives in roofline.analysis
-.fno_collective_bytes and benchmarks/bench_e2e.run_serve.
+.fno_collective_bytes.
 """
 import os
 import sys

@@ -46,12 +46,11 @@ DTYPE_SCOPE: Dict[str, Tuple[str, ...]] = {
     "kernels/engine.py": (),                   # policy dtypes only
     "kernels/cgemm.py": ("<module>",),         # _F32 accumulator default
     "kernels/dft.py": ("<module>",),           # _F32 accumulator default
-    "kernels/ops.py": ("_spectral_layer_nd",   # f32 oracle boundary
+    "kernels/ops.py": ("_spectral_oracle",     # f32 oracle boundary
                        "_block_tail"),         # f32 epilogue accumulation
     "core/fno.py": ("_dense_init",             # f32 master-param init
                     "relative_l2"),            # f32 metric reduction
-    "core/spectral_conv.py": ("init_spectral_nd", "init_spectral_1d",
-                              "init_spectral_2d", "init_spectral_3d"),
+    "core/spectral_conv.py": ("init_spectral_nd",),
     "train/train_step.py": ("make_train_step",  # f32 grad-acc fallback
                             "train_step"),      # f32 loss accumulator
 }

@@ -207,41 +207,50 @@ def block_args(rank: int, layout: str, dtype: str):
     return x, wr, wi, wb, bias
 
 
-def expected_block_calls(rank: int, variant: str) -> Tuple[int, int]:
-    """(fwd, grad) pallas_call counts for one block. Full fusion is one
-    kernel; the paper-faithful partial variant runs outer-fwd + core +
-    outer-inv for rank ≥ 2 (rank 1 has no outer stages). The backward is
-    always the fused adjoint: gz recompute + dx + extended wgrad = +3."""
+def expected_block_calls(rank: int, variant: str,
+                         layer: str = "block") -> Tuple[int, int]:
+    """(fwd, grad) pallas_call counts for one block, or for the spectral
+    layer alone (``layer="spectral"``). Full fusion is one kernel; the
+    paper-faithful partial variant runs outer-fwd + core + outer-inv for
+    rank ≥ 2 (rank 1 has no outer stages). The backward is always the
+    fused adjoint: gz recompute + dx + extended wgrad = +3 for the block;
+    the linear spectral layer needs no recompute, dx + wgrad = +2."""
     fwd = 1 if (variant == "full" or rank == 1) else 3
-    return fwd, fwd + 3
+    return fwd, fwd + (3 if layer == "block" else 2)
 
 
 def lint_block_matrix(ranks: Sequence[int] = (1, 2, 3),
                       layouts: Sequence[str] = LAYOUTS,
                       variants: Sequence[str] = VARIANTS,
-                      dtypes: Sequence[str] = DTYPES) -> List[Finding]:
-    """fwd + grad of ``ops.fno_block_nd`` across the whole single-device
+                      dtypes: Sequence[str] = DTYPES,
+                      layers: Sequence[str] = ("block", "spectral")
+                      ) -> List[Finding]:
+    """fwd + grad of ``ops.fno_block_nd`` and of ``ops.spectral_layer_nd``
+    (the same custom_vjp with no bypass) across the whole single-device
     matrix: pallas counts and cast ownership."""
     from repro.kernels import ops
 
     findings: List[Finding] = []
-    for rank, layout, variant, dtype in itertools.product(
-            ranks, layouts, variants, dtypes):
-        target = f"fno_block_nd r{rank}/{layout}/{variant}/{dtype}"
+    for layer, rank, layout, variant, dtype in itertools.product(
+            layers, ranks, layouts, variants, dtypes):
+        entry = "fno_block_nd" if layer == "block" else "spectral_layer_nd"
+        target = f"{entry} r{rank}/{layout}/{variant}/{dtype}"
         pol = _policy(dtype)
         modes = _MODES[rank]
         args = block_args(rank, layout, dtype)
-        blk = lambda *a: ops.fno_block_nd(  # noqa: E731
+        if layer == "spectral":
+            args = args[:3]
+        fn = lambda *a: getattr(ops, entry)(  # noqa: E731
             *a, modes, path="pallas", variant=variant, policy=pol)
-        loss = lambda *a: jnp.sum(blk(*a) ** 2)  # noqa: E731
+        loss = lambda *a: jnp.sum(fn(*a) ** 2)  # noqa: E731
         grad = lambda *a: jax.grad(  # noqa: E731
-            loss, argnums=(0, 1, 2, 3, 4))(*a)
-        want_fwd, want_grad = expected_block_calls(rank, variant)
-        findings += check_pallas_count(blk, args, want_fwd,
+            loss, argnums=tuple(range(len(a))))(*a)
+        want_fwd, want_grad = expected_block_calls(rank, variant, layer)
+        findings += check_pallas_count(fn, args, want_fwd,
                                        target=f"{target} fwd")
         findings += check_pallas_count(grad, args, want_grad,
                                        target=f"{target} grad")
-        findings += check_cast_ownership(blk, args, pol,
+        findings += check_cast_ownership(fn, args, pol,
                                          target=f"{target} fwd")
         findings += check_cast_ownership(grad, args, pol,
                                          target=f"{target} grad")

@@ -2,7 +2,7 @@
 
 Computes each engine launch's operand + scratch bytes from the resolved
 block plans (``repro.tuning.resolve_launch_plans`` — tuned cache with the
-static ``kernels.ops._BLOCK_DEFAULTS`` as fallback), the config's shapes,
+static ``tuning.plans._BLOCK_DEFAULTS`` as fallback), the config's shapes,
 and the ``PrecisionPolicy`` dtypes — BEFORE lowering, so an over-budget
 config is a lint finding instead of a Mosaic allocation failure mid-run.
 The estimator is also the autotuner's pruning oracle
@@ -95,16 +95,16 @@ def resolve_blocks(rank: int, b: int, h: int, o: int,
                    ) -> Tuple[int, int, int]:
     """The (bb, bo, bh) the ops layer would pick: per-rank defaults from
     the block-size table, shrunk to the (8-aligned) actual dims."""
-    from repro.kernels.ops import _resolve_blocks, legal_blocks
+    from repro.tuning.plans import _resolve_blocks, legal_blocks
     return legal_blocks(rank, b, o, h,
                         *_resolve_blocks(rank, bb, bo, bh))
 
 
 def _rev_modes(modes: Sequence[int]) -> Tuple[int, ...]:
     """Accumulator-order spectral extents, with the rank-1 lane-alignment
-    pad (``ops._mode_pad``) applied."""
-    from repro.kernels.ops import _mode_pad
-    kp = _mode_pad(modes)
+    pad (``spectral._mode_pad``) applied."""
+    from repro.core import spectral
+    kp = spectral._mode_pad(modes)
     return (kp,) if len(modes) == 1 else tuple(reversed(modes))
 
 
@@ -151,10 +151,9 @@ def _fused_call_estimate(launch: str, spatial, modes, bb, bo, bh, per_mode,
                          adjoint: bool = False) -> LaunchEstimate:
     """One ``engine.fused_fnond_call`` program, block epilogue included."""
     from repro.core import spectral
-    from repro.kernels.ops import _mode_pad
 
     cd, ad = pol.compute_dtype, pol.accum_dtype
-    kp = _mode_pad(modes)
+    kp = spectral._mode_pad(modes)
     sp, rev = _kernel_shapes(spatial, modes)
     mats = spectral.realified_operand_mats(tuple(spatial), tuple(modes),
                                            pol.spectral_dtype, adjoint, kp)
@@ -207,11 +206,10 @@ def _wgrad_estimate(spatial, modes, bb, bo, bh, per_mode,
                     with_bypass: bool) -> LaunchEstimate:
     """One fused weight-gradient program (``fused_fnond_wgrad_call``)."""
     from repro.core import spectral
-    from repro.kernels.ops import _mode_pad
 
     cd, ad, pd = pol.compute_dtype, pol.accum_dtype, pol.param_dtype
     sp = tuple(spatial)
-    kp = _mode_pad(modes)
+    kp = spectral._mode_pad(modes)
     rev = _rev_modes(modes)
     mats = spectral.wgrad_operand_mats(sp, tuple(modes),
                                        pol.spectral_dtype, kp)
@@ -264,7 +262,7 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
     when the config opts into fuse_ends."""
     from repro import tuning
     from repro.core import spectral
-    from repro.kernels.ops import _mode_pad, legal_blocks
+    from repro.tuning.plans import legal_blocks
 
     h, spatial, modes, per_mode, pol = _norm_shapes(cfg, policy)
     r = len(modes)
@@ -280,7 +278,7 @@ def ends_launch_estimate(cfg: FNOConfig, *, batch: int = 8,
     coutp = _rup8(cfg.out_channels)
 
     cd, ad = pol.compute_dtype, pol.accum_dtype
-    kp = _mode_pad(modes)
+    kp = spectral._mode_pad(modes)
     sp, rev = _kernel_shapes(spatial, modes)
     mats = spectral.realified_operand_mats(tuple(spatial), tuple(modes),
                                            pol.spectral_dtype, False, kp)
@@ -322,10 +320,10 @@ def launch_estimate(cfg_or_shapes, launch: str,
     """Estimate ONE launch kind under an explicit (bb, bo, bh) block
     preference — the autotuner's pruning oracle and the cache staleness
     re-check. The preference is clamped to the actual dims exactly like
-    the ops layer does at call time (``ops.legal_blocks``). dx_adjoint
+    the ops layer does at call time (``plans.legal_blocks``). dx_adjoint
     runs with hidden/out swapped in the real kernel; o == h throughout
     this repo's FNO stacks, so the unswapped estimate is exact."""
-    from repro.kernels.ops import legal_blocks
+    from repro.tuning.plans import legal_blocks
 
     h, spatial, modes, per_mode, pol = _norm_shapes(cfg_or_shapes, policy)
     bb, bo, bh = legal_blocks(len(modes), batch, h, h, *triple)

@@ -261,7 +261,7 @@ class FNOConfig:
     fuse_block: bool = False
     # Explicit (bb, bo, bh) launch-plan override for the pallas kernels.
     # None (the default) lets ``repro.tuning.resolve_block_plan`` pick the
-    # tuned-cache winner (fallback: ops._BLOCK_DEFAULTS). A component of 0
+    # tuned-cache winner (fallback: plans._BLOCK_DEFAULTS). A component of 0
     # keeps the resolved value for that axis. See configs.fno.with_block_plan.
     block_plan: Optional[Tuple[int, int, int]] = None
     # TP inter-layer collective layout (docs/DESIGN.md §6). "scatter" (the

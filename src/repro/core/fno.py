@@ -190,15 +190,8 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
             h = shard_activation(h, "fno" if (ends_on and i == last)
                                  else "fno_hidden")
             continue
-        if cfg.ndim == 1:
-            s = sc.apply_spectral_1d(blk["spectral"], h, modes[0],
-                                     path=path, policy=pol, **bkw)
-        elif cfg.ndim == 2:
-            s = sc.apply_spectral_2d(blk["spectral"], h, modes, path=path,
-                                     variant=variant, **spec_kw)
-        else:
-            s = sc.apply_spectral_3d(blk["spectral"], h, modes, path=path,
-                                     variant=variant, **spec_kw)
+        s = sc.apply_spectral_nd(blk["spectral"], h, modes, path=path,
+                                 variant=variant, **spec_kw)
         h = ops.activate(s.astype(h.dtype) + _dense(blk["bypass"], h),
                          block_act)
         h = shard_activation(h, "fno_hidden")

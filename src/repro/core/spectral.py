@@ -215,6 +215,12 @@ def kernel_layout(spatial, modes) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
             (modes[0], modes[1] * modes[2]))
 
 
+def _mode_pad(modes) -> int:
+    """Rank-1 keeps its modes axis lane-aligned (it is the minor dim of the
+    accumulator); higher ranks use whole-extent mode blocks unpadded."""
+    return -(-modes[0] // 128) * 128 if len(modes) == 1 else 0
+
+
 def _merge_trailing(pairs):
     """Rank-3 (mr, mi) pairs in ``_fused_mat_pairs`` order -> the rank-2
     pairs of ``kernel_layout``: the s_2 and s_3 operands combined into one
@@ -486,7 +492,7 @@ def padded_icdft(xr: jax.Array, xi: jax.Array, n: int,
 
 
 # ---------------------------------------------------------------------------
-# FLOP accounting (paper Fig. 5 analogue — see benchmarks/bench_prune.py)
+# FLOP accounting (paper Fig. 5 analogue)
 # ---------------------------------------------------------------------------
 def fft_flops(n: int) -> float:
     """Real-op count of a full radix-2 complex FFT (5 N log2 N convention)."""

@@ -1,5 +1,5 @@
 """SpectralConv modules — the FNO Fourier layer with selectable execution
-path (ref | xla | pallas) and weight mode (shared | per_mode), rank 1/2/3.
+path (ref | xla | pallas) and weight mode (shared | per_mode), any rank.
 
 Functional style: ``init(key) -> params``, ``apply(params, x) -> y``.
 Channel-first layout [B, C, *spatial], matching the paper. ``apply_*``
@@ -31,51 +31,14 @@ def init_spectral_nd(key: jax.Array, in_ch: int, out_ch: int,
             "wi": scale * jax.random.normal(ki, shape, dtype)}
 
 
-def init_spectral_1d(key: jax.Array, in_ch: int, out_ch: int, modes: int,
-                     weight_mode: str = "shared",
-                     dtype=jnp.float32) -> Dict[str, jax.Array]:
-    return init_spectral_nd(key, in_ch, out_ch, (modes,), weight_mode, dtype)
-
-
-def apply_spectral_1d(params: Dict[str, jax.Array], x: jax.Array, modes: int,
-                      *, path: str = "xla",
-                      policy: Optional[PrecisionPolicy] = None,
-                      **kw) -> jax.Array:
-    """x: [B, C_in, N] -> [B, C_out, N]."""
-    return ops.spectral_layer_1d(x, params["wr"], params["wi"], modes,
-                                 path=path, policy=policy, **kw)
-
-
-def init_spectral_2d(key: jax.Array, in_ch: int, out_ch: int,
-                     modes: Tuple[int, int], weight_mode: str = "shared",
-                     dtype=jnp.float32) -> Dict[str, jax.Array]:
-    return init_spectral_nd(key, in_ch, out_ch, modes, weight_mode, dtype)
-
-
-def apply_spectral_2d(params: Dict[str, jax.Array], x: jax.Array,
-                      modes: Tuple[int, int], *, path: str = "xla",
+def apply_spectral_nd(params: Dict[str, jax.Array], x: jax.Array,
+                      modes: Sequence[int], *, path: str = "xla",
                       variant: str = "full",
                       policy: Optional[PrecisionPolicy] = None,
                       **kw) -> jax.Array:
-    """x: [B, C_in, X, Y] -> [B, C_out, X, Y]."""
-    return ops.spectral_layer_2d(x, params["wr"], params["wi"], modes,
-                                 path=path, variant=variant, policy=policy,
-                                 **kw)
-
-
-def init_spectral_3d(key: jax.Array, in_ch: int, out_ch: int,
-                     modes: Tuple[int, int, int], weight_mode: str = "shared",
-                     dtype=jnp.float32) -> Dict[str, jax.Array]:
-    return init_spectral_nd(key, in_ch, out_ch, modes, weight_mode, dtype)
-
-
-def apply_spectral_3d(params: Dict[str, jax.Array], x: jax.Array,
-                      modes: Tuple[int, int, int], *, path: str = "xla",
-                      variant: str = "full",
-                      policy: Optional[PrecisionPolicy] = None,
-                      **kw) -> jax.Array:
-    """x: [B, C_in, X, Y, Z] -> [B, C_out, X, Y, Z]."""
-    return ops.spectral_layer_3d(x, params["wr"], params["wi"], modes,
+    """x: [B, C_in, *spatial] -> [B, C_out, *spatial], any rank
+    (ops.spectral_layer_nd; ``truncation`` and block sizes in kw)."""
+    return ops.spectral_layer_nd(x, params["wr"], params["wi"], modes,
                                  path=path, variant=variant, policy=policy,
                                  **kw)
 

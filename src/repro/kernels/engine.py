@@ -2,9 +2,8 @@
 
 This is the single home of the paper's core contribution (§4.3) mapped to
 TPU, generalized over spatial rank R (1/2/3, and any R the block shapes
-fit): the per-rank kernels that used to live in ``fused_fno1d.py`` and
-``fused_fno2d.py`` are emitted by the factories below, so every future
-optimization (bf16 accumulators, new fusion variants) lands exactly once.
+fit): every fused launch of every rank is emitted by the factories below,
+so every optimization lands exactly once.
 
 Grid and accumulator layout (identical for every rank):
 
@@ -561,7 +560,7 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
     the f32 accumulator); acc_dtype is the VMEM accumulator dtype
     (PrecisionPolicy.accum_dtype).
 
-    Block epilogue (all optional, see ``fused_fno_block_call``):
+    Block epilogue (all optional):
     wb [O,H] accumulates the 1×1 bypass GEMM alongside the CGEMM k-loop;
     bias [O,1] adds per-out-channel; act picks the epilogue nonlinearity —
     "linear" (default), "gelu" or "relu" (forward block), or "gelu_vjp" /
@@ -680,21 +679,6 @@ def fused_fnond_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
         compiler_params=_compiler_params(dimension_semantics=_SEMANTICS),
         interpret=interpret,
     )(*operands)
-
-
-def fused_fno_block_call(x: jax.Array, wr: jax.Array, wi: jax.Array,
-                         wb: jax.Array, bias: jax.Array, *mats: jax.Array,
-                         bb: int, bo: int, bh: int, interpret: bool = False,
-                         out_dtype: str = None,
-                         acc_dtype: str = "float32") -> jax.Array:
-    """One whole FNO block — gelu(spectral(x) + x·W_bᵀ + bias) — in a
-    single pallas_call (the paper's fusion thesis extended to the full
-    block). wb: [O,H] bypass 1×1 weight; bias: [O,1]; everything else as
-    ``fused_fnond_call``."""
-    return fused_fnond_call(x, wr, wi, *mats, bb=bb, bo=bo, bh=bh,
-                            interpret=interpret, out_dtype=out_dtype,
-                            acc_dtype=acc_dtype, wb=wb, bias=bias,
-                            act="gelu", name="block_fwd")
 
 
 # ---------------------------------------------------------------------------

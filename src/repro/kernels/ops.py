@@ -32,7 +32,7 @@ from repro.kernels import dft as dft_k
 from repro.kernels import engine
 from repro.kernels import ref as ref_k
 from repro.tuning import resolve_launch_plans
-from repro.tuning.plans import LaunchPlans
+from repro.tuning.plans import LaunchPlans, _pick_block, _rup, legal_blocks
 
 
 def on_tpu() -> bool:
@@ -43,10 +43,6 @@ def _interpret(flag: Optional[bool]) -> bool:
     return (not on_tpu()) if flag is None else flag
 
 
-def _rup(v: int, m: int) -> int:
-    return ((v + m - 1) // m) * m
-
-
 def _pad_axis(x: jax.Array, axis: int, to: int) -> jax.Array:
     pad = to - x.shape[axis]
     if pad == 0:
@@ -54,48 +50,6 @@ def _pad_axis(x: jax.Array, axis: int, to: int) -> jax.Array:
     cfg = [(0, 0)] * x.ndim
     cfg[axis] = (0, pad)
     return jnp.pad(x, cfg)
-
-
-@functools.lru_cache(maxsize=None)
-def _pick_block(dim: int, pref: int) -> int:
-    """Clamp a preferred block size to the actual dim with minimal pad
-    waste: among the feasible candidates (8-aligned sizes up to pref, or
-    every size up to pref when pref < 8 — batch blocks), pick the one
-    whose padded total ``_rup(dim, b)`` is smallest, breaking ties toward
-    the larger block (fewer grid steps). This keeps prime/odd extents
-    from forcing near-2× padding — e.g. dim=129 under pref=128 pads to
-    136 via b=8, not to 256 via b=128 — while exact-fit dims still get
-    the largest divisor ≤ pref. A preference equal to the dim is taken as
-    it is: a block that spans the whole dim is always legal (Li et al.'s
-    width 20 runs as one 20-wide block, not padded to 24)."""
-    if pref == dim:
-        return dim
-    if pref < 8:
-        cands = range(1, pref + 1)
-    else:
-        cands = range(8, max(8, min(pref, _rup(dim, 8))) + 1, 8)
-    return min(cands, key=lambda b: (_rup(dim, b), -b))
-
-
-def legal_blocks(rank: int, b: int, o: int, h: int, bb: int, bo: int,
-                 bh: int) -> Tuple[int, int, int]:
-    """The (bb, bo, bh) every engine launch of a rank-R layer runs for
-    dims (B, O, H) under a block preference — the one clamp the ops
-    layer, the tuner and the VMEM estimator share. Mosaic tiles the last
-    two dims of every block by (8, 128) unless the block spans the whole
-    array dim. bo is the second-minor dim of the weight and bias windows,
-    so it is a multiple of 8 (O is padded to it). bh is a free k-loop tile
-    (the engine lays weights out hidden-blocked), except at rank 1, where
-    it is the second-minor dim of the [bb, bh, N] activation window."""
-    bh = _pick_block(h, max(bh, 8) if rank == 1 else bh)
-    return _pick_block(b, bb), _pick_block(o, max(bo, 8)), bh
-
-
-def tiling_legal(rank: int, o: int, h: int, bo: int, bh: int) -> bool:
-    """Whether (bo, bh) satisfies the tiling rule for a rank-R layer with
-    dims (O, H) — see :func:`legal_blocks`."""
-    return ((bo % 8 == 0 or bo == o)
-            and (rank > 1 or bh % 8 == 0 or bh == h))
 
 
 def _blocks(x, o, bb, bo, bh):
@@ -240,17 +194,20 @@ def cgemm(ar: jax.Array, ai: jax.Array, br: jax.Array, bi: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# Fused FNO spectral layers (the paper's contribution), rank-generic.
+# Fused FNO spectral layers and blocks (the paper's contribution),
+# rank-generic.
 #
 # One implementation serves every spatial rank: the engine
 # (kernels/engine.py) emits the fused forward, adjoint, and weight-gradient
 # pallas_calls for any R, and the helpers below only handle padding, block
-# selection, and operand caching. spectral_layer_1d/2d/3d are thin
-# rank-pinning wrappers.
+# selection, and operand caching. ``_fnond_fused`` is the one place a
+# forward-family launch is laid out (kernel layout, plan clamp, padding).
 #
-# The pallas path is wrapped in jax.custom_vjp so training can stay on the
-# fused kernels end-to-end. The layer is y = Re(((x·C…)∘W)·…E) — real-
-# linear in both x and W — so:
+# The pallas path is wrapped in a custom_vjp so training can stay on the
+# fused kernels end-to-end. The spectral layer is the block with no bypass,
+# no bias and a linear epilogue, so ONE custom_vjp family
+# (``_fno_block_nd_pallas``) serves both. The layer is
+# y = Re(((x·C…)∘W)·…E) — real-linear in both x and W — so:
 #   * dx is the SAME fused DFT→CGEMM→iDFT pipeline run on the cotangent
 #     with transposed DFT operands (spectral.realified_operand_mats
 #     adjoint=True) and the weight swapped over (out, hidden);
@@ -261,18 +218,40 @@ def _modes_key(modes) -> Tuple[int, ...]:
     return tuple(int(m) for m in modes)
 
 
-def _mode_pad(modes: Sequence[int]) -> int:
-    """Rank-1 keeps its modes axis lane-aligned (it is the minor dim of the
-    accumulator); higher ranks use whole-extent mode blocks unpadded."""
-    return _rup(modes[0], 128) if len(modes) == 1 else 0
-
-
 def _default_policy(x, wr) -> PrecisionPolicy:
     """Policy inferred from the operands (legacy behavior): compute and
     spectral operands at x.dtype, dW at the weight dtype, f32 accumulate."""
     xd = jnp.dtype(x.dtype).name
     return PrecisionPolicy(param_dtype=jnp.dtype(wr.dtype).name,
                            compute_dtype=xd, spectral_dtype=xd)
+
+
+def _pallas_setup(x, wr, modes, policy, bb, bo, bh, block_plan
+                  ) -> Tuple[PrecisionPolicy, LaunchPlans]:
+    """The policy and per-launch block plans a pallas custom_vjp runs
+    under: ``policy`` (None infers one from the operands), and the
+    tuned-cache resolver keyed on (rank, shape class, layout, per-launch
+    variant, dtype) for the weight's (out, hidden), with explicit nonzero
+    bb/bo/bh (or an ``FNOConfig.block_plan`` triple) overriding
+    component-wise and ``_BLOCK_DEFAULTS`` as the fallback."""
+    pol = policy or _default_policy(x, wr)
+    override = tuple(block_plan) if block_plan else None
+    plans = resolve_launch_plans(
+        len(modes), hidden=wr.shape[1], out=wr.shape[0],
+        spatial=tuple(x.shape[2:]), modes=modes,
+        per_mode=wr.ndim == 2 + len(modes), policy=pol,
+        override=override)
+    return pol, plans.with_override(bb, bo, bh)
+
+
+def _cast(pol, *arrays):
+    """The cast contract of the custom_vjps: their primals (and so the
+    cotangents the caller sees) keep the caller's dtypes, while every
+    operand is cast here, inside the custom_vjp, to ``pol.compute_dtype``
+    and the kernels accumulate at ``pol.accum_dtype`` (ROADMAP.md
+    §Precision policy). None (no bypass, no bias) passes through."""
+    cp = jnp.dtype(pol.compute_dtype)
+    return tuple(None if a is None else a.astype(cp) for a in arrays)
 
 
 def _trailing(a, shape):
@@ -285,7 +264,7 @@ def _trailing(a, shape):
 def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
                  adjoint: bool = False, out_dtype: str = None,
                  wb=None, bias=None, gy=None, act: str = "linear",
-                 truncation: str = "low"):
+                 truncation: str = "low", lift=None, proj=None):
     """Pad to block multiples and invoke the rank-generic fused kernel.
 
     adjoint=True runs the input-cotangent pipeline: transposed DFT
@@ -294,10 +273,12 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     straight from the accumulator). wb [O,H] / bias [O] / act extend the
     kernel with the block epilogue (bypass GEMM riding the k-loop,
     +bias → activation at the ref write); gy feeds the "gelu_vjp" /
-    "relu_vjp" backward-recompute epilogue. ``modes`` are kept counts
-    (``spectral.kept_modes``). A rank-3 layer runs in its kernel layout
-    (``spectral.kernel_layout``): x, gy, y and a per-mode weight are
-    reshaped around the call, which XLA folds away between blocks.
+    "relu_vjp" backward-recompute epilogue. lift / proj fold the model's
+    end MLPs into the launch (``_ends_fused_impl``): x is then the raw
+    model input and the result the model output. ``modes`` are kept
+    counts (``spectral.kept_modes``). A rank-3 layer runs in its kernel
+    layout (``spectral.kernel_layout``): x, gy, y and a per-mode weight
+    are reshaped around the call, which XLA folds away between blocks.
     """
     spatial = tuple(x.shape[2:])
     ksp, kmodes = spectral.kernel_layout(spatial, modes)
@@ -307,13 +288,16 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     if per_mode:
         wr, wi = (_trailing(w, kmodes) for w in (wr, wi))
     b = x.shape[0]
-    o = wr.shape[0]
-    kp = _mode_pad(modes)
-    bb, bo, bh, bp, op_, hp = _blocks(x, o, bb, bo, bh)
+    o, h = wr.shape[:2]
+    kp = spectral._mode_pad(modes)
+    bb, bo, bh = legal_blocks(len(ksp), b, o, h, bb, bo, bh)
+    bp, op_, hp = _rup(b, bb), _rup(o, bo), _rup(h, bh)
     mats = spectral.realified_operand_mats(
         spatial, _modes_key(modes), pol.spectral_dtype, adjoint, kp,
         truncation)
-    xpad = _pad_axis(_pad_axis(x, 0, bp), 1, hp)
+    ends = lift is not None or proj is not None
+    cin = _rup(x.shape[1], 8) if lift is not None else hp
+    xpad = _pad_axis(_pad_axis(x, 0, bp), 1, cin)
 
     def wpad(w):
         if per_mode and kp:
@@ -323,7 +307,11 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
     wbp = None if wb is None else _pad_axis(_pad_axis(wb, 0, op_), 1, hp)
     biasp = None if bias is None else _pad_axis(bias[:, None], 0, op_)
     gyp = None if gy is None else _pad_axis(_pad_axis(gy, 0, bp), 1, op_)
-    name = ("dx_adjoint" if adjoint else
+    cout = o if proj is None else proj[3].shape[0]
+    if ends:
+        lift, proj = _engine_ends(lift, proj, cin, hp, op_)
+    name = ("block_ends" if ends else
+            "dx_adjoint" if adjoint else
             "gz_recompute" if act.endswith("_vjp") else
             "spectral_fwd" if wb is None else "block_fwd")
     y = engine.fused_fnond_call(xpad, wpad(wr), wpad(wi), *mats,
@@ -331,8 +319,9 @@ def _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
                                 interpret=interpret,
                                 out_dtype=out_dtype,
                                 acc_dtype=pol.accum_dtype,
-                                wb=wbp, bias=biasp, gy=gyp, act=act)
-    return _trailing(y[:b, :o], spatial)
+                                wb=wbp, bias=biasp, gy=gyp, act=act,
+                                lift=lift, proj=proj)
+    return _trailing(y[:b, :cout], spatial)
 
 
 def _outer_fwd_batched(x, spatial, modes, interpret, operand_dtype=None,
@@ -443,7 +432,7 @@ def _fnond_wgrad(x, gy, modes, bb, bo, bh, interpret, per_mode, pol,
     r = len(modes)
     b, h = x.shape[:2]
     o = gy.shape[1]
-    kp = _mode_pad(modes)
+    kp = spectral._mode_pad(modes)
     bb, bo, bh, bp, op_, hp = _blocks(x, o, bb, bo, bh)
     mats = spectral.wgrad_operand_mats(
         tuple(x.shape[2:]), _modes_key(modes), pol.spectral_dtype, kp,
@@ -471,61 +460,6 @@ def _check_partial(variant, truncation):
         raise NotImplementedError(
             f"variant={variant!r} keeps the low corner only; "
             f"truncation={truncation!r} runs on the full-fusion variant")
-
-
-def _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret, pol,
-                       truncation):
-    # The compute-dtype casts live INSIDE the custom_vjp: primals (and
-    # therefore the cotangents the caller sees) stay at the caller's
-    # dtypes, while the kernels run at pol.compute_dtype. `plans` is the
-    # per-launch LaunchPlans bundle (hashable nondiff arg): the forward
-    # variants read fwd/core, the backward gz/dx/wgrad.
-    cp = jnp.dtype(pol.compute_dtype)
-    x, wr, wi = x.astype(cp), wr.astype(cp), wi.astype(cp)
-    if variant == "full":
-        return _fnond_fused(x, wr, wi, modes, *plans.fwd, interpret, pol,
-                            truncation=truncation)
-    return _fnond_partial(x, wr, wi, modes, *plans.core, interpret, pol)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _spectral_layer_nd_pallas(x, wr, wi, modes, variant, plans, interpret,
-                              pol, truncation):
-    return _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret,
-                              pol, truncation)
-
-
-def _fnond_vjp_fwd(x, wr, wi, modes, variant, plans, interpret, pol,
-                   truncation):
-    y = _fnond_pallas_impl(x, wr, wi, modes, variant, plans, interpret,
-                           pol, truncation)
-    return y, (x, wr, wi)
-
-
-def _fnond_vjp_bwd(modes, variant, plans, interpret, pol, truncation, res,
-                   gy):
-    # partial and full compute the same linear map, so one adjoint (the
-    # fully fused one) serves both variants. Mixed precision: operands run
-    # at pol.compute_dtype, the accumulators at pol.accum_dtype (f32), and
-    # the emissions happen once at the ref-write boundary — dx at the
-    # primal x dtype, dW at the param dtype.
-    x, wr, wi = res
-    cp = jnp.dtype(pol.compute_dtype)
-    gy = gy.astype(cp)
-    wrc, wic = wr.astype(cp), wi.astype(cp)
-    dx = _fnond_fused(gy, jnp.swapaxes(wrc, 0, 1), jnp.swapaxes(wic, 0, 1),
-                      modes, *plans.dx, interpret, pol, adjoint=True,
-                      out_dtype=jnp.dtype(x.dtype).name,
-                      truncation=truncation)
-    dwr, dwi = _fnond_wgrad(x.astype(cp), gy, modes, *plans.wgrad,
-                            interpret,
-                            per_mode=wr.ndim == 2 + len(modes), pol=pol,
-                            out_dtype=jnp.dtype(wr.dtype).name,
-                            truncation=truncation)
-    return (dx.astype(x.dtype), dwr.astype(wr.dtype), dwi.astype(wi.dtype))
-
-
-_spectral_layer_nd_pallas.defvjp(_fnond_vjp_fwd, _fnond_vjp_bwd)
 
 
 def _fnond_xla(x, wr, wi, modes, pol=None, truncation: str = "low"):
@@ -561,42 +495,9 @@ def _fnond_xla(x, wr, wi, modes, pol=None, truncation: str = "low"):
     return y.astype(x.dtype) if pol is not None else y
 
 
-# Per-rank (bb, bo, bh) kernel block-size defaults — the documented
-# FALLBACK when no tuned cache entry matches a workload's tuning key.
-# Block selection is owned by ``repro.tuning.resolve_launch_plans``
-# (override → tuned cache → this table); nothing outside the resolver
-# and the legacy ``analysis.vmem.resolve_blocks`` helper should read it.
-# Ranks ≥ 2 hold one sample's whole spatial slab per program, so their
-# batch block is 1.
-_BLOCK_DEFAULTS = {1: (8, 128, 128), 2: (1, 128, 32), 3: (1, 128, 16)}
-
-
-def _resolve_blocks(rank, bb, bo, bh):
-    dbb, dbo, dbh = _BLOCK_DEFAULTS[rank]
-    return bb or dbb, bo or dbo, bh or dbh
-
-
-def _resolve_plans(x, wr, modes, pol, bb, bo, bh,
-                   block_plan) -> LaunchPlans:
-    """Per-launch block plans for this workload: the tuned-cache resolver
-    keyed on (rank, shape class, layout, per-launch variant, dtype), with
-    explicit nonzero bb/bo/bh (or an ``FNOConfig.block_plan`` triple)
-    overriding component-wise and ``_BLOCK_DEFAULTS`` as the fallback."""
-    override = tuple(block_plan) if block_plan else None
-    plans = resolve_launch_plans(
-        len(modes), hidden=x.shape[1], out=wr.shape[0],
-        spatial=tuple(x.shape[2:]), modes=modes,
-        per_mode=wr.ndim == 2 + len(modes), policy=pol,
-        override=override)
-    return plans.with_override(bb, bo, bh)
-
-
-def _spectral_layer_nd(x, wr, wi, modes, path, variant, bb, bo, bh,
-                       interpret, policy=None, block_plan=None,
-                       truncation="low"):
-    """The rank-R spectral layer at kept counts ``modes``
-    (``spectral.kept_modes``)."""
-    modes = _modes_key(modes)
+def _spectral_oracle(x, wr, wi, modes, path, policy, truncation):
+    """The staged ref/xla spectral layer at kept counts ``modes``: the
+    parity oracle the pallas path is held to."""
     if path == "ref":
         if policy is not None:  # oracle runs in f32, emits at compute dtype
             y32 = ref_k.ref_fnond(x.astype(jnp.float32),
@@ -604,82 +505,51 @@ def _spectral_layer_nd(x, wr, wi, modes, path, variant, bb, bo, bh,
                                   wi.astype(jnp.float32), modes, truncation)
             return y32.astype(policy.compute_dtype)
         return ref_k.ref_fnond(x, wr, wi, modes, truncation)
-    if path == "xla":
-        return _fnond_xla(x, wr, wi, modes, policy, truncation)
+    assert path == "xla", path
+    return _fnond_xla(x, wr, wi, modes, policy, truncation)
+
+
+def spectral_layer_nd(x: jax.Array, wr: jax.Array, wi: jax.Array,
+                      modes: Sequence[int], *, path: str = "pallas",
+                      variant: str = "full", bb: int = 0, bo: int = 0,
+                      bh: int = 0,
+                      interpret: Optional[bool] = None,
+                      policy: Optional[PrecisionPolicy] = None,
+                      block_plan: Optional[Tuple[int, int, int]] = None,
+                      truncation: str = "low") -> jax.Array:
+    """The FNO spectral layer at any rank R: y = Re(((x·C…)∘W)·…E).
+
+    x: [B,H,s_1..s_R]; w: [O,H] shared or [O,H,k_1..k_R] per-mode, one
+    ``modes`` entry per spatial axis. truncation: "low" keeps the first k
+    bins of every axis (TurboFNO); "corners" the ± corners of Li et al.
+    (a per-mode weight [O,H,2k_1,..,2k_{R-1},k_R]).
+
+    variant: "full" fuses the entire layer in one kernel (beyond-paper,
+    docs/DESIGN.md §3.4); "partial" fuses only the cDFT/CGEMM/icDFT stages
+    around the CGEMM (paper-faithful) and runs the outer transforms as
+    standalone launches, one batched launch per direction at rank ≥ 3. At
+    rank 1 there are no outer stages, and "partial" means "full".
+
+    path="pallas" is the block family with no bypass, no bias and a linear
+    epilogue (``fno_block_nd``'s custom_vjp): jax.grad routes through the
+    fused dx-adjoint and wgrad kernels, never falling back to XLA. policy
+    sets the mixed-precision contract (bf16 kernel I/O with f32
+    accumulators); None infers a uniform policy from the operand dtypes.
+    Block sizes resolve through ``repro.tuning.resolve_launch_plans``
+    (tuned cache → ``_BLOCK_DEFAULTS``); nonzero bb/bo/bh or a
+    ``block_plan`` triple override component-wise. path="ref"/"xla" are
+    the staged parity oracles.
+    """
+    modes = spectral.kept_modes(modes, truncation)
+    if path in ("ref", "xla"):
+        return _spectral_oracle(x, wr, wi, modes, path, policy, truncation)
+    if len(modes) == 1:
+        variant = "full"
     _check_partial(variant, truncation)
-    pol = policy or _default_policy(x, wr)
-    plans = _resolve_plans(x, wr, modes, pol, bb, bo, bh, block_plan)
-    return _spectral_layer_nd_pallas(x, wr, wi, modes, variant, plans,
-                                     _interpret(interpret), pol, truncation)
-
-
-def spectral_layer_1d(x: jax.Array, wr: jax.Array, wi: jax.Array,
-                      modes: int, *, path: str = "pallas",
-                      bb: int = 0, bo: int = 0, bh: int = 0,
-                      interpret: Optional[bool] = None,
-                      policy: Optional[PrecisionPolicy] = None,
-                      block_plan: Optional[Tuple[int, int, int]] = None
-                      ) -> jax.Array:
-    """Full 1D FNO spectral layer. x: [B,H,N]; w: [O,H] or [O,H,modes].
-
-    path="pallas" is differentiable: jax.grad routes through the fused
-    backward kernels (custom_vjp), never falling back to XLA. policy sets
-    the mixed-precision contract (bf16 kernel I/O with f32 accumulators);
-    None infers a uniform policy from the operand dtypes. Block sizes
-    resolve through ``repro.tuning.resolve_launch_plans`` (tuned cache →
-    ``_BLOCK_DEFAULTS``); nonzero bb/bo/bh or a ``block_plan`` triple
-    override component-wise.
-    """
-    return _spectral_layer_nd(x, wr, wi, (modes,), path, "full", bb, bo, bh,
-                              interpret, policy, block_plan)
-
-
-def spectral_layer_2d(x: jax.Array, wr: jax.Array, wi: jax.Array,
-                      modes: Tuple[int, int], *, path: str = "pallas",
-                      variant: str = "full", bb: int = 0, bo: int = 0,
-                      bh: int = 0,
-                      interpret: Optional[bool] = None,
-                      policy: Optional[PrecisionPolicy] = None,
-                      block_plan: Optional[Tuple[int, int, int]] = None,
-                      truncation: str = "low") -> jax.Array:
-    """Full 2D FNO spectral layer, TurboFNO truncation convention by
-    default (``truncation="corners"``: Li et al.'s ± corners, a weight
-    [O,H,2kx,ky]).
-
-    x: [B,H,X,Y]; w: [O,H] or [O,H,kx,ky]. variant: "partial" fuses only
-    around the CGEMM (paper-faithful); "full" fuses the entire layer
-    (beyond-paper, docs/DESIGN.md §3.4). path="pallas" is differentiable via
-    custom_vjp (fused backward for both variants). policy / block
-    selection: see spectral_layer_1d.
-    """
-    return _spectral_layer_nd(x, wr, wi,
-                              spectral.kept_modes(modes, truncation), path,
-                              variant, bb, bo, bh, interpret, policy,
-                              block_plan, truncation)
-
-
-def spectral_layer_3d(x: jax.Array, wr: jax.Array, wi: jax.Array,
-                      modes: Tuple[int, int, int], *, path: str = "pallas",
-                      variant: str = "full", bb: int = 0, bo: int = 0,
-                      bh: int = 0,
-                      interpret: Optional[bool] = None,
-                      policy: Optional[PrecisionPolicy] = None,
-                      block_plan: Optional[Tuple[int, int, int]] = None,
-                      truncation: str = "low") -> jax.Array:
-    """Full 3D FNO spectral layer (Navier–Stokes-class workloads);
-    truncation as in spectral_layer_2d (corners: [O,H,2kx,2ky,kz]).
-
-    x: [B,H,X,Y,Z]; w: [O,H] or [O,H,kx,ky,kz]. Same engine, rank pinned
-    to 3: variant "full" fuses the whole layer in one kernel; "partial"
-    (paper-faithful) fuses only the GEMM-adjacent cDFT/icDFT pair and runs
-    the outer transforms as ONE batched standalone launch per direction.
-    path="pallas" is differentiable via custom_vjp (fused backward for
-    both variants). policy / block selection: see spectral_layer_1d.
-    """
-    return _spectral_layer_nd(x, wr, wi,
-                              spectral.kept_modes(modes, truncation), path,
-                              variant, bb, bo, bh, interpret, policy,
-                              block_plan, truncation)
+    pol, plans = _pallas_setup(x, wr, modes, policy, bb, bo, bh, block_plan)
+    return _fno_block_nd_pallas(x, wr, wi, None, None, modes, variant, plans,
+                                _interpret(interpret), pol, "linear", None,
+                                truncation)
 
 
 # ---------------------------------------------------------------------------
@@ -691,15 +561,16 @@ def spectral_layer_3d(x: jax.Array, wr: jax.Array, wi: jax.Array,
 # in the iDFT epilogue — the per-layer XLA ops (bypass GEMM, bias, sum,
 # GELU) and their ~4 HBM round trips on B·H·∏s tensors disappear.
 #
-# End-to-end differentiable via its own custom_vjp:
+# End-to-end differentiable via its custom_vjp, which also serves the
+# spectral layer alone (wb=None, bias=None, act="linear"):
 #   * gz: one fused kernel recomputes the pre-activation z through the
 #     same forward pipeline and emits gz = gy·gelu'(z) (act="gelu_vjp") —
-#     z never touches HBM;
+#     z never touches HBM; a linear block skips it (gz = gy);
 #   * dx = spectral_adjoint(gz) + gz·W_b: the SAME block kernel with
 #     adjoint DFT operands, (out,hidden)-swapped spectral weight, and the
-#     transposed bypass riding the k-loop;
-#   * dW, dW_b, dbias: the extended wgrad kernel (with_bypass=True) emits
-#     all three from the refs it already holds in VMEM.
+#     transposed bypass (if any) riding the k-loop;
+#   * dW (and dW_b, dbias with a bypass): the wgrad kernel emits them all
+#     from the refs it already holds in VMEM.
 # The backward always runs the fully fused pipeline — partial and full
 # compute the same function, so one adjoint serves both variants.
 # ---------------------------------------------------------------------------
@@ -733,23 +604,20 @@ def _fno_block_oracle(x, wr, wi, wb, bias, modes, path, pol, act="gelu",
     """Staged parity oracle: spectral layer (ref/xla path) + XLA bypass +
     bias + activation — the exact math the one-kernel pallas path fuses.
     ``modes`` are kept counts."""
-    s = _spectral_layer_nd(x, wr, wi, modes, path, "full", 0, 0, 0,
-                           None, pol, truncation=truncation)
+    s = _spectral_oracle(x, wr, wi, modes, path, pol, truncation)
     cp = jnp.dtype(pol.compute_dtype) if pol is not None else x.dtype
     return _block_tail(s, x.astype(cp), wb, bias, s.dtype, act)
 
 
 def _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
                     interpret, pol, act, out_dtype, truncation):
-    # Same cast contract as the spectral layer: compute-dtype casts live
-    # inside the custom_vjp so the caller's primal/cotangent dtypes are
-    # preserved (PrecisionPolicy — ROADMAP.md §Precision policy).
+    # `plans` is the per-launch LaunchPlans bundle (hashable nondiff arg):
+    # the forward variants read fwd/core, the backward gz/dx/wgrad.
     # out_dtype (default: the compute dtype) overrides the single ref-write
     # emission — the TP-sharded dispatch keeps the partial pre-activations
     # at the accumulator dtype through the psum.
-    cp = jnp.dtype(pol.compute_dtype)
-    od = jnp.dtype(out_dtype) if out_dtype else cp
-    x, wr, wi, wb, bias = (a.astype(cp) for a in (x, wr, wi, wb, bias))
+    od = jnp.dtype(out_dtype or pol.compute_dtype)
+    x, wr, wi, wb, bias = _cast(pol, x, wr, wi, wb, bias)
     if variant == "full":
         return _fnond_fused(x, wr, wi, modes, *plans.fwd, interpret, pol,
                             wb=wb, bias=bias, act=act, out_dtype=od.name,
@@ -758,7 +626,7 @@ def _fno_block_impl(x, wr, wi, wb, bias, modes, variant, plans,
     # pipeline; the block tail (bypass+bias+act) runs as XLA ops. The
     # BACKWARD still uses the fully fused adjoint (one linear map).
     s = _fnond_partial(x, wr, wi, modes, *plans.core, interpret, pol)
-    return _block_tail(s, x, wb, bias, od, act)
+    return s if wb is None else _block_tail(s, x, wb, bias, od, act)
 
 
 @functools.partial(jax.custom_vjp,
@@ -779,9 +647,7 @@ def _fno_block_vjp_fwd(x, wr, wi, wb, bias, modes, variant, plans,
 def _fno_block_vjp_bwd(modes, variant, plans, interpret, pol, act,
                        out_dtype, truncation, res, gy):
     x, wr, wi, wb, bias = res
-    cp = jnp.dtype(pol.compute_dtype)
-    xc, wrc, wic, wbc, biasc = (a.astype(cp) for a in (x, wr, wi, wb, bias))
-    gyc = gy.astype(cp)
+    xc, wrc, wic, wbc, biasc, gyc = _cast(pol, x, wr, wi, wb, bias, gy)
     if act != "linear":
         # (1) recompute the pre-activation through the fused forward and
         # form gz = gy·act'(z) in the epilogue — z never reaches HBM.
@@ -789,8 +655,9 @@ def _fno_block_vjp_bwd(modes, variant, plans, interpret, pol, act,
                           wb=wbc, bias=biasc, gy=gyc, act=act + "_vjp",
                           truncation=truncation)
     else:
-        # Linear block (the TP-sharded partial): z IS the output, so the
-        # incoming cotangent is gz directly — no recompute kernel.
+        # Linear block (the spectral layer, a model's unactivated last
+        # block, the TP-sharded partial): z IS the output, so the incoming
+        # cotangent is gz directly — no recompute kernel.
         gz = gyc
     # (2) dx = spectral_adjoint(gz) + gz·W_b: the same block kernel with
     # adjoint operands, swapped spectral weight, transposed bypass, linear
@@ -798,16 +665,20 @@ def _fno_block_vjp_bwd(modes, variant, plans, interpret, pol, act,
     dx = _fnond_fused(gz, jnp.swapaxes(wrc, 0, 1), jnp.swapaxes(wic, 0, 1),
                       modes, *plans.dx, interpret, pol, adjoint=True,
                       out_dtype=jnp.dtype(x.dtype).name,
-                      wb=jnp.swapaxes(wbc, 0, 1), truncation=truncation)
-    # (3) dW, dW_b, dbias from ONE extended wgrad kernel, emitted at the
-    # param dtype straight from the f32 accumulators.
-    dwr, dwi, dwb, db = _fnond_wgrad(
+                      wb=None if wb is None else jnp.swapaxes(wbc, 0, 1),
+                      truncation=truncation)
+    # (3) dW (with a bypass also dW_b, dbias) from ONE wgrad kernel,
+    # emitted at the param dtype straight from the f32 accumulators.
+    dw = _fnond_wgrad(
         xc, gz, modes, *plans.wgrad, interpret,
         per_mode=wr.ndim == 2 + len(modes), pol=pol,
-        out_dtype=jnp.dtype(wr.dtype).name, with_bypass=True,
+        out_dtype=jnp.dtype(wr.dtype).name, with_bypass=wb is not None,
         truncation=truncation)
-    return (dx.astype(x.dtype), dwr.astype(wr.dtype), dwi.astype(wi.dtype),
-            dwb.astype(wb.dtype), db.astype(bias.dtype))
+    grads = (dx.astype(x.dtype), dw[0].astype(wr.dtype),
+             dw[1].astype(wi.dtype))
+    if wb is None:
+        return grads + (None, None)
+    return grads + (dw[2].astype(wb.dtype), dw[3].astype(bias.dtype))
 
 
 _fno_block_nd_pallas.defvjp(_fno_block_vjp_fwd, _fno_block_vjp_bwd)
@@ -839,7 +710,7 @@ def fno_block_nd(x: jax.Array, wr: jax.Array, wi: jax.Array, wb: jax.Array,
     staged parity oracles. Block sizes come from the tuned-plan resolver
     (override → ``tuning/cache`` → ``_BLOCK_DEFAULTS``); nonzero bb/bo/bh
     or ``block_plan`` override component-wise across all five launches.
-    policy: see spectral_layer_1d.
+    policy: see spectral_layer_nd.
 
     act: "gelu" (the standard block), "relu" (Li et al.'s FNO-3D) or
     "linear" (pre-activation only — a model's unactivated last block, and
@@ -857,8 +728,7 @@ def fno_block_nd(x: jax.Array, wr: jax.Array, wi: jax.Array, wb: jax.Array,
         return _fno_block_oracle(x, wr, wi, wb, bias, modes, path, policy,
                                  act, truncation)
     _check_partial(variant, truncation)
-    pol = policy or _default_policy(x, wr)
-    plans = _resolve_plans(x, wr, modes, pol, bb, bo, bh, block_plan)
+    pol, plans = _pallas_setup(x, wr, modes, policy, bb, bo, bh, block_plan)
     return _fno_block_nd_pallas(x, wr, wi, wb, bias, modes, variant, plans,
                                 _interpret(interpret), pol, act, out_dtype,
                                 truncation)
@@ -909,71 +779,43 @@ def _ends_staged(x, wr, wi, wb, bias, ends, modes, path, pol):
     return z
 
 
-def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
-                     pol):
-    """Pad/transpose the end-MLP params to the engine layout and launch the
-    single ends-fused kernel. Reuses the block_fwd tuned plan; the proj
-    epilogue pins bo to the padded O (it contracts the full hidden width),
-    so the out-channel grid collapses to one step."""
-    cp = jnp.dtype(pol.compute_dtype)
-    lift, proj = ends
-    x, wr, wi, wb, bias = (a.astype(cp) for a in (x, wr, wi, wb, bias))
-    lift = None if lift is None else tuple(a.astype(cp) for a in lift)
-    proj = None if proj is None else tuple(a.astype(cp) for a in proj)
-    spatial = tuple(x.shape[2:])
-    ksp, kmodes = spectral.kernel_layout(spatial, modes)
-    x = _trailing(x, ksp)
-    if wr.ndim == 2 + len(modes):
-        wr, wi = (_trailing(w, kmodes) for w in (wr, wi))
-    r = len(kmodes)
-    b = x.shape[0]
-    o = wr.shape[0]
-    h = lift[2].shape[1] if lift is not None else x.shape[1]
-    kp = _mode_pad(modes)
-    bb, bo, bh = legal_blocks(r, b, o, h, *plans.fwd)
-    bp, hp = _rup(b, bb), _rup(h, bh)
-    if proj is not None:
-        bo = op_ = _rup(o, 8)
-    else:
-        op_ = _rup(o, bo)
-    mats = spectral.realified_operand_mats(
-        spatial, _modes_key(modes), pol.spectral_dtype, False, kp)
-
-    def wpad(w):
-        if wr.ndim == 2 + r and kp:
-            w = _pad_axis(w, 2, kp)
-        return _pad_axis(_pad_axis(w, 0, op_), 1, hp)
-
-    wbp = _pad_axis(_pad_axis(wb, 0, op_), 1, hp)
-    biasp = _pad_axis(bias[:, None], 0, op_)
+def _engine_ends(lift, proj, cin, hp, op_):
+    """The end MLPs in the engine's layout: each weight transposed to
+    [out, in] and each bias a column, padded to the kernel's channel
+    padding — the lift from the raw input's ``cin`` channels to the
+    padded hidden ``hp``, the projection from the padded out channels
+    ``op_``; the inner widths to multiples of 8."""
     col = lambda v, to: _pad_axis(v[:, None], 0, to)
     mat = lambda w, rto, cto: _pad_axis(
         _pad_axis(jnp.swapaxes(w, 0, 1), 0, rto), 1, cto)
-    engine_lift = None
+    engine_lift = engine_proj = None
     if lift is not None:
         l1w, l1b, l2w, l2b = lift
-        cinp = _rup(x.shape[1], 8)
         lp = _rup(l1w.shape[1], 8)
-        xpad = _pad_axis(_pad_axis(x, 0, bp), 1, cinp)
-        engine_lift = (mat(l1w, lp, cinp), col(l1b, lp),
+        engine_lift = (mat(l1w, lp, cin), col(l1b, lp),
                        mat(l2w, hp, lp), col(l2b, hp))
-    else:
-        xpad = _pad_axis(_pad_axis(x, 0, bp), 1, hp)
-    engine_proj = None
     if proj is not None:
         p1w, p1b, p2w, p2b = proj
         lp2 = _rup(p1w.shape[1], 8)
         coutp = _rup(p2w.shape[1], 8)
         engine_proj = (mat(p1w, lp2, op_), col(p1b, lp2),
                        mat(p2w, coutp, lp2), col(p2b, coutp))
-    y = engine.fused_fnond_call(xpad, wpad(wr), wpad(wi), *mats,
-                                bb=bb, bo=bo, bh=bh, name="block_ends",
-                                interpret=interpret,
-                                acc_dtype=pol.accum_dtype, wb=wbp,
-                                bias=biasp, act="gelu", lift=engine_lift,
-                                proj=engine_proj)
-    cout = proj[3].shape[0] if proj is not None else o
-    return _trailing(y[:b, :cout], spatial)
+    return engine_lift, engine_proj
+
+
+def _ends_fused_impl(x, wr, wi, wb, bias, ends, modes, plans, interpret,
+                     pol):
+    """The single ends-fused launch (``_fnond_fused`` with the end MLPs).
+    Reuses the block_fwd tuned plan; the proj epilogue pins bo to the
+    padded O (it contracts the full hidden width), so the out-channel
+    grid collapses to one step."""
+    x, wr, wi, wb, bias = _cast(pol, x, wr, wi, wb, bias)
+    lift, proj = (None if e is None else _cast(pol, *e) for e in ends)
+    bb, bo, bh = plans.fwd
+    if proj is not None:
+        bo = _rup(wr.shape[0], 8)
+    return _fnond_fused(x, wr, wi, modes, bb, bo, bh, interpret, pol,
+                        wb=wb, bias=bias, act="gelu", lift=lift, proj=proj)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
@@ -1031,18 +873,12 @@ def fno_block_ends_nd(x: jax.Array, wr: jax.Array, wi: jax.Array,
     """
     modes = _modes_key(modes)
     ends = (lift, proj)
-    pol = policy or _default_policy(x, wr)
     if path in ("ref", "xla"):
-        return _ends_staged(x, wr, wi, wb, bias, ends, modes, path, pol)
+        return _ends_staged(x, wr, wi, wb, bias, ends, modes, path,
+                            policy or _default_policy(x, wr))
     assert variant == "full", \
         "fused ends require the full-fusion variant (partial stays staged)"
-    hidden = lift[2].shape[1] if lift is not None else x.shape[1]
-    override = tuple(block_plan) if block_plan else None
-    plans = resolve_launch_plans(
-        len(modes), hidden=hidden, out=wr.shape[0],
-        spatial=tuple(x.shape[2:]), modes=modes,
-        per_mode=wr.ndim == 2 + len(modes), policy=pol, override=override)
-    plans = plans.with_override(bb, bo, bh)
+    pol, plans = _pallas_setup(x, wr, modes, policy, bb, bo, bh, block_plan)
     return _fno_block_ends_pallas(x, wr, wi, wb, bias, ends, modes, plans,
                                   _interpret(interpret), pol)
 

@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache for this checkout's entry points.
 
 ``chip_smoke.py``, ``launch/serve.py``, ``examples/train_fno.py`` and
-``benchmarks/run.py`` call :func:`enable_compile_cache` before their first
-compile, so a second run on the same machine finds the compiled kernels
+the on-chip benchmark's harness (``benchmarks/onchip/harness.py``) call
+:func:`enable_compile_cache` before their first compile, so a second run on the same machine finds the compiled kernels
 again instead of recompiling them.
 """
 from __future__ import annotations

@@ -330,7 +330,7 @@ def fno_collective_bytes(cfg, dp: int, tp: int, *, scattered: bool = True,
     Mirrors the runtime's degradation rules: tp<=1 or hidden % tp != 0
     folds TP away (no collectives — ``make_context``). Returns a dict
     {"interior_per_layer", "final", "total"} so callers can surface the
-    per-layer ratio directly (bench_e2e.run_serve's derived column).
+    per-layer ratio directly (``FNOServer.collective_plan``).
     """
     import math
     if tp <= 1 or cfg.hidden % tp != 0:

@@ -351,5 +351,5 @@ class ResilientServer:
     def pool_report(self) -> Dict[str, object]:
         """Pool + degradation counters in one dict — what the serve
         driver prints next to ``collective_plan()`` and what dashboards
-        scrape (schema recorded in benchmarks/README.md)."""
+        scrape (the keys are ``STAT_KEYS``)."""
         return {"replicas": self.pool.states(), **self.stats}

@@ -1,4 +1,4 @@
-"""The block-size autotuner: generate → VMEM-prune → measure → persist.
+"""The block-size autotuner: generate → VMEM-prune → rank → compile → persist.
 
 TVM/Ansor-shaped search specialized to the fused FNO engine's tiny
 3-parameter launch space (docs/DESIGN.md §8). For every tuning key the
@@ -7,48 +7,44 @@ config matrix can emit (all ``FNO_IDS`` × {full, reduced} × {f32, bf16}
 
 1. **generate** the candidate grid — bb ∈ {1, 2, 4, 8}, bo/bh ∈ {1, 2,
    …, 128}, plus the rank's static default — clamped to the probe
-   dims and to Mosaic's tiling rule (``ops.legal_blocks``) and deduped on
-   the effective triple;
+   dims and to Mosaic's tiling rule (``plans.legal_blocks``) and deduped
+   on the effective triple;
 2. **prune** statically with ``analysis.vmem.launch_estimate`` against
    ``VMEM_BUDGET_BYTES`` (the limit the kernels compile under);
-3. **measure** the top-K surviving candidates (static score: least pad
-   waste, then largest bo/bh/bb) with the bench harness
-   (``benchmarks.common.time_fn``) over jit-wrapped interpret-mode
-   launches — only for probes small enough to interpret
-   (``hidden·∏spatial ≤ MEASURE_ELEMS``; the full-size 2D/3D grids keep
-   their statically-scored winner, which is compiled for a described v5e
-   where libtpu is installed — a plan the compiler refuses for VMEM falls
-   through to the next, and the winner is flagged ``source: "compiled"``;
-   elsewhere ``"estimated"``);
-4. **persist** winners + evidence (VMEM estimate, wall time, probe
-   shapes) via ``store.save_cache`` — the committed
-   ``tuning/cache/blocks.json`` that ``resolve_launch_plans`` reads.
+3. **rank** the survivors statically (least pad waste, then the largest
+   bo/bh/bb);
+4. **compile** the winner of every full-size probe
+   (``hidden·∏spatial > COMPILE_ELEMS``) for a described v5e where
+   libtpu is installed — a plan the compiler refuses for VMEM falls
+   through to the next, and the winner is flagged ``source:
+   "compiled"``; elsewhere ``"estimated"``. Nothing here is timed: the
+   interpreter's wall time says nothing about the chip;
+5. **persist** winners + evidence (VMEM estimate, probe shapes) via
+   ``store.save_cache`` — the committed ``tuning/cache/blocks.json``
+   that ``resolve_launch_plans`` reads.
 
-Entry points: :func:`tune` (library), ``scripts/autotune.py`` (CLI),
-``benchmarks/run.py --autotune`` (bench hook).
+Entry points: :func:`tune` (library), ``scripts/autotune.py`` (CLI).
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.tuning import plans as P
 from repro.tuning import store
 
-# A candidate interpret-mode measurement is only meaningful (and
-# affordable) when the probe activation is small: hidden·∏spatial in
-# elements. Reduced configs and the full-size 1D config qualify; the
-# full-size 2D/3D grids are statically scored.
-MEASURE_ELEMS = 131_072
+# Probes over this many elements (hidden·∏spatial) — the full-size 2D/3D
+# grids — have their winner compiled for a described v5e; the reduced
+# configs and the full-size 1D config keep their static winner.
+COMPILE_ELEMS = 131_072
 
 _BB_GRID = (1, 2, 4, 8)
-# Preferences only: ops.legal_blocks clamps every one to a plan Mosaic's
+# Preferences only: plans.legal_blocks clamps every one to a plan Mosaic's
 # tiling rule admits (bo a multiple of 8; bh too at rank 1) and the
 # candidates dedupe on the clamped triple. bh down to 1: the full-size 3D
 # grid keeps bh·∏s elements of x resident per program.
 _BOH_GRID = (1, 2, 4, 8, 16, 32, 64, 128)
-_TOP_K = 3
 _PROBE_BATCH = 8
 _COMPILE_TRIES = 8
 # Static ranking prefers the largest batch block whose program stays under
@@ -129,14 +125,12 @@ def _candidates(w: Workload) -> List[Tuple[int, int, int]]:
     """Candidate grid, clamped to the probe dims and deduped on the
     effective triple (two preferences that clamp to the same launch are
     the same candidate)."""
-    from repro.kernels.ops import _BLOCK_DEFAULTS, legal_blocks
-
     boh = _BOH_GRID + (w.hidden,)  # the whole dim is always a legal block
     raw = list(itertools.product(_BB_GRID, boh, boh))
-    raw.append(_BLOCK_DEFAULTS[w.rank])
+    raw.append(P._BLOCK_DEFAULTS[w.rank])
     seen, out = set(), []
     for t in raw:
-        eff = legal_blocks(w.rank, _PROBE_BATCH, w.hidden, w.hidden, *t)
+        eff = P.legal_blocks(w.rank, _PROBE_BATCH, w.hidden, w.hidden, *t)
         if eff not in seen:
             seen.add(eff)
             out.append(eff)
@@ -187,7 +181,7 @@ def _feasible(w: Workload) -> Dict[str, List[Tuple[Tuple[int, int, int],
 
 
 # ---------------------------------------------------------------------------
-# Measurement: jit-wrapped interpret-mode launches over a shared probe.
+# The probe: one launch's operands at the workload's shapes.
 # ---------------------------------------------------------------------------
 def _probe_arrays(w: Workload):
     import jax
@@ -210,8 +204,8 @@ def _probe_arrays(w: Workload):
 def _launch(w: Workload, launch: str, triple: Tuple[int, int, int],
             interpret: bool):
     """ONE launch of the given kind as fn(x, wr, wi, wb, bias, gy) — the
-    same internal entry points the custom_vjps call, so what is timed or
-    compiled is a real launch."""
+    same internal entry points the custom_vjps call, so what is compiled
+    is a real launch."""
     import jax.numpy as jnp
 
     from repro.kernels import ops
@@ -237,21 +231,6 @@ def _launch(w: Workload, launch: str, triple: Tuple[int, int, int],
     return lambda x, wr, wi, wb, bias, gy: ops._fnond_wgrad(
         x, gy, modes, bb, bo, bh, interpret, per_mode=w.per_mode, pol=pol,
         with_bypass=True)
-
-
-def _measure(w: Workload, launch: str, triple, iters: int) -> float:
-    import sys
-
-    import jax
-
-    bench = _bench_dir()
-    if bench not in sys.path:  # the harness is a top-level dir, not a pkg
-        sys.path.insert(0, bench)
-    from common import time_fn
-
-    args = _probe_arrays(w)
-    fn = _launch(w, launch, triple, interpret=True)
-    return time_fn(jax.jit(lambda: fn(*args)), warmup=1, iters=iters)
 
 
 # ---------------------------------------------------------------------------
@@ -311,26 +290,15 @@ def _compile_refusal(w: Workload, launch: str, triple, chip
     return None
 
 
-def _bench_dir() -> str:
-    import os
-
-    return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))), "benchmarks")
-
-
 # ---------------------------------------------------------------------------
 # Driver
 # ---------------------------------------------------------------------------
-def tune(measure: str = "auto", smoke: bool = False,
-         out: Optional[str] = None, iters: int = 5,
+def tune(smoke: bool = False, out: Optional[str] = None,
          log=print) -> Tuple[str, Dict[str, dict]]:
-    """Run the full search and persist the cache. ``measure``: "auto"
-    (probes under :data:`MEASURE_ELEMS` get wall-timed), "all" (force
-    timing everywhere — slow off-TPU), "none" (static scores only —
-    the CI smoke mode). Statically scored full-size winners are compiled
-    for a described v5e where libtpu is installed (``smoke`` skips it).
-    Returns (cache_path, entries)."""
-    assert measure in ("auto", "all", "none"), measure
+    """Run the full search and persist the cache. Statically ranked
+    full-size winners are compiled for a described v5e where libtpu is
+    installed (``smoke``: the reduced shapes only, nothing compiled — the
+    CI mode). Returns (cache_path, entries)."""
     entries: Dict[str, dict] = {}
     chip = None if smoke else _described_chip()
     if not smoke and chip is None:
@@ -338,8 +306,6 @@ def tune(measure: str = "auto", smoke: bool = False,
             "not compile-checked")
     for w in tunable_workloads(smoke=smoke):
         feasible = _feasible(w)
-        timed = measure == "all" or (measure == "auto"
-                                     and w.elems <= MEASURE_ELEMS)
         for launch in w.launches:
             key = P.plan_key(w.rank, w.klass, w.layout, w.dtype, launch)
             if key in entries:
@@ -349,32 +315,19 @@ def tune(measure: str = "auto", smoke: bool = False,
                 log(f"  !! {key}: NO feasible candidate — key left to the "
                     f"static fallback")
                 continue
-            entry = {"probe": {"batch": _PROBE_BATCH, "hidden": w.hidden,
-                               "spatial": list(w.spatial),
-                               "modes": list(w.modes)},
-                     "workload": w.label}
-            if timed:
-                best_us, best = None, None
-                for t, est in fits[:_TOP_K]:
-                    us = _measure(w, launch, t, iters)
-                    log(f"  {key}: {t} -> {us:.0f}us "
-                        f"({est / 2**20:.1f} MiB est)")
-                    if best_us is None or us < best_us:
-                        best_us, best = us, (t, est)
-                entry.update(bb=best[0][0], bo=best[0][1], bh=best[0][2],
-                             est_bytes=best[1], us=round(best_us, 1),
-                             source="measured")
-            else:
-                t, est = fits[0]
-                source = "estimated"
-                if chip is not None and w.elems > MEASURE_ELEMS:
-                    t, est, source = _compiled_choice(w, launch, fits,
-                                                      chip, key, log)
-                log(f"  {key}: {t} ({est / 2**20:.1f} MiB est, {source})")
-                entry.update(bb=t[0], bo=t[1], bh=t[2], est_bytes=est,
-                             source=source)
-            entries[key] = entry
-    path = store.save_cache(entries, meta={"measure": measure,
-                                           "smoke": smoke}, path=out)
+            t, est = fits[0]
+            source = "estimated"
+            if chip is not None and w.elems > COMPILE_ELEMS:
+                t, est, source = _compiled_choice(w, launch, fits, chip,
+                                                  key, log)
+            log(f"  {key}: {t} ({est / 2**20:.1f} MiB est, {source})")
+            entries[key] = {"probe": {"batch": _PROBE_BATCH,
+                                      "hidden": w.hidden,
+                                      "spatial": list(w.spatial),
+                                      "modes": list(w.modes)},
+                            "workload": w.label,
+                            "bb": t[0], "bo": t[1], "bh": t[2],
+                            "est_bytes": est, "source": source}
+    path = store.save_cache(entries, meta={"smoke": smoke}, path=out)
     log(f"wrote {len(entries)} entries -> {path}")
     return path, entries

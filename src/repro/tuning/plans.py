@@ -3,10 +3,11 @@
 A *plan* is a (bb, bo, bh) block-size preference for ONE engine launch
 kind; a :class:`LaunchPlans` bundles the five per-launch plans a fused
 FNO block's training step needs and travels through the custom_vjps as a
-single hashable nondiff argument. Plans are *preferences*: the ops layer
-still clamps them to the actual dims at call time (``ops._pick_block``),
-which is why the tuning key classes shapes by power-of-two buckets and
-excludes the batch size entirely.
+single hashable nondiff argument. Plans are *preferences*: they are
+clamped to the actual dims at call time by :func:`legal_blocks`, the one
+rule the ops layer, the tuner and the VMEM estimator share. That is why
+the tuning key classes shapes by power-of-two buckets and excludes the
+batch size entirely.
 
 Key schema (docs/DESIGN.md §8)::
 
@@ -27,6 +28,7 @@ Key schema (docs/DESIGN.md §8)::
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
 Triple = Tuple[int, int, int]
@@ -164,3 +166,67 @@ def normalize_override(override: Optional[Sequence[int]]) -> Triple:
         raise ValueError(f"block plan override must be 3 non-negative "
                          f"ints, got {override!r}")
     return t
+
+
+# ---------------------------------------------------------------------------
+# The plan rules: the static fallback and the clamp every launch runs under.
+# ---------------------------------------------------------------------------
+# Per-rank (bb, bo, bh) kernel block-size defaults — the documented
+# FALLBACK when no tuned cache entry matches a workload's tuning key.
+# Block selection is owned by ``repro.tuning.resolve_launch_plans``
+# (override → tuned cache → this table); nothing outside the resolver
+# and the legacy ``analysis.vmem.resolve_blocks`` helper should read it.
+# Ranks ≥ 2 hold one sample's whole spatial slab per program, so their
+# batch block is 1.
+_BLOCK_DEFAULTS = {1: (8, 128, 128), 2: (1, 128, 32), 3: (1, 128, 16)}
+
+
+def _resolve_blocks(rank, bb, bo, bh):
+    dbb, dbo, dbh = _BLOCK_DEFAULTS[rank]
+    return bb or dbb, bo or dbo, bh or dbh
+
+
+def _rup(v: int, m: int) -> int:
+    return ((v + m - 1) // m) * m
+
+
+@functools.lru_cache(maxsize=None)
+def _pick_block(dim: int, pref: int) -> int:
+    """Clamp a preferred block size to the actual dim with minimal pad
+    waste: among the feasible candidates (8-aligned sizes up to pref, or
+    every size up to pref when pref < 8 — batch blocks), pick the one
+    whose padded total ``_rup(dim, b)`` is smallest, breaking ties toward
+    the larger block (fewer grid steps). This keeps prime/odd extents
+    from forcing near-2× padding — e.g. dim=129 under pref=128 pads to
+    136 via b=8, not to 256 via b=128 — while exact-fit dims still get
+    the largest divisor ≤ pref. A preference equal to the dim is taken as
+    it is: a block that spans the whole dim is always legal (Li et al.'s
+    width 20 runs as one 20-wide block, not padded to 24)."""
+    if pref == dim:
+        return dim
+    if pref < 8:
+        cands = range(1, pref + 1)
+    else:
+        cands = range(8, max(8, min(pref, _rup(dim, 8))) + 1, 8)
+    return min(cands, key=lambda b: (_rup(dim, b), -b))
+
+
+def legal_blocks(rank: int, b: int, o: int, h: int, bb: int, bo: int,
+                 bh: int) -> Triple:
+    """The (bb, bo, bh) every engine launch of a rank-R layer runs for
+    dims (B, O, H) under a block preference — the one clamp the ops
+    layer, the tuner and the VMEM estimator share. Mosaic tiles the last
+    two dims of every block by (8, 128) unless the block spans the whole
+    array dim. bo is the second-minor dim of the weight and bias windows,
+    so it is a multiple of 8 (O is padded to it). bh is a free k-loop tile
+    (the engine lays weights out hidden-blocked), except at rank 1, where
+    it is the second-minor dim of the [bb, bh, N] activation window."""
+    bh = _pick_block(h, max(bh, 8) if rank == 1 else bh)
+    return _pick_block(b, bb), _pick_block(o, max(bo, 8)), bh
+
+
+def tiling_legal(rank: int, o: int, h: int, bo: int, bh: int) -> bool:
+    """Whether (bo, bh) satisfies the tiling rule for a rank-R layer with
+    dims (O, H) — see :func:`legal_blocks`."""
+    return ((bo % 8 == 0 or bo == o)
+            and (rank > 1 or bh % 8 == 0 or bh == h))

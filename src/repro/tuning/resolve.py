@@ -11,25 +11,20 @@ answers for one launch kind (the serve bucket ladder asks it for the
    resolved value);
 2. tuned cache hit (``store.lookup`` under the ``plans.plan_key``
    schema — regenerate with ``scripts/autotune.py``);
-3. the documented static fallback ``kernels.ops._BLOCK_DEFAULTS``.
+3. the documented static fallback ``plans._BLOCK_DEFAULTS``.
 
-Returned plans are preferences: ``ops._pick_block`` still clamps them to
+Returned plans are preferences: ``plans.legal_blocks`` still clamps them to
 the actual dims at call time, so tiny trace shapes and ragged batches
 never need their own cache entries.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple, Union
+from typing import Optional, Sequence, Tuple
 
 from repro.tuning import store
-from repro.tuning.plans import (BlockPlan, LAUNCH_KINDS, LaunchPlans,
-                                dtype_tag, normalize_override, plan_key,
-                                shape_class)
-
-
-def _defaults(rank: int) -> Tuple[int, int, int]:
-    from repro.kernels.ops import _BLOCK_DEFAULTS
-    return _BLOCK_DEFAULTS[rank]
+from repro.tuning.plans import (_BLOCK_DEFAULTS, BlockPlan, LAUNCH_KINDS,
+                                LaunchPlans, dtype_tag, normalize_override,
+                                plan_key, shape_class)
 
 
 def _norm_workload(cfg_or_shapes, policy):
@@ -52,7 +47,7 @@ def _resolve_one(rank: int, klass: str, layout: str, dtype: str,
                  cache_path: Optional[str]) -> BlockPlan:
     key = plan_key(rank, klass, layout, dtype, launch)
     cached = store.lookup(key, cache_path)
-    base = cached if cached is not None else _defaults(rank)
+    base = cached if cached is not None else _BLOCK_DEFAULTS[rank]
     source = "cache" if cached is not None else "default"
     bb, bo, bh = (override[0] or base[0], override[1] or base[1],
                   override[2] or base[2])
@@ -115,7 +110,7 @@ def resolve_block_plan(cfg_or_shapes, launch: str = "block_fwd", *,
     """Resolve ONE launch kind's plan for a config (or a ``(hidden,
     spatial, modes, per_mode)`` tuple). An ``FNOConfig.block_plan``
     participates as the override unless an explicit ``override`` is
-    given. This is the public face of the old ``ops._BLOCK_DEFAULTS``
+    given. This is the public face of the ``plans._BLOCK_DEFAULTS``
     lookup — ``train/serve_fno_step.batch_block`` reads ``.bb`` off it.
     """
     if launch not in LAUNCH_KINDS:

@@ -13,7 +13,7 @@ Staleness contract (``check_tuning_cache``, wired into ``scripts/lint.py
 current budget — a cache tuned against an older launch geometry or
 budget is an error, not a silent fallback. Each entry must parse as a
 valid key, carry a positive triple that Mosaic's tiling rule admits
-(``ops.tiling_legal``), and (re-estimated against its recorded probe
+(``plans.tiling_legal``), and (re-estimated against its recorded probe
 shapes with the CURRENT estimator) still fit the budget.
 """
 from __future__ import annotations
@@ -93,7 +93,6 @@ def check_tuning_cache(path: Optional[str] = None) -> List[Finding]:
     from repro.analysis.vmem import VMEM_BUDGET_BYTES, launch_estimate
     from repro.configs.base import PrecisionPolicy
     from repro.kernels.engine import BLOCK_SIGNATURE
-    from repro.kernels.ops import tiling_legal
     from repro.tuning import plans as P
 
     path = path or DEFAULT_CACHE_PATH
@@ -147,7 +146,7 @@ def check_tuning_cache(path: Optional[str] = None) -> List[Finding]:
                                     "to re-check feasibility"))
             continue
         hidden = int(probe["hidden"])
-        if not tiling_legal(parsed["rank"], hidden, hidden, triple[1],
+        if not P.tiling_legal(parsed["rank"], hidden, hidden, triple[1],
                             triple[2]):
             findings.append(Finding(
                 "tuning-cache", f"{rel}::{key}",

@@ -32,7 +32,7 @@ def test_fused_fno1d(b, h, o, n, k, weight_mode):
     wshape = (o, h) if weight_mode == "shared" else (o, h, k)
     wr = _mk(rng, *wshape, scale=1.0 / h)
     wi = _mk(rng, *wshape, scale=1.0 / h)
-    y = ops.spectral_layer_1d(x, wr, wi, k, path="pallas")
+    y = ops.spectral_layer_nd(x, wr, wi, (k,), path="pallas")
     yref = ref_k.ref_fno1d(x, wr, wi, k)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref), **TOL32)
 
@@ -43,7 +43,7 @@ def test_fused_fno1d_bf16(dtype):
     x = _mk(rng, 2, 16, 64, dtype=dtype)
     wr = _mk(rng, 8, 16, dtype=dtype, scale=1 / 16)
     wi = _mk(rng, 8, 16, dtype=dtype, scale=1 / 16)
-    y = ops.spectral_layer_1d(x, wr, wi, 16, path="pallas")
+    y = ops.spectral_layer_nd(x, wr, wi, (16,), path="pallas")
     yref = ref_k.ref_fno1d(x.astype(jnp.float32), wr.astype(jnp.float32),
                            wi.astype(jnp.float32), 16)
     np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(yref),
@@ -65,7 +65,7 @@ def test_fused_fno2d_shared(b, h, o, x_, y_, kx, ky, variant):
     x = _mk(rng, b, h, x_, y_)
     wr = _mk(rng, o, h, scale=1.0 / h)
     wi = _mk(rng, o, h, scale=1.0 / h)
-    y = ops.spectral_layer_2d(x, wr, wi, (kx, ky), path="pallas",
+    y = ops.spectral_layer_nd(x, wr, wi, (kx, ky), path="pallas",
                               variant=variant)
     yref = ref_k.ref_fno2d(x, wr, wi, (kx, ky))
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref), **TOL32)
@@ -81,7 +81,7 @@ def test_fused_fno2d_permode(b, h, o, x_, y_, kx, ky, variant):
     x = _mk(rng, b, h, x_, y_)
     wr = _mk(rng, o, h, kx, ky, scale=1.0 / h)
     wi = _mk(rng, o, h, kx, ky, scale=1.0 / h)
-    y = ops.spectral_layer_2d(x, wr, wi, (kx, ky), path="pallas",
+    y = ops.spectral_layer_nd(x, wr, wi, (kx, ky), path="pallas",
                               variant=variant)
     yref = ref_k.ref_fno2d(x, wr, wi, (kx, ky))
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref), **TOL32)
@@ -93,9 +93,9 @@ def test_fused_fno2d_permode_partial_matches_xla():
     x = _mk(rng, 2, 8, 16, 32)
     wr = _mk(rng, 8, 8, 5, 9, scale=1.0 / 8)
     wi = _mk(rng, 8, 8, 5, 9, scale=1.0 / 8)
-    y = ops.spectral_layer_2d(x, wr, wi, (5, 9), path="pallas",
+    y = ops.spectral_layer_nd(x, wr, wi, (5, 9), path="pallas",
                               variant="partial")
-    yx = ops.spectral_layer_2d(x, wr, wi, (5, 9), path="xla")
+    yx = ops.spectral_layer_nd(x, wr, wi, (5, 9), path="xla")
     np.testing.assert_allclose(np.asarray(y), np.asarray(yx), **TOL32)
 
 
@@ -113,7 +113,7 @@ def test_fused_fno3d_shared(b, h, o, x_, y_, z_, kx, ky, kz, variant):
     x = _mk(rng, b, h, x_, y_, z_)
     wr = _mk(rng, o, h, scale=1.0 / h)
     wi = _mk(rng, o, h, scale=1.0 / h)
-    y = ops.spectral_layer_3d(x, wr, wi, (kx, ky, kz), path="pallas",
+    y = ops.spectral_layer_nd(x, wr, wi, (kx, ky, kz), path="pallas",
                               variant=variant)
     yref = ref_k.ref_fnond(x, wr, wi, (kx, ky, kz))
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref), **TOL32)
@@ -126,31 +126,35 @@ def test_fused_fno3d_permode(b, h, o, x_, y_, z_, kx, ky, kz, variant):
     x = _mk(rng, b, h, x_, y_, z_)
     wr = _mk(rng, o, h, kx, ky, kz, scale=1.0 / h)
     wi = _mk(rng, o, h, kx, ky, kz, scale=1.0 / h)
-    y = ops.spectral_layer_3d(x, wr, wi, (kx, ky, kz), path="pallas",
+    y = ops.spectral_layer_nd(x, wr, wi, (kx, ky, kz), path="pallas",
                               variant=variant)
     yref = ref_k.ref_fnond(x, wr, wi, (kx, ky, kz))
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref), **TOL32)
 
 
 def test_compat_wrappers_match_oracle():
-    """The fused_fno{1d,2d} compat wrappers must keep their positional
-    operand contract wired to the engine correctly (they have no other
-    callers in-repo)."""
+    """The raw engine launch, given positional operands built by hand
+    (``fused_operand_mats`` pairs packed by ``pack_realified``) and block
+    multiples, matches the oracle at rank 1 and at rank 2."""
     from repro.core import spectral as sp
-    from repro.kernels import fused_fno1d as f1d, fused_fno2d as f2d
+    from repro.kernels import engine
     rng = np.random.default_rng(11)
     # 1D: B,H,O already block multiples; rank-1 mats are 128-padded.
     x = _mk(rng, 2, 8, 64)
     wr, wi = _mk(rng, 8, 8, scale=1 / 8), _mk(rng, 8, 8, scale=1 / 8)
-    mats = sp.fused_operand_mats((64,), (17,), "float32", False, 128)
-    y = f1d.fused_fno1d_call(x, wr, wi, *mats, 2, 8, 8, True)
+    m = sp.fused_operand_mats((64,), (17,), "float32", False, 128)
+    mats = sp.pack_realified([m[0:2], m[2:4]], 1, jnp)
+    y = engine.fused_fnond_call(x, wr, wi, *mats, bb=2, bo=8, bh=8,
+                                name="spectral_fwd", interpret=True)
     np.testing.assert_allclose(np.asarray(y),
                                np.asarray(ref_k.ref_fno1d(x, wr, wi, 17)),
                                **TOL32)
     # 2D full: rank ≥ 2 needs no mode padding.
     x2 = _mk(rng, 2, 8, 16, 32)
-    mats = sp.fused_operand_mats((16, 32), (5, 9), "float32", False, 0)
-    y2 = f2d.fused_fno2d_full_call(x2, wr, wi, *mats, 2, 8, 8, True)
+    m = sp.fused_operand_mats((16, 32), (5, 9), "float32", False, 0)
+    mats = sp.pack_realified([m[0:2], m[2:4], m[4:6], m[6:8]], 2, jnp)
+    y2 = engine.fused_fnond_call(x2, wr, wi, *mats, bb=2, bo=8, bh=8,
+                                 name="spectral_fwd", interpret=True)
     np.testing.assert_allclose(
         np.asarray(y2), np.asarray(ref_k.ref_fno2d(x2, wr, wi, (5, 9))),
         **TOL32)
@@ -217,7 +221,7 @@ def test_three_paths_agree():
     x = _mk(rng, 2, 16, 8, 64)
     wr = _mk(rng, 16, 16, scale=1 / 16.0)
     wi = _mk(rng, 16, 16, scale=1 / 16.0)
-    outs = [ops.spectral_layer_2d(x, wr, wi, (3, 17), path=p,
+    outs = [ops.spectral_layer_nd(x, wr, wi, (3, 17), path=p,
                                   variant=v)
             for p, v in (("ref", "full"), ("xla", "full"),
                          ("pallas", "full"), ("pallas", "partial"))]

@@ -51,8 +51,8 @@ def test_grad_fused_fno1d(b, h, o, n, k, weight_mode):
     wshape = (o, h) if weight_mode == "shared" else (o, h, k)
     wr = _mk(rng, *wshape, scale=1.0 / h)
     wi = _mk(rng, *wshape, scale=1.0 / h)
-    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_1d(
-        x, wr, wi, k, path=p)
+    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_nd(
+        x, wr, wi, (k,), path=p)
     _assert_grads_match(mk, x, wr, wi)
 
 
@@ -70,7 +70,7 @@ def test_grad_fused_fno2d_shared(b, h, o, x_, y_, kx, ky, variant):
     x = _mk(rng, b, h, x_, y_)
     wr = _mk(rng, o, h, scale=1.0 / h)
     wi = _mk(rng, o, h, scale=1.0 / h)
-    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_2d(
+    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_nd(
         x, wr, wi, (kx, ky), path=p, variant=variant if p == "pallas"
         else "full")
     _assert_grads_match(mk, x, wr, wi)
@@ -83,7 +83,7 @@ def test_grad_fused_fno2d_permode(b, h, o, x_, y_, kx, ky, variant):
     x = _mk(rng, b, h, x_, y_)
     wr = _mk(rng, o, h, kx, ky, scale=1.0 / h)
     wi = _mk(rng, o, h, kx, ky, scale=1.0 / h)
-    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_2d(
+    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_nd(
         x, wr, wi, (kx, ky), path=p, variant=variant if p == "pallas"
         else "full")
     _assert_grads_match(mk, x, wr, wi)
@@ -106,7 +106,7 @@ def test_grad_fused_fno3d(b, h, o, x_, y_, z_, kx, ky, kz, weight_mode,
               else (o, h, kx, ky, kz))
     wr = _mk(rng, *wshape, scale=1.0 / h)
     wi = _mk(rng, *wshape, scale=1.0 / h)
-    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_3d(
+    mk = lambda p: lambda x, wr, wi: ops.spectral_layer_nd(
         x, wr, wi, (kx, ky, kz), path=p, variant=variant if p == "pallas"
         else "full")
     _assert_grads_match(mk, x, wr, wi)
@@ -117,7 +117,7 @@ def test_grad_linearity_in_cotangent():
     rng = np.random.default_rng(3)
     x = _mk(rng, 2, 8, 64)
     wr, wi = _mk(rng, 8, 8, scale=1 / 8), _mk(rng, 8, 8, scale=1 / 8)
-    f = lambda x: ops.spectral_layer_1d(x, wr, wi, 17, path="pallas")
+    f = lambda x: ops.spectral_layer_nd(x, wr, wi, (17,), path="pallas")
     y, vjp = jax.vjp(f, x)
     g1 = _mk(rng, *y.shape)
     g2 = _mk(rng, *y.shape)

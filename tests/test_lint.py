@@ -42,6 +42,29 @@ def test_block_matrix_subset_clean():
     assert fs == []
 
 
+@pytest.mark.parametrize("rank", [1, 2, 3])
+@pytest.mark.parametrize("layer,names", [
+    ("spectral", ["spectral_fwd", "dx_adjoint", "wgrad"]),
+    ("block", ["block_fwd", "gz_recompute", "dx_adjoint", "wgrad"]),
+], ids=["spectral", "block"])
+def test_grad_traces_the_named_launches(layer, names, rank):
+    # The spectral layer and the block share one custom_vjp: the layer is
+    # the block with no bypass and a linear epilogue, so its gradient
+    # skips the gz recompute and its forward launch is spectral_fwd.
+    modes = jaxpr_lint._MODES[rank]
+    args = jaxpr_lint.block_args(rank, "shared", "f32")
+    if layer == "block":
+        fn = lambda *a: ops.fno_block_nd(*a, modes, path="pallas")
+    else:
+        args = args[:3]
+        fn = lambda *a: ops.spectral_layer_nd(*a, modes, path="pallas")
+    grad = jax.grad(lambda *a: jnp.sum(fn(*a) ** 2),
+                    argnums=tuple(range(len(args))))
+    traced = [e.params["name"] for e in jaxpr_lint.launch_eqns(grad, *args)
+              if e.primitive.name == "pallas_call"]
+    assert traced == names
+
+
 def test_fused_block_contract_wrapper_clean():
     assert jaxpr_lint.fused_block_contract() == []
 

@@ -26,8 +26,6 @@ BF16 = PrecisionPolicy.from_name("bf16")
 TOL_BF16 = dict(rtol=2e-2, atol=2e-2)
 TOL_BF16_GRAD = dict(rtol=5e-2, atol=5e-2)
 
-_LAYERS = {1: ops.spectral_layer_1d, 2: ops.spectral_layer_2d,
-           3: ops.spectral_layer_3d}
 _CASES = {
     1: ((48,), (11,)),
     2: ((16, 32), (5, 9)),
@@ -40,12 +38,8 @@ def _mk(rng, *s, dtype=jnp.float32, scale=1.0):
 
 
 def _layer_fn(rank, modes, path, policy=None, variant="full"):
-    fn = _LAYERS[rank]
-    m = modes[0] if rank == 1 else modes
-    kw = {} if rank == 1 else {"variant": variant}
-    if policy is not None:
-        kw["policy"] = policy
-    return lambda x, wr, wi: fn(x, wr, wi, m, path=path, **kw)
+    return lambda x, wr, wi: ops.spectral_layer_nd(
+        x, wr, wi, modes, path=path, variant=variant, policy=policy)
 
 
 def _allclose_rel(a, b, **tol):

@@ -51,7 +51,7 @@ def test_spectral_layer_linearity(seed):
     mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     x1, x2 = mk(2, 8, 32), mk(2, 8, 32)
     wr, wi = mk(8, 8) / 8, mk(8, 8) / 8
-    f = lambda x: ops.spectral_layer_1d(x, wr, wi, 9, path="xla")
+    f = lambda x: ops.spectral_layer_nd(x, wr, wi, (9,), path="xla")
     lhs = f(1.7 * x1 + x2)
     rhs = 1.7 * f(x1) + f(x2)
     np.testing.assert_allclose(np.asarray(lhs), np.asarray(rhs), rtol=1e-3,
@@ -66,7 +66,7 @@ def test_truncation_contracts_energy(seed, n):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.normal(size=(2, 4, n)), jnp.float32)
     eye = jnp.eye(4, dtype=jnp.float32)
-    y = ops.spectral_layer_1d(x, eye, jnp.zeros_like(eye), k, path="xla")
+    y = ops.spectral_layer_nd(x, eye, jnp.zeros_like(eye), (k,), path="xla")
     e_in = float(jnp.sum(x ** 2))
     e_out = float(jnp.sum(y ** 2))
     assert e_out <= e_in * (1 + 1e-4)
@@ -79,7 +79,7 @@ def test_fusion_equals_staged(seed):
     mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     x = mk(2, 8, 64)
     wr, wi = mk(8, 8) / 8, mk(8, 8) / 8
-    y1 = ops.spectral_layer_1d(x, wr, wi, 17, path="pallas")
+    y1 = ops.spectral_layer_nd(x, wr, wi, (17,), path="pallas")
     y0 = ref_k.ref_fno1d(x, wr, wi, 17)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y0), rtol=2e-4,
                                atol=2e-4)
@@ -98,7 +98,7 @@ def test_fusion_equals_staged_both_dtypes(dtype, seed):
     mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
     x = mk(2, 8, 64)
     wr, wi = mk(8, 8) / 8, mk(8, 8) / 8
-    y1 = ops.spectral_layer_1d(x, wr, wi, 17, path="pallas", policy=pol)
+    y1 = ops.spectral_layer_nd(x, wr, wi, (17,), path="pallas", policy=pol)
     assert jnp.dtype(y1.dtype).name == pol.compute_dtype
     y0 = ref_k.ref_fno1d(x, wr, wi, 17)
     scale = max(float(jnp.abs(np.asarray(y0)).max()), 1.0)
@@ -144,9 +144,6 @@ _RANK_CASES = {
     2: ((16, 16), (5, 5)),
     3: ((8, 8, 8), (3, 3, 3)),
 }
-_RANK_LAYERS = {1: lambda *a, **k: ops.spectral_layer_1d(*a, **k),
-                2: lambda *a, **k: ops.spectral_layer_2d(*a, **k),
-                3: lambda *a, **k: ops.spectral_layer_3d(*a, **k)}
 
 
 @given(rank=st.sampled_from([1, 2, 3]),
@@ -163,8 +160,7 @@ def test_engine_matches_ref_all_ranks(rank, weight_mode, seed):
     x = mk(2, h, *spatial)
     wshape = (o, h) if weight_mode == "shared" else (o, h) + modes
     wr, wi = mk(*wshape) / h, mk(*wshape) / h
-    m = modes[0] if rank == 1 else modes
-    y = _RANK_LAYERS[rank](x, wr, wi, m, path="pallas")
+    y = ops.spectral_layer_nd(x, wr, wi, modes, path="pallas")
     yref = ref_k.ref_fnond(x, wr, wi, modes)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yref), rtol=2e-4,
                                atol=2e-4)

@@ -136,15 +136,15 @@ def test_legal_blocks_obey_tiling_rule(rank):
     # aligned, ragged and tiny dims alike.
     for dim in (1, 8, 16, 20, 64, 129, 200):
         for pref in (1, 2, 4, 8, 16, 32, 64, 128):
-            bb, bo, bh = ops.legal_blocks(rank, 8, dim, dim, pref, pref,
+            bb, bo, bh = plans.legal_blocks(rank, 8, dim, dim, pref, pref,
                                           pref)
             o_pad, h_pad = -dim % bo + dim, -dim % bh + dim
-            assert ops.tiling_legal(rank, o_pad, h_pad, bo, bh), (
+            assert plans.tiling_legal(rank, o_pad, h_pad, bo, bh), (
                 rank, dim, pref, bo, bh)
             assert bo % 8 == 0 and h_pad - dim < max(dim, 8), (dim, bh)
     # rank >= 2 keeps a small hidden tile free; rank 1 may not
-    assert ops.legal_blocks(2, 8, 64, 64, 1, 8, 4)[2] == 4
-    assert ops.legal_blocks(1, 8, 64, 64, 1, 8, 4)[2] == 8
+    assert plans.legal_blocks(2, 8, 64, 64, 1, 8, 4)[2] == 4
+    assert plans.legal_blocks(1, 8, 64, 64, 1, 8, 4)[2] == 8
 
 
 def test_committed_cache_is_fresh():
@@ -173,7 +173,7 @@ def test_resolver_precedence(tmp_path, monkeypatch):
                         lambda path=None: {"meta": {}, "entries": {}})
     p4 = resolve_block_plan(cfg, "block_fwd")
     assert p4.source == "default"
-    assert p4.triple == ops._BLOCK_DEFAULTS[cfg.ndim]
+    assert p4.triple == plans._BLOCK_DEFAULTS[cfg.ndim]
 
 
 def test_rank1_core_aliases_block_fwd():
@@ -265,8 +265,7 @@ def test_all_cells_resolve_feasible_plans(dtype, reduced):
 
 
 def test_autotune_smoke_covers_reduced_cells(tmp_path):
-    path, entries = autotune.tune(measure="none", smoke=True,
-                                  out=str(tmp_path / "b.json"),
+    path, entries = autotune.tune(smoke=True, out=str(tmp_path / "b.json"),
                                   log=lambda *a: None)
     assert entries
     for key in entries:
@@ -279,14 +278,14 @@ def test_autotune_smoke_covers_reduced_cells(tmp_path):
 # _pick_block clamp regressions (odd extents must not explode padding)
 # ---------------------------------------------------------------------------
 def test_pick_block_minimizes_pad_waste():
-    assert ops._pick_block(129, 128) == 8      # pads to 136, not 256
-    assert ops._pick_block(192, 128) == 96     # exact multiple, zero waste
-    assert ops._pick_block(64, 128) == 64
-    assert ops._pick_block(4, 128) == 8        # tiny dims keep one block
-    assert ops._pick_block(1, 2) == 1          # no padding a singleton
-    assert ops._pick_block(8, 2) == 2          # explicit small pref wins
+    assert plans._pick_block(129, 128) == 8      # pads to 136, not 256
+    assert plans._pick_block(192, 128) == 96     # exact multiple, zero waste
+    assert plans._pick_block(64, 128) == 64
+    assert plans._pick_block(4, 128) == 8        # tiny dims keep one block
+    assert plans._pick_block(1, 2) == 1          # no padding a singleton
+    assert plans._pick_block(8, 2) == 2          # explicit small pref wins
     for dim, pref in ((129, 128), (65, 64), (33, 32), (7, 8)):
-        b = ops._pick_block(dim, pref)
+        b = plans._pick_block(dim, pref)
         padded = -dim % b + dim
         assert padded - dim < dim, (dim, pref, b)  # waste strictly < 100%
 
@@ -335,7 +334,7 @@ def test_tuned_vs_default_parity_reduced_2d():
     modes = (4, 4)
     y_tuned = ops.fno_block_nd(x, wr, wi, wb, bias, modes, path="pallas",
                                interpret=True)
-    dflt = ops._BLOCK_DEFAULTS[cfg.ndim]
+    dflt = plans._BLOCK_DEFAULTS[cfg.ndim]
     y_dflt = ops.fno_block_nd(x, wr, wi, wb, bias, modes, path="pallas",
                               interpret=True, block_plan=dflt)
     assert jnp.max(jnp.abs(y_tuned - y_dflt)) < 1e-5
