@@ -10,7 +10,7 @@ One chip (the default) runs three phases in this one process:
 * serve f32 and serve bf16 — ``FNOServer`` (built the way
   ``python -m repro.launch.serve --arch fno2d`` builds it) answers four
   request batches of mixed size at full fno2d width with ``max_batch`` 8.
-  The compiled serve step must hold ``num_layers`` Mosaic kernels
+  The compiled serve step must hold ``fno.pallas_launches`` Mosaic kernels
   (``tpu_custom_call``), every output must be finite, and the outputs must
   match the ``path="ref"`` oracle computed in f32 under
   ``jax.default_matmul_precision("highest")``.
@@ -110,6 +110,8 @@ def _serve(jax, cfg, server, tag: str):
     """Warm every bucket, assert the kernel count, serve the requests."""
     import numpy as np
 
+    from repro.core import fno as fno_mod
+
     warm = {}
     for b in server.buckets:
         xb = jax.numpy.zeros((b, cfg.in_channels) + tuple(cfg.spatial))
@@ -121,9 +123,10 @@ def _serve(jax, cfg, server, tag: str):
     text = jax.jit(server.step_fn).lower(server.params,
                                          {"x": xb}).compile().as_text()
     kernels = text.count("tpu_custom_call")
-    assert kernels == cfg.num_layers, (
+    want = fno_mod.pallas_launches(cfg, ctx=server.ctx)
+    assert kernels == want, (
         f"{tag}: compiled serve step holds {kernels} tpu_custom_call(s), "
-        f"want one per layer ({cfg.num_layers})")
+        f"want one per layer and one per staged end ({want})")
     reqs = _requests(jax, cfg)
     jax.block_until_ready(reqs)
     t0 = time.perf_counter()

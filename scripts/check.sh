@@ -10,7 +10,7 @@ export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 # four). Pure tracing — runs in a couple of seconds, no kernels execute.
 python scripts/fused_block_smoke.py
 # FNO serving smoke (ISSUE 5): the batched serve driver on the fused
-# pallas path, one bucket — asserts one pallas_call per layer through the
+# pallas path, one bucket — asserts one pallas_call per layer and per end through the
 # sharded dispatch and that every served output is finite.
 python -m repro.launch.serve --arch fno2d --reduced --requests 2 \
   --max-batch 2
@@ -27,7 +27,7 @@ python scripts/autotune.py --smoke
 # through the async coalescing queue on a virtual clock — exact shed/
 # coalesce/deadline counts, the no-late-serving deadline contract, replay
 # determinism, and the rollout trace contract (K-step device-resident
-# rollout == num_layers pallas_calls for K in {1,4} — docs/DESIGN.md §10).
+# rollout == one forward's pallas_calls for K in {1,4} — docs/DESIGN.md §10).
 python scripts/serve_replay_smoke.py
 # Chaos smoke (ISSUE 9): the deterministic fault plan (kernel fault, NaN
 # injection, replica kill, corrupt checkpoint) replayed through the

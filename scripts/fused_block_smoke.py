@@ -10,7 +10,7 @@ lower to plain HLO and can't be counted post-compilation:
   * jax.grad of the block traces to exactly four (fwd + gz recompute +
     dx adjoint + extended wgrad) — every cotangent on fused kernels;
   * a whole apply_fno forward with cfg.fuse_block traces to exactly
-    num_layers pallas_calls.
+    num_layers + 2 pallas_calls (the blocks and the two end_mlp ends).
 
 Since ISSUE 6 this is a thin wrapper over the contract-linter framework
 (``repro.analysis.jaxpr_lint.fused_block_contract``) — the same checkers
@@ -33,7 +33,7 @@ def main() -> None:
             f"fused-block contract violated ({len(findings)} finding(s))")
     layers = get_config("fno2d", reduced=True).num_layers
     print(f"fused-block smoke OK: block fwd=1, grad=4, "
-          f"model={layers} pallas_calls ({layers} layers)")
+          f"model={layers + 2} pallas_calls ({layers} layers, 2 ends)")
 
 
 if __name__ == "__main__":

@@ -16,7 +16,7 @@ so every count below is machine-independent and asserted exactly:
     (every completed request's t_complete <= its deadline), so completed
     p99 <= the deadline by construction;
   * the rollout trace contract — a K-step device-resident rollout traces
-    exactly num_layers pallas_calls for K in {1, 4}
+    exactly one forward's pallas_calls for K in {1, 4}
     (``analysis.jaxpr_lint.lint_rollout``);
   * every served output is finite (the engine really ran).
 
@@ -122,14 +122,14 @@ def main() -> int:
     assert rep2 == rep, "replay is not deterministic"
     print("replay determinism: OK")
 
-    # Rollout trace contract: K-step rollout == num_layers pallas_calls
+    # Rollout trace contract: K-step rollout == one forward's pallas_calls
     # for K in {1, 4} (the acceptance-criteria pin), clean casts.
     from repro.analysis import format_findings
     from repro.analysis.jaxpr_lint import lint_rollout
     findings = lint_rollout(archs=("fno2d",), dtypes=("f32",), ks=(1, 4))
     assert not findings, format_findings(findings)
-    print(f"rollout trace contract: {cfg.num_layers} pallas_calls for "
-          f"K in (1, 4): OK")
+    print(f"rollout trace contract: {cfg.num_layers} blocks + 2 ends "
+          f"pallas_calls for K in (1, 4): OK")
 
     # Rollout parity through the tier: a K-step continuous-batched answer
     # matches the engine's own device-resident rollout bit-for-bit (the

@@ -43,11 +43,14 @@ FFT_ALLOWED = ("kernels/ref.py", "data/pde.py")
 # allowed to hold float-dtype literals ("<module>" = module level). These
 # are exactly the cast-ownership boundaries of DESIGN.md §4.
 DTYPE_SCOPE: Dict[str, Tuple[str, ...]] = {
-    "kernels/engine.py": (),                   # policy dtypes only
+    "kernels/engine.py": ("_bf16_pieces",      # end_mlp: bf16 pieces of
+                          "_make_end_mlp_kernel",  # an f32 operand, f32
+                          "end_mlp_call"),     # accumulation (§4)
     "kernels/cgemm.py": ("<module>",),         # _F32 accumulator default
     "kernels/dft.py": ("<module>",),           # _F32 accumulator default
     "kernels/ops.py": ("_spectral_oracle",     # f32 oracle boundary
-                       "_block_tail"),         # f32 epilogue accumulation
+                       "_block_tail",          # f32 epilogue accumulation
+                       "end_mlp_terms"),       # f32 operands split in 1-6
     "core/fno.py": ("_dense_init",             # f32 master-param init
                     "relative_l2"),            # f32 metric reduction
     "core/spectral_conv.py": ("init_spectral_nd",),

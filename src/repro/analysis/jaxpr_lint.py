@@ -8,7 +8,8 @@ the contracts are stated at):
   * **pallas-count** — the fusion contract (ROADMAP.md, DESIGN.md §3.4):
     one FNO block forward on the full-fusion path == ONE pallas_call,
     jax.grad of the block == exactly FOUR (fwd + gz recompute + dx adjoint
-    + extended wgrad), a fused model forward / serve step == num_layers.
+    + extended wgrad), a fused model forward / serve step == num_layers
+    + the end_mlp launches of its staged ends (``fno.pallas_launches``).
   * **cast-ownership** — DESIGN.md §4: launch-level
     ``convert_element_type`` ops between float dtypes may only move
     between the dtypes the active ``PrecisionPolicy`` names (so the f32
@@ -260,7 +261,8 @@ def lint_block_matrix(ranks: Sequence[int] = (1, 2, 3),
 def lint_model(archs: Sequence[str] = ("fno1d", "fno2d", "fno3d"),
                dtypes: Sequence[str] = DTYPES) -> List[Finding]:
     """Whole fused-model forward (``apply_fno`` with fuse_block): exactly
-    num_layers pallas_calls and policy-clean casts."""
+    ``fno.pallas_launches`` pallas_calls (num_layers + the lift and
+    projection end_mlp launches) and policy-clean casts."""
     from repro.configs import get_config
     from repro.configs.fno import with_fuse_block, with_precision
     from repro.core import fno as fno_mod
@@ -277,7 +279,8 @@ def lint_model(archs: Sequence[str] = ("fno1d", "fno2d", "fno3d"),
         x = jnp.zeros((2, cfg.in_channels) + tuple(cfg.spatial))
         model = lambda p, xx: fno_mod.apply_fno(  # noqa: E731
             p, cfg, xx, path="pallas")
-        findings += check_pallas_count(model, (params, x), cfg.num_layers,
+        findings += check_pallas_count(model, (params, x),
+                                       fno_mod.pallas_launches(cfg, "pallas"),
                                        target=target)
         findings += check_cast_ownership(model, (params, x), cfg.precision,
                                          target=target)
@@ -341,7 +344,9 @@ def lint_serve(arch: str = "fno2d",
                layouts: Sequence[str] = ("scatter", "psum")
                ) -> List[Finding]:
     """``FNOServer.step_fn`` through the shard_map dispatch, both TP
-    layouts: num_layers pallas_calls; on the scattered layout one
+    layouts: ``fno.pallas_launches`` pallas_calls (the staged ends run
+    as end_mlp launches under pure DP, as einsums under TP); on the
+    scattered layout one
     psum_scatter per interior layer and ONE psum on the final layer, on
     the psum layout one psum per layer (iff TP); zero all-gathers, clean
     casts."""
@@ -373,8 +378,9 @@ def lint_serve(arch: str = "fno2d",
         args = (params, {"x": xb})
         tp_on = ctx.model_axis is not None
         scat = tp_on and layout == "scatter"
-        findings += check_pallas_count(server.step_fn, args, cfg.num_layers,
-                                       target=target)
+        findings += check_pallas_count(
+            server.step_fn, args, fno_mod.pallas_launches(cfg, ctx=ctx),
+            target=target)
         findings += check_collective_budget(
             server.step_fn, args,
             psums=(1 if scat else cfg.num_layers) if tp_on else 0,
@@ -389,9 +395,10 @@ def lint_rollout(archs: Sequence[str] = ("fno1d", "fno2d", "fno3d"),
                  ks: Sequence[int] = (1, 4)) -> List[Finding]:
     """The rollout trace contract (DESIGN.md §10): a K-step device-
     resident rollout (``FNOServer.rollout_step_fn`` — one ``lax.scan``
-    whose body is the fused forward) traces EXACTLY ``num_layers``
-    pallas_calls regardless of K, because the scan body traces once. An
-    unrolled per-step loop would trace K × num_layers — K kernel-launch
+    whose body is the fused forward) traces EXACTLY the forward's
+    ``fno.pallas_launches`` pallas_calls regardless of K, because the scan
+    body traces once. An unrolled per-step loop would trace K times as
+    many — K kernel-launch
     sets and K HBM round-trips of the carry — which is precisely the
     staged dispatch the rollout tier exists to eliminate. Casts stay
     policy-owned: the single carry cast at the top is the policy's own
@@ -420,7 +427,8 @@ def lint_rollout(archs: Sequence[str] = ("fno1d", "fno2d", "fno3d"),
         for k in ks:
             target = f"FNOServer.rollout_step_fn {arch}/{dtype} K={k}"
             fn = functools.partial(server.rollout_step_fn, steps=k)
-            findings += check_pallas_count(fn, args, cfg.num_layers,
+            findings += check_pallas_count(fn, args,
+                                           fno_mod.pallas_launches(cfg),
                                            target=target)
             findings += check_cast_ownership(fn, args, cfg.precision,
                                              target=target)
@@ -431,7 +439,8 @@ def lint_resilient_serve(arch: str = "fno2d",
                          dtypes: Sequence[str] = DTYPES) -> List[Finding]:
     """The resilience contract at trace level (DESIGN.md §9): the
     ``ResilientServer`` production step stays EXACTLY the fused serving
-    step — num_layers pallas_calls, no extra collectives — while the
+    step — ``fno.pallas_launches`` pallas_calls, no extra collectives —
+    while the
     degraded (XLA-oracle) step contains ZERO pallas_calls and zero
     explicit collectives. The fallback lives in its own jit entry; if it
     ever leaked into the hot trace (or started launching kernels itself)
@@ -459,7 +468,7 @@ def lint_resilient_serve(arch: str = "fno2d",
         args = (params, {"x": xb})
         tgt = f"ResilientServer {arch}/{dtype}"
         findings += check_pallas_count(
-            rs.primary.step_fn, args, cfg.num_layers,
+            rs.primary.step_fn, args, fno_mod.pallas_launches(cfg),
             target=f"{tgt} production step")
         findings += check_pallas_count(
             rs.fallback.step_fn, args, 0, target=f"{tgt} degraded step")
@@ -477,8 +486,9 @@ def lint_resilient_serve(arch: str = "fno2d",
 # ---------------------------------------------------------------------------
 def fused_block_contract() -> List[Finding]:
     """The PR-4 trace-count guard as framework checks: block fwd == 1,
-    grad == 4, reduced fno2d fused model == num_layers pallas_calls
-    (scripts/fused_block_smoke.py wraps this)."""
+    grad == 4, reduced fno2d fused model == num_layers + 2 pallas_calls
+    (the blocks and the two end_mlp launches;
+    scripts/fused_block_smoke.py wraps this)."""
     findings = lint_block_matrix(ranks=(2,), layouts=("shared",),
                                  variants=("full",), dtypes=("f32",))
     findings += lint_model(archs=("fno2d",), dtypes=("f32",))
@@ -487,9 +497,13 @@ def fused_block_contract() -> List[Finding]:
 
 def serve_step_contract(server, cfg) -> List[Finding]:
     """The serve driver's fusion-contract assert (one pallas_call per
-    layer through the shard_map dispatch) as a framework check."""
+    layer, and one per staged end where ``end_mlp`` runs them, through the
+    shard_map dispatch) as a framework check."""
+    from repro.core import fno as fno_mod
+
     xb = jnp.zeros((server.buckets[0], cfg.in_channels)
                    + tuple(cfg.spatial), jnp.float32)
     return check_pallas_count(
-        server.step_fn, (server.params, {"x": xb}), cfg.num_layers,
+        server.step_fn, (server.params, {"x": xb}),
+        fno_mod.pallas_launches(cfg, ctx=server.ctx),
         target=f"{cfg.name} serve step")

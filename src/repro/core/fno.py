@@ -32,10 +32,13 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import FNOConfig
 from repro.core import spectral, spectral_conv as sc
-from repro.distributed.sharding import current_context, shard_activation
+from repro.distributed.sharding import (activation_spec, compat_shard_map,
+                                        current_context, guard_spec,
+                                        shard_activation)
 from repro.kernels import ops
 
 
@@ -98,6 +101,39 @@ def input_layout(cfg: FNOConfig, path: str = None) -> Tuple[int, ...]:
     return tuple(cfg.spatial)
 
 
+def end_mlp_on(cfg: FNOConfig, path: str = None, ctx=None) -> bool:
+    """Whether ``apply_fno`` runs each staged end as one ``end_mlp``
+    launch: on the fused pallas path without fused ends, on one device
+    or data-parallel under the sharding context `ctx` (under TP the ends
+    shard the hidden axis and stay staged XLA einsums, DESIGN.md §6)."""
+    return ((path or cfg.path) == "pallas" and cfg.fuse_block
+            and not cfg.fuse_ends
+            and (ctx is None or ctx.model_axis is None))
+
+
+def pallas_launches(cfg: FNOConfig, path: str = None, ctx=None) -> int:
+    """The pallas_calls one fused forward of ``apply_fno`` traces under
+    the sharding context `ctx` (the fusion contract, DESIGN.md §3.4): one
+    per block, plus the lift and projection ``end_mlp`` launches where
+    they run (``end_mlp_on``)."""
+    return cfg.num_layers + 2 * end_mlp_on(cfg, path, ctx)
+
+
+def _end_mlp(x, ps, act):
+    """``ops.end_mlp`` over x with the Linears ``ps`` (``_dense``
+    params); inside a multi-device sharding context it runs per batch
+    shard under shard_map, the weights replicated."""
+    layers = tuple((p["w"], p["b"]) for p in ps)
+    ctx = current_context()
+    if ctx is None or ctx.mesh.devices.size == 1:
+        return ops.end_mlp(x, layers, act)
+    spec = guard_spec(activation_spec("fno", ctx), x.shape, ctx.mesh)
+    return compat_shard_map(
+        lambda xl, ll: ops.end_mlp(xl, ll, act), ctx.mesh,
+        in_specs=(spec, jax.tree_util.tree_map(lambda _: P(), layers)),
+        out_specs=spec)(x, layers)
+
+
 def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
               *, path: str = None, variant: str = "full") -> jax.Array:
     """x: [B, in_channels, *spatial] -> [B, out_channels, *spatial]; x may
@@ -130,9 +166,10 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
     ctx = current_context()
     ends_on = fuse and cfg.fuse_ends and (ctx is None
                                           or ctx.model_axis is None)
-    # The staged ends run under the named scopes "lift" and "projection",
-    # which the compiled ops carry in their op_name (the device trace's
-    # labels for them); the fused ends are the block_ends launches.
+    # The ends run under the named scopes "lift" and "projection", which
+    # the compiled ops carry in their op_name (the device trace's labels
+    # for the staged einsums and the end_mlp launches); the fused ends
+    # are the block_ends launches.
     act = lambda z: ops.activate(z, cfg.activation)  # noqa: E731
     # Between its ends a fused model keeps the activations in the forward
     # kernel's layout (spectral.kernel_layout: a rank-3 grid's trailing
@@ -145,8 +182,18 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
                 if fuse and not ends_on else spatial)
     x = x.reshape(x.shape[:2] + kspatial)
     as_grid = lambda a: a.reshape(a.shape[:2] + spatial)  # noqa: E731
+    # Otherwise, where the blocks are fused and the ends are not sharded,
+    # each staged end runs as ONE end_mlp launch (ops.end_mlp) on the
+    # kernel layout: the MLP's inner activation stays in VMEM.
+    mlp_on = end_mlp_on(cfg, path, ctx)
+    lift_ps = [params["lift1"]] + ([params["lift2"]]
+                                   if cfg.lift_layers == 2 else [])
     if ends_on:
         h = x
+    elif mlp_on:
+        with jax.named_scope("lift"):
+            h = shard_activation(_end_mlp(x, lift_ps, cfg.activation),
+                                 "fno_hidden")
     elif cfg.lift_layers == 1:
         with jax.named_scope("lift"):
             h = shard_activation(_dense(params["lift1"], x), "fno_hidden")
@@ -198,7 +245,11 @@ def apply_fno(params: Dict[str, Any], cfg: FNOConfig, x: jax.Array,
     if ends_on:
         return shard_activation(h, "fno")
     with jax.named_scope("projection"):
-        out = _dense(params["proj2"], act(_dense(params["proj1"], h)))
+        if mlp_on:
+            out = _end_mlp(h, [params["proj1"], params["proj2"]],
+                           cfg.activation)
+        else:
+            out = _dense(params["proj2"], act(_dense(params["proj1"], h)))
         return shard_activation(as_grid(out), "fno")
 
 

@@ -25,7 +25,7 @@ VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 # among them.
 LAUNCH_NAMES = ("block_fwd", "block_ends", "spectral_fwd", "gz_recompute",
                 "dx_adjoint", "wgrad", "core", "rdft", "cdft", "irdft",
-                "cgemm")
+                "cgemm", "end_mlp")
 
 
 def _compiler_params(**kwargs):

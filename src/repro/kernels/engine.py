@@ -71,6 +71,9 @@ Three kernel families:
     ``with_bypass=True`` additionally emits the bypass-weight cotangent
     ``dW_b = Σ gz·xᵀ`` and ``dbias = Σ gz`` from the x/gz refs the
     spectral reduction already holds in VMEM — no extra HBM pass.
+
+Beside them, ``end_mlp_call`` runs a model end's pointwise MLP (the lift
+or the projection) over the same kernel layout in one launch.
 """
 from __future__ import annotations
 
@@ -961,3 +964,203 @@ def fused_fnond_wgrad_call(x: jax.Array, g: jax.Array, *mats: jax.Array,
     if with_bypass:
         return dwr, dwi, _hidden_unblocked(out[2]), out[3]
     return dwr, dwi
+
+
+# ---------------------------------------------------------------------------
+# The model's staged ends as one launch (``end_mlp``): the lift (one
+# Linear, or Linear → act → Linear) or the projection (Linear → act →
+# Linear) over the channels of a [B, C, …] activation in the forward
+# kernels' layout (``spectral.kernel_layout``), so the ends and the block
+# kernels meet without a relayout and the MLP's inner activation never
+# leaves VMEM.
+#
+# An f32 contraction is made of bf16 products: each operand splits into
+# three bf16 pieces, hi + mid + lo, and XLA's "highest" sums six cross
+# products with f32 accumulation (hi·hi, hi·mid, mid·hi, hi·lo, lo·hi,
+# mid·mid), "high" the first three, the default one. XLA runs them as that
+# many MXU passes, each with the K = C contraction padded to the MXU's
+# depth. Here the pieces are stacked along K instead — x as
+# [x_hi; x_hi; x_hi; x_mid; x_mid; x_lo] against [w_hi, w_mid, w_lo, w_hi,
+# w_mid, w_hi] — so ONE bf16 dot with f32 accumulation forms every product
+# in ceil(terms·C / 128) passes (``end_mlp_passes``). A Linear to one
+# channel (the projections' last) runs on the VPU in f32: a multiply and a
+# sublane sum, at least as precise as the six passes.
+#
+# The kernel layout's rank-2 form is [B, C, s_1, s_2] with C a major axis,
+# so a grid step holds a [C, t, L] window and contracts one row of it at a
+# time: eight rows of every channel, [C, 8, L], are swapped to [8, C, L]
+# in registers, so each row is a [C, L] operand (points on lanes, channels
+# on sublanes), and the eight [D, L] results are swapped back into the
+# output window. The rank-1 form [B, C, S] already has channels on
+# sublanes: one "row" per step.
+# ---------------------------------------------------------------------------
+END_MLP_TERMS = {
+    1: ((0, 0),),
+    3: ((0, 0), (0, 1), (1, 0)),
+    6: ((0, 0), (0, 1), (0, 2), (1, 0), (1, 1), (2, 0)),
+}
+_MXU_DEPTH = 128
+
+
+def end_mlp_passes(cin: int, cout: int, terms: int) -> int:
+    """MXU passes one Linear cin → cout of an end MLP takes per row of
+    points: its `terms` bf16 products (``END_MLP_TERMS``) stacked along
+    the contraction, ceil(terms·cin / 128); none for a Linear to one
+    channel, which runs on the VPU. The launch checks this count as it
+    traces."""
+    if cout == 1:
+        return 0
+    return -(-terms * cin // _MXU_DEPTH)
+
+
+def _bf16_pieces(v: jax.Array, n: int):
+    """The first n bf16 pieces of v — hi, mid, lo: each the bf16 rounding
+    of what the earlier ones leave; for f32 v, hi + mid + lo is v to
+    within f32 rounding."""
+    pieces, r = [], v.astype(jnp.float32)
+    for _ in range(n):
+        p = r.astype(jnp.bfloat16)
+        pieces.append(p)
+        r = r - p.astype(jnp.float32)
+    return pieces
+
+
+def _end_act(z, act: str):
+    if act == "gelu":
+        return jax.nn.gelu(z, approximate=True)
+    if act == "relu":
+        return jnp.maximum(z, 0.0)
+    assert act == "linear", act
+    return z
+
+
+@functools.lru_cache(maxsize=None)
+def _make_end_mlp_kernel(dims: Tuple[int, ...], act: str, terms: int,
+                         rows: int, interpret: bool):
+    """dims: (C, D_1[, D_2]); rows: the window's rows (0: the rank-1
+    layout, one row per step)."""
+    pairs = END_MLP_TERMS[terms]
+    n_pieces = 1 + max(px for px, _ in pairs)
+    layers = tuple(zip(dims[:-1], dims[1:]))
+    mxu = tuple(i for i, (_, d) in enumerate(layers) if d != 1)
+    f32 = jnp.float32
+
+    def dot(w, x):
+        if interpret:  # XLA:CPU runs no bf16×bf16→f32 dot in a loop
+            w, x = w.astype(f32), x.astype(f32)
+        return jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=f32,
+                                   precision=jax.lax.Precision.DEFAULT)
+
+    def stacked(v, cin, cout):
+        pieces = _bf16_pieces(v, n_pieces)
+        xs = pieces[0] if terms == 1 else jnp.concatenate(
+            [pieces[px] for px, _ in pairs], axis=0)
+        assert (-(-xs.shape[0] // _MXU_DEPTH)
+                == end_mlp_passes(cin, cout, terms)), (xs.shape, terms)
+        return xs
+
+    def kernel(x_ref, *refs):
+        nl = len(layers)
+        w_refs, b_refs = refs[0:2 * nl:2], refs[1:2 * nl:2]
+        y_ref, packed = refs[2 * nl], refs[2 * nl + 1:]
+        # The W side once per step: each term's columns hold the f32 W
+        # [D, C] and take that term's bf16 piece of it.
+        for i, p_ref in zip(mxu, packed):
+            w = w_refs[i][...]
+            cin = layers[i][0]
+            pieces = [p.astype(f32) for p in _bf16_pieces(w, 3)]
+            col = jax.lax.broadcasted_iota(jnp.int32, w.shape, 1)
+            pw = jnp.zeros(w.shape, jnp.int32)
+            for t, (_, piece) in enumerate(pairs[1:], 1):
+                pw = jnp.where(col >= t * cin, piece, pw)
+            p_ref[...] = jnp.where(
+                pw == 0, pieces[0],
+                jnp.where(pw == 1, pieces[1], pieces[2])).astype(p_ref.dtype)
+
+        def mlp(v):  # [C, L] -> [D_last, L], f32
+            for i, (cin, cout) in enumerate(layers):
+                if i:
+                    v = _end_act(v, act)
+                if cout == 1:
+                    v = jnp.sum(v.astype(f32) * w_refs[i][...].astype(f32),
+                                axis=0, keepdims=True)
+                else:
+                    v = dot(packed[mxu.index(i)][...], stacked(v, cin, cout))
+                v = v + b_refs[i][...].astype(f32)
+            return v.astype(y_ref.dtype)
+
+        if not rows:
+            y_ref[0] = mlp(x_ref[0])
+            return
+
+        def slab(r0, n):  # rows r0 .. r0+n of every channel
+            xt = jnp.swapaxes(x_ref[0, :, pl.ds(r0, n), :], 0, 1)
+            yt = jnp.stack([mlp(xt[u]) for u in range(n)])
+            y_ref[0, :, pl.ds(r0, n), :] = jnp.swapaxes(yt, 0, 1)
+
+        if rows % 8:
+            slab(0, rows)
+            return
+        def group(g, carry):  # eight rows per loop trip
+            slab(pl.multiple_of(g * 8, 8), 8)
+            return carry
+
+        jax.lax.fori_loop(0, rows // 8, group, 0)
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnames=("act", "terms", "rows",
+                                             "lanes", "interpret"))
+def end_mlp_call(x: jax.Array, layers: Tuple, *, act: str, terms: int,
+                 rows: int, lanes: int,
+                 interpret: bool = False) -> jax.Array:
+    """One end MLP over x's channels in one launch.
+
+    x: [B, C, s_1, s_2] (the rank-2 kernel layout) or [B, C, S] (rank 1);
+    layers: one or two (w [D, C_in], b [D]) Linears, w transposed to
+    [out, in] at the param dtype; ``act`` sits between two. terms: the
+    bf16 products per contraction (``END_MLP_TERMS``). A grid step holds
+    `rows` rows of s_1 and `lanes` points of the minor axis (rank 1:
+    `lanes` points of S; `rows` is ignored). Returns [B, D_last, …] at
+    x's dtype."""
+    assert 1 <= len(layers) <= 2 and terms in END_MLP_TERMS, (layers, terms)
+    dims = (x.shape[1],) + tuple(w.shape[0] for w, _ in layers)
+    b, sp = x.shape[0], x.shape[2:]
+    if len(sp) == 1:
+        rows = 0
+        grid = (b, sp[0] // lanes)
+        win = lambda c: pl.BlockSpec((1, c, lanes),  # noqa: E731
+                                     lambda i, q: (i, 0, q))
+        const = lambda a: pl.BlockSpec(a.shape,  # noqa: E731
+                                       lambda i, q: (0, 0))
+        sem = ("parallel", "parallel")
+    else:
+        assert len(sp) == 2, sp
+        grid = (b, sp[0] // rows, sp[1] // lanes)
+        win = lambda c: pl.BlockSpec((1, c, rows, lanes),  # noqa: E731
+                                     lambda i, r, q: (i, 0, r, q))
+        const = lambda a: pl.BlockSpec(a.shape,  # noqa: E731
+                                       lambda i, r, q: (0, 0))
+        sem = ("parallel",) * 3
+    operands, scratch = [x], []
+    for w, bias in layers:
+        if w.shape[0] == 1:  # the VPU Linear: W's column across the lanes
+            w = jnp.broadcast_to(jnp.swapaxes(w, 0, 1), (w.shape[1], lanes))
+        else:  # one f32 copy of W per term, split in the kernel
+            w = jnp.concatenate([w] * terms, axis=1)
+            scratch.append(pltpu.VMEM(w.shape, jnp.bfloat16))
+        operands += [w, jnp.broadcast_to(bias[:, None],
+                                         (bias.shape[0], lanes))]
+    return pl.pallas_call(
+        _make_end_mlp_kernel(dims, act, terms, rows, interpret),
+        grid=grid,
+        in_specs=[win(dims[0])] + [const(a) for a in operands[1:]],
+        out_specs=win(dims[-1]),
+        out_shape=jax.ShapeDtypeStruct((b, dims[-1]) + sp, x.dtype),
+        name="end_mlp",
+        scratch_shapes=scratch,
+        compiler_params=_compiler_params(dimension_semantics=sem),
+        interpret=interpret,
+    )(*operands)

@@ -32,7 +32,8 @@ from repro.kernels import dft as dft_k
 from repro.kernels import engine
 from repro.kernels import ref as ref_k
 from repro.tuning import resolve_launch_plans
-from repro.tuning.plans import LaunchPlans, _pick_block, _rup, legal_blocks
+from repro.tuning.plans import (LaunchPlans, _pick_block, _rup, end_mlp_window,
+                                 legal_blocks)
 
 
 def on_tpu() -> bool:
@@ -881,6 +882,84 @@ def fno_block_ends_nd(x: jax.Array, wr: jax.Array, wi: jax.Array,
     pol, plans = _pallas_setup(x, wr, modes, policy, bb, bo, bh, block_plan)
     return _fno_block_ends_pallas(x, wr, wi, wb, bias, ends, modes, plans,
                                   _interpret(interpret), pol)
+
+
+# ---------------------------------------------------------------------------
+# The staged ends as one launch (``engine.end_mlp_call``): the model's lift
+# or projection MLP over an activation in the forward kernels' layout. The
+# number of bf16 products per f32 contraction follows the operands and the
+# precision the staged einsum would run at; the backward is the jax.vjp of
+# the staged chain, recomputed in XLA, so gradients are what they were.
+# ---------------------------------------------------------------------------
+_TERMS_AT = {None: 1, "default": 1, "bfloat16": 1, "BF16_BF16_F32": 1,
+             "high": 3, "tensorfloat32": 3, "BF16_BF16_F32_X3": 3}
+
+
+def end_mlp_terms(dtype) -> int:
+    """The bf16 products (``engine.END_MLP_TERMS``) an f32 contraction of
+    `dtype` operands is made of where the launch runs, as XLA resolves
+    the einsum it replaces: one for bf16 operands; for f32 on the TPU, 1
+    at the default matmul precision, 3 at "high", 6 at "highest"; 6
+    elsewhere, where XLA computes f32 dots in f32 whatever the
+    precision."""
+    if jnp.dtype(dtype) != jnp.float32:
+        return 1
+    if not on_tpu():
+        return 6
+    return _TERMS_AT.get(jax.config.jax_default_matmul_precision, 6)
+
+
+def _end_mlp_staged(x, layers, act):
+    """The staged chain ``end_mlp`` fuses (core.fno's ``_dense`` →
+    activation → ``_dense``): its parity oracle and its backward."""
+    for i, (w, b) in enumerate(layers):
+        if i:
+            x = activate(x, act)
+        x = _pointwise(w, b, x)
+    return x
+
+
+def _end_mlp_impl(x, layers, act, terms, interpret):
+    rows, lanes = end_mlp_window(
+        x.shape[2:], x.shape[1] + layers[-1][0].shape[1],
+        jnp.dtype(x.dtype).itemsize)
+    return engine.end_mlp_call(
+        x, tuple((jnp.swapaxes(w, 0, 1), b) for w, b in layers), act=act,
+        terms=terms, rows=rows, lanes=lanes, interpret=interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
+def _end_mlp_pallas(x, layers, act, terms, interpret):
+    return _end_mlp_impl(x, layers, act, terms, interpret)
+
+
+def _end_mlp_vjp_fwd(x, layers, act, terms, interpret):
+    return _end_mlp_impl(x, layers, act, terms, interpret), (x, layers)
+
+
+def _end_mlp_vjp_bwd(act, terms, interpret, res, gy):
+    _, vjp = jax.vjp(lambda x_, l_: _end_mlp_staged(x_, l_, act), *res)
+    return vjp(gy)
+
+
+_end_mlp_pallas.defvjp(_end_mlp_vjp_fwd, _end_mlp_vjp_bwd)
+
+
+def end_mlp(x: jax.Array, layers: Sequence[Tuple[jax.Array, jax.Array]],
+            act: str = "gelu", *,
+            interpret: Optional[bool] = None) -> jax.Array:
+    """A model end's pointwise MLP — one Linear, or Linear → act → Linear
+    — over the channels of x in ONE pallas_call (``end_mlp``).
+
+    x: [B, C, s_1, s_2] or [B, C, S], the forward kernels' layout
+    (``spectral.kernel_layout``), at the compute dtype; layers: (w [C, D],
+    b [D]) pairs as core.fno's ``_dense`` params. Computes what
+    ``_end_mlp_staged`` computes at the precision its einsums would run
+    at (``end_mlp_terms``), with the inner activation kept in VMEM;
+    differentiable through the staged chain's VJP."""
+    layers = tuple((w, b) for w, b in layers)
+    return _end_mlp_pallas(x, layers, act, end_mlp_terms(x.dtype),
+                           _interpret(interpret))
 
 
 # ---------------------------------------------------------------------------

@@ -141,7 +141,8 @@ def run(args) -> dict:
             findings += check_pallas_count(
                 functools.partial(server.rollout_step_fn,
                                   steps=args.rollout_steps),
-                (params, {"x": xb}), cfg.num_layers,
+                (params, {"x": xb}),
+                fno_mod.pallas_launches(cfg, ctx=server.ctx),
                 target=f"{cfg.name} rollout K={args.rollout_steps}")
         assert not findings, format_findings(findings)
 

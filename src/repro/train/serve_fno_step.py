@@ -14,7 +14,7 @@ The production workload IS autoregressive, though: a PDE rollout feeds
 step t's prediction back as step t+1's state. ``make_fno_rollout_step``
 keeps the whole K-step trajectory device-resident inside one jitted
 ``lax.scan`` — the scan body traces once, so the trace stays exactly
-``num_layers`` pallas_calls regardless of rollout depth (docs/DESIGN.md
+forward's pallas_calls regardless of rollout depth (docs/DESIGN.md
 §10; pinned by ``analysis.jaxpr_lint.lint_rollout``).
 """
 from __future__ import annotations
@@ -69,8 +69,8 @@ def make_fno_rollout_step(cfg: FNOConfig, *, path: Optional[str] = None,
     ``out_channels <= in_channels``.
 
     Trace contract: the scan body traces ONCE, so a K-step rollout on the
-    fused pallas path contains exactly ``num_layers`` pallas_calls for
-    ANY K (pinned by ``analysis.jaxpr_lint.lint_rollout``). ``steps``
+    fused pallas path contains exactly one forward's pallas_calls
+    (``fno.pallas_launches``) for ANY K (pinned by ``analysis.jaxpr_lint.lint_rollout``). ``steps``
     must be static under jit (``static_argnames=("steps",)``).
     """
     if cfg.out_channels > cfg.in_channels:
@@ -173,7 +173,8 @@ class FNOServer:
             step_fn, rollout_step_fn = base, roll
         self.step_fn = step_fn
         # Un-jitted, exposed for trace guards: a K-step rollout must trace
-        # exactly num_layers pallas_calls regardless of K (lint_rollout).
+        # exactly one forward's pallas_calls regardless of K
+        # (lint_rollout).
         self.rollout_step_fn = rollout_step_fn
         # A host request crosses to the device already in the layout the
         # model runs in (a free reshape of a numpy array): a rank-3 grid's

@@ -20,7 +20,7 @@ the resilience layer a front-end serving millions of requests needs:
     the ladder is pallas → XLA → reject, and every degradation increments
     ``stats["degraded"]`` so silent fallback is impossible. The fallback
     is a separate jit entry: the production step's trace stays exactly
-    ``num_layers`` pallas_calls (linted by
+    ``fno.pallas_launches`` pallas_calls (linted by
     ``analysis.jaxpr_lint.lint_resilient_serve``).
   * **hot checkpoint reload** — ``reload()`` restores params via
     ``Checkpointer`` (``latest_valid_step`` skips corrupt steps),

@@ -230,3 +230,37 @@ def tiling_legal(rank: int, o: int, h: int, bo: int, bh: int) -> bool:
     dims (O, H) — see :func:`legal_blocks`."""
     return ((bo % 8 == 0 or bo == o)
             and (rank > 1 or bh % 8 == 0 or bh == h))
+
+
+# The end_mlp launch's window: the grid step's input and output windows,
+# double-buffered, are held under this many bytes. A window of fno3d-ns's
+# projection (20 + 1 channels × 64 rows × 128 lanes) takes 1.4 MB, one of
+# fno2d's lift (3 + 64 channels × 128 rows × 128 lanes) 8.8 MB; a larger
+# step only shares the per-step cost among more points.
+END_MLP_WINDOW_BYTES = 16 * 1024 * 1024
+
+
+def end_mlp_window(spatial: Sequence[int], channels: int,
+                   itemsize: int) -> Tuple[int, int]:
+    """(rows, lanes) of an end_mlp grid step over a kernel-layout
+    activation with trailing axes `spatial` (rank 1 or 2) and `channels`
+    = input + output channels. Rank 2: lanes are one 128-lane column
+    where the minor axis has whole ones (the kernel gathers its rows by
+    strided loads, which Mosaic runs on 128-lane windows), else the whole
+    minor axis; rows are all of s_1 when the window fits
+    ``END_MLP_WINDOW_BYTES``, else the largest multiple of 8 dividing
+    s_1 that does. Rank 1: rows 0 and lanes the largest multiple of 128
+    dividing S that fits, or all of S."""
+    def largest(n, unit, align):
+        if n * unit <= END_MLP_WINDOW_BYTES or n % align:
+            return n
+        fit = [t for t in range(align, n, align)
+               if n % t == 0 and t * unit <= END_MLP_WINDOW_BYTES]
+        return max(fit, default=align)
+
+    per_point = 2 * channels * itemsize
+    if len(spatial) == 1:
+        return 0, largest(spatial[0], per_point, 128)
+    s1, s2 = spatial
+    lanes = 128 if s2 % 128 == 0 else s2
+    return largest(s1, per_point * lanes, 8), lanes

@@ -157,7 +157,8 @@ def test_fused_model_launches_one_block_kernel_per_layer():
     jaxpr = jax.make_jaxpr(make_fno_serve_step(cfg))(p, {"x": x})
     names = [e.params["name"] for e in _eqns(jaxpr.jaxpr)
              if e.primitive.name == "pallas_call"]
-    assert names == ["block_fwd"] * cfg.num_layers
+    assert names == (["end_mlp"] + ["block_fwd"] * cfg.num_layers
+                     + ["end_mlp"])
 
 
 def _eqns(jaxpr):

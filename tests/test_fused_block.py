@@ -117,7 +117,8 @@ def test_block_grad_stays_on_fused_kernels():
 
 def test_apply_fno_fused_block_model_parity():
     """cfg.fuse_block threads through apply_fno: one pallas_call per
-    layer, output matches the unfused pallas path and the XLA oracle."""
+    layer and one per staged end, output matches the unfused pallas path
+    and the XLA oracle."""
     from repro.core import fno as fno_mod
 
     cfg0 = get_config("fno2d", reduced=True)
@@ -134,8 +135,10 @@ def test_apply_fno_fused_block_model_parity():
     np.testing.assert_array_equal(
         np.asarray(fno_mod.apply_fno(params, cfg, x, path="xla")),
         np.asarray(y_xla))
+    # one launch per block, plus the lift and projection end_mlp launches
     fn = lambda xx: fno_mod.apply_fno(params, cfg, xx, path="pallas")
-    assert count_pallas_calls(fn, x) == cfg.num_layers
+    assert count_pallas_calls(fn, x) == cfg.num_layers + 2
+    assert fno_mod.pallas_launches(cfg, "pallas") == cfg.num_layers + 2
 
 
 def test_block_3d_rank_generic():

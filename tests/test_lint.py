@@ -261,8 +261,8 @@ def test_resilient_serve_lint_clean_and_mutation():
     fs = jaxpr_lint.check_pallas_count(kernel_launching_fallback, args, 0,
                                        target="mutant fallback")
     assert len(fs) == 1 and fs[0].checker == "pallas-count"
-    assert (f"traced {cfg.num_layers} pallas_calls, want exactly 0"
-            in fs[0].message)
+    assert (f"traced {fno_mod.pallas_launches(cfg)} pallas_calls, want "
+            f"exactly 0" in fs[0].message)
 
 
 def _rollout_fixture():
@@ -288,11 +288,14 @@ def _rollout_fixture():
 def test_rollout_lint_clean_and_depth_invariant():
     # ISSUE 10: the rollout trace contract. The device-resident K-step
     # rollout is ONE lax.scan whose body traces once, so the pallas_call
-    # count stays exactly num_layers for ANY depth — pinned here for the
-    # acceptance K in {1, 4} via the sweep entry point AND the raw
-    # checker. ``steps`` must be bound statically (functools.partial)
-    # before tracing: a traced depth would abstract the scan length.
+    # count stays exactly one forward's (num_layers + 2 ends) for ANY
+    # depth — pinned here for the acceptance K in {1, 4} via the sweep
+    # entry point AND the raw checker. ``steps`` must be bound statically
+    # (functools.partial) before tracing: a traced depth would abstract
+    # the scan length.
     import functools
+
+    from repro.core import fno as fno_mod
 
     fs = jaxpr_lint.lint_rollout(archs=("fno2d",), dtypes=("f32",),
                                  ks=(1, 4))
@@ -302,7 +305,8 @@ def test_rollout_lint_clean_and_depth_invariant():
     for k in (1, 4):
         fn = functools.partial(server.rollout_step_fn, steps=k)
         assert jaxpr_lint.check_pallas_count(
-            fn, args, cfg.num_layers, target=f"rollout K={k}") == []
+            fn, args, fno_mod.pallas_launches(cfg),
+            target=f"rollout K={k}") == []
         assert jaxpr_lint.check_cast_ownership(
             fn, args, cfg.precision, target=f"rollout K={k}") == []
 
@@ -324,11 +328,12 @@ def test_mutation_unrolled_rollout_fires_count_checker():
                                 axis=1)
         return x[:, :cfg.out_channels]
 
-    fs = jaxpr_lint.check_pallas_count(unrolled, args, cfg.num_layers,
+    want = fno_mod.pallas_launches(cfg)
+    fs = jaxpr_lint.check_pallas_count(unrolled, args, want,
                                        target="unrolled rollout")
     assert len(fs) == 1 and fs[0].checker == "pallas-count"
-    assert (f"traced {4 * cfg.num_layers} pallas_calls, want exactly "
-            f"{cfg.num_layers}" in fs[0].message)
+    assert (f"traced {4 * want} pallas_calls, want exactly {want}"
+            in fs[0].message)
 
 
 def test_mutation_psum_layout_fails_scatter_budget(subproc):

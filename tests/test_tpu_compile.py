@@ -7,9 +7,10 @@ full-width block forward and its gradient with ``interpret=False`` and
 assert that a Mosaic kernel (``tpu_custom_call``) comes out for every
 launch: one for the forward, four under ``jax.value_and_grad``
 (block_fwd, gz_recompute, dx_adjoint, wgrad), each instruction named for
-its launch kind. The whole fno2d serve step is compiled once more, to
-hold the names the device trace reads: the kernels' launch names and the
-model ends' named scopes.
+its launch kind, and the model ends' ``end_mlp`` launches at the served
+shapes. The whole fno2d serve step is compiled once more, to hold the
+names the device trace reads: the kernels' launch names and the model
+ends' named scopes.
 
 Rank 3 runs in the engine's merged layout (``spectral.kernel_layout``):
 the forward of fno3d and of Li et al.'s fno3d-ns compiles; their weight
@@ -179,10 +180,12 @@ def test_partial_block_compiles_for_v5e(one_chip, arch, dtype):
 
 def test_serve_step_names_its_work_for_v5e(one_chip, monkeypatch):
     """The fno2d serve step at batch 8, as the fused production path runs
-    it: the four block kernels are block_fwd launches, every large XLA op
-    around them carries a launch name or the scope of a model end, and
-    the lifting and projection einsums (the same einsum, told apart
-    only by their scopes) carry different labels."""
+    it: the four block kernels are block_fwd launches and the two staged
+    ends end_mlp launches, every large XLA op around them carries a
+    launch name or the scope of a model end, the two end_mlp launches
+    (the same kernel, told apart only by their scopes) carry the lift and
+    the projection scope, and no activation-sized copy relays the last
+    block's output for the projection."""
     from repro.core import fno
     from repro.train.serve_fno_step import make_fno_serve_step
 
@@ -198,11 +201,49 @@ def test_serve_step_names_its_work_for_v5e(one_chip, monkeypatch):
     text = _compiled_text(make_fno_serve_step(cfg), (params, {"x": x}))
 
     assert _kernel_launches(text) == collections.Counter(
-        {"block_fwd": cfg.num_layers})
+        {"block_fwd": cfg.num_layers, "end_mlp": 2})
     ops_ = _entry_ops(text)
-    large = [(name, label) for name, op, nbytes, _, label in ops_
-             if op in ("fusion", "copy") and nbytes >= 2 ** 20]
-    assert large and all(label for _, label in large), large
-    einsums = {label for _, op, _, path, label in ops_
-               if op == "fusion" and path.endswith("dot_general")}
-    assert einsums == {"lift", "projection"}, einsums
+    large = [(name, op, label) for name, op, nbytes, _, label in ops_
+             if op in ("fusion", "copy", "custom-call")
+             and nbytes >= 2 ** 20]
+    assert large and all(label for *_, label in large), large
+    assert not [n for n, op, _ in large if op == "copy"], large
+    ends = {scope for name, _, _, path, _ in ops_
+            if name.startswith("end_mlp")
+            for scope in ("lift", "projection")
+            if f"/{scope}/" in path}
+    assert ends == {"lift", "projection"}, ends
+
+
+@pytest.mark.parametrize("arch,batch", [("fno2d", 64), ("fno3d-ns", 4),
+                                        ("fno3d-ns", 32)])
+def test_end_mlp_compiles_for_v5e(one_chip, monkeypatch, arch, batch):
+    """The lift and the projection of each benchmark model as end_mlp
+    launches at their served shapes (fno3d-ns's 4-sample chunk and its
+    whole request), f32 at "highest": the six bf16 products stacked along
+    the contraction are Mosaic-legal, one kernel per end."""
+    from repro.core import fno
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    cfg = get_config(arch)
+    ksp = fno.input_layout(dataclasses.replace(cfg, path="pallas",
+                                               fuse_block=True))
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,  # noqa
+                                            sharding=one_chip)
+    inner = cfg.lifting_dim or 2 * cfg.hidden
+    lift_w = ([cfg.in_channels, cfg.hidden] if cfg.lift_layers == 1 else
+              [cfg.in_channels, inner, cfg.hidden])
+    ends = {"lift": (cfg.in_channels, lift_w),
+            "projection": (cfg.hidden, [cfg.hidden, inner,
+                                        cfg.out_channels])}
+    for end, (cin, widths) in ends.items():
+        layers = tuple((sd((a, b)), sd((b,)))
+                       for a, b in zip(widths[:-1], widths[1:]))
+
+        def fn(x, layers):
+            return ops.end_mlp(x, layers, cfg.activation, interpret=False)
+
+        with jax.default_matmul_precision("highest"):
+            text = _compiled_text(fn, (sd((batch, cin) + ksp), layers))
+        assert _kernel_launches(text) == collections.Counter(
+            ["end_mlp"]), (arch, end)
